@@ -392,14 +392,16 @@ class CryptoConfig:
     """Verify-backend intent + launch-ledger sizing (crypto/tpu/
     {watchdog,ledger}.py; this framework's addition).
 
-    `backend` is the operator's PROMISE, not a dispatch switch: the
-    verify paths keep their own breaker-aware device/host ladder
-    regardless. With "tpu" the silicon watchdog degrades the /status
-    device check whenever the launch ledger shows launches landing on
-    CPU, raising, going silent past the window, or drifting >3x past
-    the recorded silicon exec baseline — the wedged-relay shape that
-    let BENCH_r04/r05 run on TFRT_CPU_0 unnoticed. "auto" (default)
-    and "cpu" report the effective backend but never degrade on it."""
+    `backend = "tpu"` is binding at start-up: the node refuses to
+    start unless the default JAX backend is a TPU
+    (crypto/tpu/backend.require_tpu). It is still not a dispatch
+    switch — once running, the verify paths keep their breaker-aware
+    device/host ladder — but the silicon watchdog then degrades the
+    /status device check whenever the launch ledger shows launches
+    landing on CPU, raising, going silent past the window, or
+    drifting >3x past an operator-given exec baseline. "auto"
+    (default) and "cpu" start on whatever backend JAX has, report the
+    effective backend and never degrade on it."""
 
     backend: str = "auto"
     # effective-backend classification window: how long without a

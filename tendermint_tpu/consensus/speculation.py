@@ -432,6 +432,7 @@ class SpeculationPlane:
                 raise ValueError(
                     "speculative structured sign-bytes self-check "
                     "failed")
+            from ..crypto.tpu import backend as tpu_backend
             from ..crypto.tpu import ledger as tpu_ledger
 
             failpoints.hit("device.verify")
@@ -446,7 +447,8 @@ class SpeculationPlane:
             with tpu_ledger.workload("speculation"):
                 out = arena.launch()
             met.launches.inc(backend="device")
-            crypto_metrics().batch_lanes.inc(n, backend="tpu")
+            crypto_metrics().batch_lanes.inc(
+                n, backend=tpu_backend.platform())
             if not out[0]:
                 # sentinel mismatch: wrong-verdict device — open a
                 # breaker and re-verify on host rather than storing

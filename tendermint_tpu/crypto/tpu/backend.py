@@ -1,19 +1,20 @@
 """One backend-classification vocabulary for the whole repo.
 
-Round 5's lesson (BENCH_r05, ROADMAP item 2c): r04/r05 silently ran on
-TFRT_CPU_0 and nothing in the process could say so. The fix grew three
-near-copies of "is this device string silicon?" — bench.py's backend
-stamp, tools/silicon_record.record_if_tpu, tools/bench_trend.py's
-misrepresentation check — and the launch-ledger watchdog would have
-been a fourth. This module is the single source all of them import
-(pure string logic; no jax, importable from tools/ scripts and the
-product alike).
+A process must be able to say which hardware ran its launches: a run
+that lands on `TFRT_CPU_0` may never be read as a chip number. This
+module is the single place that answers "is this device string a
+chip?" — bench.py's backend stamp, tools/bench_trend.py's
+misrepresentation check and the launch-ledger watchdog all import it
+(string logic needs no jax, so tools/ scripts can import it too; the
+two functions that ask the live backend import jax lazily).
 
 Vocabulary:
-  * ``backend_label(device)`` — the stamp written into BENCH lines and
-    silicon records: ``"tpu"`` or ``"cpu-fallback"`` (hyphen; the
-    historical silicon-record spelling, kept stable for the recorded
-    rounds already on disk).
+  * ``platform()`` — ``jax.devices()[0].platform`` of this process,
+    read once: the label lane counters carry and what
+    ``require_tpu()`` (``[crypto] backend = "tpu"``) checks.
+  * ``backend_label(device)`` — the stamp written into BENCH lines:
+    ``"tpu"`` or ``"cpu-fallback"`` (hyphen; kept stable for recorded
+    lines already on disk).
   * ``classify_stamps(...)`` — the trajectory-gate classifier:
     ``"silicon"`` / ``"cpu_fallback"`` (underscore; the bench_trend
     table vocabulary) plus the misrepresentation/unattribution
@@ -22,6 +23,8 @@ Vocabulary:
 """
 
 from __future__ import annotations
+
+import functools
 
 # Substrings that mark a jax device string as host silicon-less
 # execution (TFRT_CPU_0, "cpu:0", "host").
@@ -45,6 +48,41 @@ SILICON_BACKENDS = ("tpu", "silicon", "device")
 #   unknown       — no device launch has ever been recorded
 EFFECTIVE_STATES = ("tpu", "mesh_degraded", "cpu_fallback", "idle",
                     "unknown")
+
+
+@functools.cache
+def platform() -> str:
+    """The platform of the process's default JAX device ("tpu",
+    "cpu", ...), read once: a process never changes backend."""
+    import jax
+
+    return jax.devices()[0].platform
+
+
+def require_tpu() -> None:
+    """`[crypto] backend = "tpu"` is binding: refuse to start on any
+    other default backend instead of serving from the host while the
+    config says chip."""
+    got = platform()
+    if got != "tpu":
+        raise RuntimeError(
+            f'[crypto] backend = "tpu" but the default JAX backend is '
+            f'{got!r}: refusing to start and verify on the host. Run '
+            'on a machine whose chip this process can open, or set '
+            'backend = "auto" (use the chip when present) or "cpu".')
+
+
+def device_memory_bytes(device) -> int:
+    """Bytes of memory the device itself reports
+    (memory_stats()["bytes_limit"]); a device that reports none is an
+    error, not a default."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"device {device} ({device.device_kind}) reports no "
+            "memory_stats()['bytes_limit']: no table budget can be "
+            "derived for it")
+    return int(limit)
 
 
 def device_is_cpu(device: str) -> bool:

@@ -87,19 +87,33 @@ def set_shard_crossover(n: int | None) -> None:
 _CPU_MAX_KEYS = 2048
 
 
+# Table bytes per key (69 windows x 9 entries x one 128-wide 4-byte
+# row = 317,952 B) and the share of a chip's memory the tables may
+# take; the rest is the builder's and the verify kernels' temporaries.
+_KEY_BYTES = _WINDOWS * _ENTRIES * _ROW * 4
+_TABLE_SHARE = 0.75
+
+
+@functools.cache
 def _single_chip_max_keys() -> int:
     """Largest valset whose REPLICATED tables fit one device.
 
-    Accelerators: HBM budget — ~318 KB/key, 3.3 GB at 10k keys on a
-    16 GB chip, ~40k the practical ceiling. CPU backend (tests / e2e
-    nets / degraded nodes): one default build chunk — tables buy
-    nothing there (no host->device wire to save), so big builds are
-    pure cost."""
+    Accelerators: _TABLE_SHARE of the memory the device itself reports
+    (backend.device_memory_bytes: memory_stats()["bytes_limit"]; a
+    device that reports none raises) over _KEY_BYTES — ~39k keys on a
+    16 GB chip. CPU backend (tests / e2e nets /
+    degraded nodes): one default build chunk — tables buy nothing
+    there (no host->device wire to save), so big builds are pure
+    cost."""
     import jax
 
-    if jax.devices()[0].platform == "cpu":
+    from . import backend as _backend
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return _CPU_MAX_KEYS
-    return 40_000
+    return int(_TABLE_SHARE * _backend.device_memory_bytes(dev)) \
+        // _KEY_BYTES
 
 
 def shard_crossover_keys() -> int:
@@ -170,6 +184,21 @@ def _builder():
         return rows.reshape(v * _WINDOWS * _ENTRIES, _ROW), ok
 
     return build
+
+
+@functools.cache
+def _placer():
+    """Write one build chunk's rows into the preallocated table buffer
+    in place (the buffer is donated): a chunked build then peaks at
+    the table plus ONE chunk, not the chunks plus their concatenation
+    plus a trimmed copy."""
+    import jax
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def place(tables, rows, start):
+        return jax.lax.dynamic_update_slice(tables, rows, (start, 0))
+
+    return place
 
 
 # Windows processed per fori_loop iteration (69 must divide evenly:
@@ -440,12 +469,13 @@ class ExpandedKeys:
     """Device-resident comb tables for a fixed list of ed25519 pubkeys."""
 
     # Keys per build launch. The builder materializes ~3 stacked
-    # copies of its output (scan rows + pad + transpose) — at 10k keys
-    # that is ~9 GB of transient HBM on top of the 3.3 GB result,
-    # within OOM distance of a 16 GB chip. Chunking bounds the
-    # transient to ~0.9 GB/launch; chunks concatenate on device and
-    # the per-key row blocks are contiguous, so the flat row-gather
-    # indexing is unchanged.
+    # copies of its output (scan rows + pad + transpose) — unchunked
+    # at 10k keys that is ~9 GB of transient HBM on top of the 3.3 GB
+    # result. Chunking bounds it: compiled for the v5e at 2,048 keys
+    # the builder has a 651 MB output and 1.9 GB of temporaries
+    # (tests/test_chip_compile.py). Chunks land in one preallocated
+    # buffer and the per-key row blocks are contiguous, so the flat
+    # row-gather indexing is unchanged.
     BUILD_CHUNK = 2048
 
     def __init__(self, pubkeys: list[bytes]):
@@ -605,7 +635,7 @@ class ExpandedKeys:
         ((V*69*9, 128) rows, (V,) ok). Builder launches run on the
         default device (BUILD_CHUNK bounds their transients); with
         `device` set, each chunk's rows move to that device as they
-        land and the concatenation happens THERE — the sharded build's
+        land and the table is assembled THERE — the sharded build's
         per-range blocks must not pile up on the default device."""
         import jax.numpy as jnp
 
@@ -621,25 +651,30 @@ class ExpandedKeys:
             tv.count_compile("table_builder", (v,))
             t, o = _builder()(jnp.asarray(a_raw))
             return park(t), o
-        # Pad to a chunk multiple (one compiled shape), build each
-        # chunk, concatenate on device. Padding keys are never
-        # addressed: verify() asserts idx < len(pubkeys).
+        # Every launch builds one full chunk (one compiled shape; the
+        # last is zero-padded) and its rows are written into ONE
+        # preallocated buffer, each chunk freed as it is consumed:
+        # the peak is the table + one chunk + the builder's
+        # temporaries. The padding keys' rows are dropped before they
+        # land (up to chunk-1 keys x ~318 KB each would otherwise sit
+        # in HBM — replicated per mesh chip — for the cache lifetime).
         chunk = self.BUILD_CHUNK
-        vp = (v + chunk - 1) // chunk * chunk
-        padded = np.zeros((vp, 32), np.uint8)
-        padded[:v] = a_raw
-        t_parts, ok_parts = [], []
+        rows_per_key = _WINDOWS * _ENTRIES
+        tables, ok_parts = None, []
         tv.count_compile("table_builder", (chunk,))
-        for s in range(0, vp, chunk):
-            t, o = _builder()(jnp.asarray(padded[s:s + chunk]))
-            t_parts.append(park(t))
+        for s in range(0, v, chunk):
+            here = min(chunk, v - s)
+            keys = np.zeros((chunk, 32), np.uint8)
+            keys[:here] = a_raw[s:s + here]
+            t, o = _builder()(jnp.asarray(keys))
+            if here < chunk:
+                t = t[: here * rows_per_key]
+            t = park(t)
+            if tables is None:
+                tables = jnp.zeros((v * rows_per_key, _ROW), t.dtype,
+                                   device=device)
+            tables = _placer()(tables, t, np.int32(s * rows_per_key))
             ok_parts.append(o)
-        tables = jnp.concatenate(t_parts, axis=0)
-        if vp != v:
-            # drop the padding keys' rows (up to chunk-1 keys ×
-            # ~318 KB each would otherwise sit in HBM — and be
-            # replicated per mesh chip — for the cache lifetime)
-            tables = tables[: v * _WINDOWS * _ENTRIES]
         ok = jnp.concatenate(ok_parts)[:v]
         return tables, ok
 
@@ -905,7 +940,7 @@ class ExpandedKeys:
         """Shared span choreography for both verify forms: one
         crypto.verify parent with pack (host prep) / dispatch (launch
         enqueue) / device_exec (wait-until-ready) / readback (D2H
-        copy) children — the stage taxonomy BENCH's stage_breakdown
+        copy) children — the stage vocabulary BENCH's stage_breakdown
         and /debug/trace report. `prepare` returns (launch_args,
         well_formed); `launch(*launch_args)` returns the device
         verdict array. One launch-ledger record per call, its stages
@@ -1083,8 +1118,9 @@ _BUILDS: dict[bytes, threading.Event] = {}
 def max_keys() -> int:
     """Largest valset the expanded tables serve on this backend.
 
-    Accelerators: the single-chip HBM budget (~318 KB/key, ~40k keys
-    on a 16 GB chip) times the mesh size — above the shard crossover
+    Accelerators: the single-chip table budget (_single_chip_max_keys:
+    a share of the memory the device reports, ~39k keys on a 16 GB
+    chip) times the mesh size — above the shard crossover
     the tables row-shard by key range across devices, so an N-chip
     mesh serves N × the single-chip cap. CPU backend (tests / e2e
     nets / degraded nodes): one build chunk regardless of the virtual

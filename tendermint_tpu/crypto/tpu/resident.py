@@ -1,10 +1,10 @@
 """ResidentArena: persistent device-resident verify buffers reused
 across launches via donated args.
 
-Round-4/5 silicon showed the general verify path re-ships ~100 B/lane
-(32 B pubkey + 64 B signature + per-lane sign-byte patches) on EVERY
-launch — at 10,240 lanes through the relay that transfer term, not the
-kernel, dominates end-to-end latency (docs/PERF_NOTES.md). In
+The general verify path re-ships ~100 B/lane (32 B pubkey + 64 B
+signature + per-lane sign-byte patches) on EVERY launch — ~1 MB of
+host->device transfer per 10,240-lane commit whose share of end-to-end
+latency is not measured yet (docs/PERF_NOTES.md). In
 consensus the inputs barely change between launches: the pubkeys are
 the validator set (changes only on ABCI valset updates), and between
 two speculative launches of the same height only the lanes whose

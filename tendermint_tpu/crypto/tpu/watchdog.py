@@ -3,10 +3,10 @@
 The configured backend (`[crypto] backend` in the node config) says
 what the operator believes; the launch ledger says what actually
 happened. This module closes the loop: it classifies the effective
-backend from recent ledger records and turns a wedged relay — the
-exact failure that let BENCH_r04/r05 run two full rounds on TFRT_CPU_0
-unnoticed — into a named, alerting `/status` condition within ONE
-launch.
+backend from recent ledger records and turns launches that land on
+the host while a chip was promised — a run on TFRT_CPU_0 that nothing
+in the process could name — into a named, alerting `/status`
+condition within ONE launch.
 
 Classification (crypto/tpu/backend.py EFFECTIVE_STATES):
 
@@ -25,9 +25,11 @@ when any of these hold:
     raising);
   * records exist but no successful launch completed within the
     window (`crypto.watchdog_window_s`);
-  * device exec p50 over the window's silicon launches drifts more
-    than DRIFT_FACTOR x past the recorded silicon baseline
-    (docs/measured_silicon.json headline device_exec_ms_per_launch);
+  * an operator gave a device exec baseline
+    (TM_TPU_SILICON_BASELINE_MS) and the p50 over the window's
+    silicon launches drifts more than DRIFT_FACTOR x past it — the
+    repo records no baseline of its own, so without the variable
+    there is no drift check;
   * any chip's registered HBM-resident bytes exceed its capacity
     budget.
 
@@ -42,7 +44,6 @@ bring-up.
 
 from __future__ import annotations
 
-import json
 import os
 import threading
 
@@ -101,29 +102,13 @@ def window_s() -> float:
     return _WINDOW_S
 
 
-def _baseline_path() -> str:
-    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "..", "..", "..", "docs",
-                        "measured_silicon.json")
-
-
 def silicon_baseline_ms() -> float | None:
     """Device exec ms/launch the drift check compares against: the
-    TM_TPU_SILICON_BASELINE_MS env (tests; operator override), else
-    the recorded headline bench in docs/measured_silicon.json."""
+    operator's TM_TPU_SILICON_BASELINE_MS, or None (no drift check)."""
     env = os.environ.get("TM_TPU_SILICON_BASELINE_MS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
     try:
-        with open(_baseline_path()) as f:
-            doc = json.load(f)
-        entry = doc.get("entries", {}).get("headline_bench", {})
-        v = entry.get("device_exec_ms_per_launch")
-        return float(v) if v is not None else None
-    except (OSError, ValueError, AttributeError):
+        return float(env) if env else None
+    except ValueError:
         return None
 
 
@@ -251,7 +236,7 @@ def verdict() -> dict:
             out["baseline_ms"] = base
             out["reason"] = (
                 f"device exec p50 {p50} ms drifted >"
-                f"{DRIFT_FACTOR:g}x past the recorded silicon "
+                f"{DRIFT_FACTOR:g}x past the operator's silicon "
                 f"baseline {base} ms")
     # state "unknown" (nothing ever launched) stays ok: a freshly
     # booted node that hasn't verified yet is not degraded.

@@ -1,4 +1,4 @@
-"""Light client error taxonomy (reference: light/errors.go)."""
+"""Light client error classes (reference: light/errors.go)."""
 
 from __future__ import annotations
 

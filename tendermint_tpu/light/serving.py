@@ -33,7 +33,7 @@ RPC surface (one or many workers — ServingPool) and the light
     the ``light.verify`` failpoint's `delay` shape is the proof).
 
 The plane preserves the Client's verification semantics exactly —
-same bisection pivots, same error taxonomy, same witness
+same bisection pivots, same error classes, same witness
 cross-checking after the target verifies, same trusted-store writes —
 only the signature work is pooled.
 """
@@ -352,6 +352,7 @@ class LightVerifyCollector:
                     # re-verifies on host instead of failing client
                     # requests on headers that are actually valid
                     spub, smsg, ssig = cbatch._ed_probe_triple()
+                    from ..crypto.tpu import backend as tpu_backend
                     from ..crypto.tpu import ledger as tpu_ledger
 
                     with tpu_ledger.workload("light"):
@@ -366,8 +367,8 @@ class LightVerifyCollector:
                     # to the host path as ONE host launch, never
                     # device+host for the same flush
                     met.verify_launches.inc(backend="device")
-                    crypto_metrics().batch_lanes.inc(len(ed),
-                                                     backend="tpu")
+                    crypto_metrics().batch_lanes.inc(
+                        len(ed), backend=tpu_backend.platform())
                     if dv[-1]:
                         out[np.asarray(ed)] = dv[:-1]
                         return out
@@ -618,7 +619,7 @@ class ServingPlane:
                                now_ns: int) -> None:
         """Client._verify_skipping with the commit checks routed
         through the coalescing collector: same pivots, same error
-        taxonomy, same store writes."""
+        classes, same store writes."""
         cl = self.client
         pending: list[LightBlock] = [target]
         seen: set[int] = {target.height()}
@@ -710,7 +711,7 @@ class ServingPlane:
             self.collector.check(plan_trusting),
             self.collector.check(plan_light),
             return_exceptions=True)
-        # error taxonomy parity with verifier.verify_non_adjacent: a
+        # error-class parity with verifier.verify_non_adjacent: a
         # failed TRUSTING check (insufficient overlap OR bad overlap
         # signature) drives bisection; a failed own-commit check is a
         # definitive rejection; anything else (shed, cancellation)

@@ -231,6 +231,7 @@ class AdmissionCollector:
             use_dev = want_dev and cbatch.breaker("ed25519").acquire()
             if use_dev:
                 try:
+                    from ..crypto.tpu import backend as tpu_backend
                     from ..crypto.tpu import verify as tpu_verify
 
                     failpoints.hit("device.verify")
@@ -259,7 +260,8 @@ class AdmissionCollector:
                             [e.signature for e in envs] + [ssig]),
                             bool)
                     met.launches.inc(backend="device")
-                    crypto_metrics().batch_lanes.inc(n, backend="tpu")
+                    crypto_metrics().batch_lanes.inc(
+                        n, backend=tpu_backend.platform())
                     if out[-1]:
                         return out[:n]
                     # sentinel mismatch: wrong-verdict device (the

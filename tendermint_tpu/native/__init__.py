@@ -1,13 +1,15 @@
 """Native (C) runtime components, loaded via ctypes.
 
 Build-on-first-use: cc -O3 -shared compiles the sibling .c into a
-cached .so (atomic rename, concurrent-build safe). Everything here is
+cached .so named by the source's hash (atomic rename,
+concurrent-build safe). Everything here is
 OPTIONAL — callers keep a pure-numpy fallback, so a box without a C
 compiler still runs, just with more host time per batch."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import shutil
@@ -26,17 +28,16 @@ _tried = False
 
 def _build_so() -> str | None:
     src = os.path.join(_DIR, "pack.c")
-    so = os.path.join(_DIR, "_pack.so")
     try:
-        if os.path.exists(so) and \
-                os.path.getmtime(so) >= os.path.getmtime(src):
-            return so
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:12]
     except OSError:
-        # .so present but source missing (prebuilt deployment):
-        # the cached binary is all we need
-        return so if os.path.exists(so) else None
-    if not os.path.exists(src):
         return None
+    # named by the source's hash: a binary is only ever reused for the
+    # pack.c it was built from (mtimes do not survive a copy)
+    so = os.path.join(_DIR, f"_pack_{digest}.so")
+    if os.path.exists(so):
+        return so
     cc = shutil.which("cc") or shutil.which("gcc") or shutil.which("clang")
     if cc is None:
         return None

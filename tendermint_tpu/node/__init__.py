@@ -202,6 +202,12 @@ class Node(Service):
 
         _watchdog.configure(cfg.crypto.backend,
                             cfg.crypto.watchdog_window_s)
+        if cfg.crypto.backend == "tpu":
+            # binding: a node promised a chip never serves from the
+            # host; "auto" and "cpu" take whatever backend JAX has
+            from ..crypto.tpu import backend as _tpu_backend
+
+            _tpu_backend.require_tpu()
         if cfg.crypto.ledger_capacity != _ledger.capacity():
             _ledger.set_capacity(cfg.crypto.ledger_capacity)
         self.block_store = BlockStore(_db(cfg, "blockstore",

@@ -147,9 +147,10 @@ def test_light_command_once(tmp_path, capsys):
             shim.state = a.cs.state
             shim.node_key = a.node_key
             shim.genesis_doc = a.gdoc
+            from tendermint_tpu.config import RPCConfig
+
             shim.config = type("C", (), {"base": type(
-                "B", (), {"moniker": "shim"})(), "rpc": type(
-                "R", (), {"max_subscriptions_per_client": 5})()})()
+                "B", (), {"moniker": "shim"})(), "rpc": RPCConfig()})()
             shim.consensus_state = a.cs
             shim.bc_reactor = a.bc_reactor
             shim.priv_validator = None

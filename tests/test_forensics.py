@@ -213,17 +213,41 @@ def test_debug_trace_height_filter_anchor_and_rollup_meta():
 # ------------------------------------------------ bench_trend gate
 
 
-def test_bench_trend_classifies_repo_rounds():
-    """Over the repo's own BENCH_r*.json: r01 (TPU v5 lite) is the
-    only silicon round, r04/r05 (TFRT_CPU fallback) sit on the
-    cpu_fallback trajectory, r02/r03 (crashed/timed-out, parsed=null)
-    are no-data — and none is misrepresented, so --check passes."""
+def test_bench_trend_classifies_repo_rounds(tmp_path):
+    """Five recorded rounds of the shapes the driver has held: r01 (a
+    TPU v5 lite device string, no stamps — the oldest format) is the
+    only silicon round, r04/r05 (TFRT_CPU_0, cpu_fallback flag) sit on
+    the cpu_fallback trajectory, r02/r03 (crashed/timed-out,
+    parsed=null) are no-data — and none is misrepresented, so --check
+    passes."""
     from tools import bench_trend
 
-    paths = sorted(
-        os.path.join(REPO, f) for f in os.listdir(REPO)
-        if f.startswith("BENCH_r") and f.endswith(".json"))
-    assert len(paths) >= 5
+    def parsed(device, value, cpu_us, **extra):
+        return {"metric": "ed25519_commit_verify_p50_10k_vals",
+                "value": value, "unit": "ms", "device": device,
+                "cpu_baseline_us_per_sig": cpu_us,
+                "baseline_estimated": False, **extra}
+
+    cpu = dict(batch=1024, expanded_valset=True, cpu_fallback=True,
+               error="no TPU measurement: backend init exceeded 75s")
+    rounds = {
+        1: dict(rc=0, parsed=parsed(
+            "TPU v5 lite0", 804.271, 109.7, vs_baseline=1.4,
+            sigs_per_sec=12732, batch=10240)),
+        2: dict(rc=1, parsed=None),
+        3: dict(rc=124, parsed=None),
+        4: dict(rc=0, parsed=parsed(
+            "TFRT_CPU_0", 1156.067, 125.1, vs_baseline=0.11,
+            sigs_per_sec=886, **cpu)),
+        5: dict(rc=0, parsed=parsed(
+            "TFRT_CPU_0", 979.134, 147.6, vs_baseline=0.15,
+            sigs_per_sec=1046, **cpu)),
+    }
+    for n, entry in rounds.items():
+        (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "tail": "", **entry}))
+    paths = sorted(str(p) for p in tmp_path.glob("BENCH_r*.json"))
+    assert len(paths) == 5
     rows = bench_trend.load_rounds(paths)
     by_file = {r["file"]: r for r in rows}
     assert by_file["BENCH_r01.json"]["backend"] == "silicon"
@@ -235,7 +259,7 @@ def test_bench_trend_classifies_repo_rounds():
     # silicon and fallback chains never cross: r01 (804ms on TPU) vs
     # r04 (1156ms on CPU) is NOT a regression, and r04 -> r05 improved
     assert bench_trend.find_regressions(rows) == []
-    assert bench_trend.main(["--check", REPO]) == 0
+    assert bench_trend.main(["--check", str(tmp_path)]) == 0
 
 
 def test_bench_trend_rejects_misrepresented_fallback(tmp_path, capsys):

@@ -177,14 +177,14 @@ def test_general_kernel_raise_records_and_propagates(monkeypatch):
     monkeypatch.setattr(tv, "b_comb_tables", _fake_btab)
 
     def boom():
-        raise RuntimeError("relay wedged")
+        raise RuntimeError("device lost")
 
     monkeypatch.setattr(tv, "_kernel", boom)
     with pytest.raises(RuntimeError):
         tv.verify_batch([bytes(32)], [b"m"], [bytes(64)])
     r = ledger.snapshot()[-1]
     assert r["verdict"] == "raised"
-    assert "relay wedged" in r["error"]
+    assert "device lost" in r["error"]
 
 
 def test_expanded_traced_verify_records():
@@ -361,7 +361,7 @@ def test_watchdog_degrades_within_one_launch_and_recovers():
     assert dv["status"] == "ok"
     assert dv["effective_backend"] == "unknown"
 
-    # ONE launch landing on CPU (the wedged-relay shape)
+    # ONE launch landing on CPU (a chip was promised)
     _fake_record(device=CPU_DEV)
     dv = mon.status()["checks"]["device"]
     assert dv["status"] == "degraded"

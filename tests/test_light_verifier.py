@@ -3,7 +3,7 @@ light/verifier_test.go table rows this suite pins exactly at the
 edge): trusting-period expiry AT the boundary instant, max-clock-drift
 AT the boundary instant, non-monotonic header time rejection, and
 `NewValSetCantBeTrustedError` driving the client's bisection (the
-serving plane routes the same taxonomy — test_light_serving.py holds
+serving plane routes the same error classes — test_light_serving.py holds
 the plane-side parity test).
 
 Everything here runs on MockPV/ref-ed25519 fixtures; the one test

@@ -254,3 +254,36 @@ def test_remote_signer_node(tmp_path):
             sidecar.cancel()
 
     run(go())
+
+
+def test_crypto_backend_tpu_is_binding_and_auto_starts(tmp_path):
+    """`[crypto] backend = "tpu"` refuses to start on a backend that
+    is not a TPU (the tests' CPU mesh) with an error that says why;
+    `auto` on the same home starts and commits."""
+    import pytest
+
+    async def go():
+        gdoc, pvs = single_val_genesis()
+        cfg = make_home(tmp_path, "n0", gdoc)
+        pv = pvs[0]
+        pv.key_path = cfg.base.resolve(cfg.base.priv_validator_key_file)
+        pv.state_path = cfg.base.resolve(cfg.base.priv_validator_state_file)
+        pv.save_key()
+
+        cfg.crypto.backend = "tpu"
+        node = Node.default_new_node(cfg)
+        with pytest.raises(RuntimeError) as e:
+            await node.start()
+        msg = str(e.value)
+        assert 'backend = "tpu"' in msg and "'cpu'" in msg
+        assert "refusing to start" in msg
+
+        cfg.crypto.backend = "auto"
+        node = Node.default_new_node(cfg)
+        await node.start()
+        try:
+            await node.consensus_state.wait_for_height(2, timeout=60)
+        finally:
+            await node.stop()
+
+    run(go())

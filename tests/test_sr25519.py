@@ -256,8 +256,12 @@ def test_batch_verifier_routes_sr25519_to_device():
     batch_mod.reset_breakers()  # clear any breaker state from
     # earlier tests — this test is about routing, not degradation
     n = batch_mod._DEVICE_THRESHOLD_SR + 16
-    lanes_before = crypto_metrics().batch_lanes.value(
-        backend="tpu-sr25519")
+    # lanes are labelled with the platform that ran them
+    from tendermint_tpu.crypto.tpu import backend as tpu_backend
+
+    label = f"{tpu_backend.platform()}-sr25519"
+    assert label == "cpu-sr25519"
+    lanes_before = crypto_metrics().batch_lanes.value(backend=label)
     minis = [hashlib.sha256(b"rt%d" % i).digest() for i in range(n)]
     bv = BatchVerifier()
     for i, mini in enumerate(minis):
@@ -272,5 +276,5 @@ def test_batch_verifier_routes_sr25519_to_device():
     want = np.ones(n, bool)
     want[9] = False
     assert (verdicts == want).all()
-    assert (crypto_metrics().batch_lanes.value(backend="tpu-sr25519")
+    assert (crypto_metrics().batch_lanes.value(backend=label)
             == lanes_before + n), "sr25519 lanes did not take the device path"

@@ -143,7 +143,7 @@ def _mesh_ab(batch: int, evict: int = 0) -> int:
     )
     from tendermint_tpu.types import sign_batch as sbm
 
-    from tools.silicon_record import backend_label
+    from tendermint_tpu.crypto.tpu.backend import backend_label
 
     device = str(jax.devices()[0])
     line = {
@@ -281,11 +281,6 @@ def _mesh_ab(batch: int, evict: int = 0) -> int:
         "max_shard_delta_bytes": int(max(per_dev)),
     }
     line["ok"] = True
-    if "--record" in sys.argv:
-        from tools import silicon_record
-
-        line["recorded"] = silicon_record.record_if_tpu(
-            "crypto_bench_mesh", device, dict(line))
     print(json.dumps(line), flush=True)
     return 0
 
@@ -303,13 +298,12 @@ def main():
                 + f" --xla_force_host_platform_device_count={mesh_n}"
             ).strip()
         if not os.environ.get("GRAFT_REAL_DEVICES"):
-            from tendermint_tpu.libs.cpuforce import force_cpu_backend
-
-            force_cpu_backend()
+            os.environ["JAX_PLATFORMS"] = "cpu"
     if "--cpu" in sys.argv:
-        from tendermint_tpu.libs.cpuforce import force_cpu_backend
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from tendermint_tpu.libs import jaxcache
 
-        force_cpu_backend()
+    jaxcache.configure()
     batch = 1024
     evict = 0
     for i, a in enumerate(sys.argv):
@@ -390,16 +384,6 @@ def main():
     width = max(len(r[0]) for r in rows)
     for name, secs in rows:
         print(f"{name:<{width}}  {secs * 1e6:>12.1f} us")
-
-    if "--record" in sys.argv:
-        from tools import silicon_record
-
-        payload = {"device": device, "batch": batch,
-                   "n_devices": jax.device_count()}
-        payload.update(
-            {name: round(secs * 1e6, 2) for name, secs in rows})
-        print("recorded ->", silicon_record.record_if_tpu(
-            "crypto_bench_us", device, payload))
 
 
 if __name__ == "__main__":

@@ -9,8 +9,8 @@ Concurrent-serving mode (`--clients N`) drives the light SERVING PLANE
 out over `--span` distinct heights in two waves (cold, then warm), and
 the run emits a BENCH-style JSON line — requests/s, verify launches by
 backend, mean lanes per launch, cache hit ratio, coalesce count — so
-the serving plane enters the perf trajectory alongside the BENCH_r0*
-records:
+the serving plane enters the perf trajectory alongside bench.py's
+lines:
 
     python tools/light_bench.py --cpu --clients 64 --span 8
 """
@@ -170,9 +170,10 @@ def serving_bench(n_clients: int, n_heights: int, n_vals: int,
 
 def main():
     if "--cpu" in sys.argv:
-        from tendermint_tpu.libs.cpuforce import force_cpu_backend
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from tendermint_tpu.libs import jaxcache
 
-        force_cpu_backend()
+    jaxcache.configure()
     n_heights, n_vals, n_clients, span = 64, 32, 0, 8
     for i, a in enumerate(sys.argv):
         if a == "--heights":

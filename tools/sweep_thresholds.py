@@ -50,9 +50,10 @@ def main():
         elif a == "--sr-sizes":
             SR_SIZES = [int(x) for x in sys.argv[i + 1].split(",")]
     if cpu:
-        from tendermint_tpu.libs.cpuforce import force_cpu_backend
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    from tendermint_tpu.libs import jaxcache
 
-        force_cpu_backend()
+    jaxcache.configure()
     import jax
 
     device = str(jax.devices()[0])
@@ -202,29 +203,6 @@ def main():
         f.write("\nRaw JSON:\n\n```json\n"
                 + json.dumps(results, indent=1) + "\n```\n")
     print(f"wrote {out_path}")
-
-    if "--record" in sys.argv:
-        from tools import silicon_record
-
-        flat = {"device": device}
-        for n in SIZES:
-            r = results["ed25519"][n]
-            flat[f"ed25519_n{n}_general_ms"] = r["general_ms"]
-            flat[f"ed25519_n{n}_expanded_ms"] = r["expanded_ms"]
-            flat[f"ed25519_n{n}_host_ms"] = r["host_ms"]
-        for wpi, ms in results["ed25519"].get(
-                "windows_per_iter_ms", {}).items():
-            flat[f"wpi{wpi}_ms"] = ms
-        for n in SR_SIZES:
-            r = results["sr25519"][n]
-            flat[f"sr25519_n{n}_device_ms"] = r["device_ms"]
-            flat[f"sr25519_n{n}_host_ms"] = r["host_ms"]
-        flat["sr25519_host_ms_per_sig"] = \
-            results["sr25519"]["host_ms_per_sig"]
-        for k, v in results["recommend"].items():
-            flat[f"recommend{k if k.startswith('_') else '_' + k}"] = v
-        print("recorded ->", silicon_record.record_if_tpu(
-            "threshold_sweep", device, flat))
 
 
 if __name__ == "__main__":
