@@ -1,0 +1,11 @@
+"""`python -m pytest benchmark/tests` — run by hand, on the CPU; not
+part of the repo's tier-1 tests."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
