@@ -53,33 +53,38 @@ def _batch_verify_window(vals, chain_id: str, items):
     lanes_all: list[int] = []
     sigs_all: list[bytes] = []
     per_commit: list[tuple] = []  # (commit, slots) per verifiable block
-    for i, (bid, height, commit) in enumerate(items):
-        start = len(lanes_all)
-        try:
-            vals._check_commit_basics(bid, height, commit)
-            need = 2 * vals.total_voting_power()
-            tallied = 0
-            slots: list[int] = []
-            for idx, cs in enumerate(commit.signatures):
-                if not cs.for_block():
-                    continue
-                val = vals.validators[idx]
-                lanes_all.append(idx)
-                slots.append(idx)
-                sigs_all.append(cs.signature)
-                tallied += val.voting_power
-                if 3 * tallied > need:
-                    break
-            if 3 * tallied <= need:
-                raise VerificationError(
-                    f"insufficient voting power at height {height}")
-            spans.append((i, start, len(lanes_all)))
-            per_commit.append((commit, slots))
-        except Exception as e:
-            results[i] = e
-            # roll back this block's lanes
-            del lanes_all[start:]
-            del sigs_all[start:]
+    with tracing.TRACER.span(tracing.VERIFY_COLLECT, blocks=len(items)):
+        for i, (bid, height, commit) in enumerate(items):
+            start = len(lanes_all)
+            try:
+                vals._check_commit_basics(bid, height, commit)
+                need = 2 * vals.total_voting_power()
+                tallied = 0
+                slots: list[int] = []
+                for idx, cs in enumerate(commit.signatures):
+                    if not cs.for_block():
+                        continue
+                    val = vals.validators[idx]
+                    lanes_all.append(idx)
+                    slots.append(idx)
+                    sigs_all.append(cs.signature)
+                    tallied += val.voting_power
+                    if 3 * tallied > need:
+                        break
+                if 3 * tallied <= need:
+                    raise VerificationError(
+                        f"insufficient voting power at height {height}")
+                spans.append((i, start, len(lanes_all)))
+                per_commit.append((commit, slots))
+            except Exception as e:
+                results[i] = e
+                # roll back this block's lanes
+                del lanes_all[start:]
+                del sigs_all[start:]
+    window = tracing.TRACER.current()
+    if window is not None and window.kind == tracing.VERIFY_WINDOW:
+        # the pipeline's job span learns its lane count here
+        window.set_attr("lanes", len(lanes_all))
     if not lanes_all:
         return results
 
@@ -106,15 +111,17 @@ def _window_lane_verdicts(vals, chain_id, lanes_all, sigs_all, per_commit):
     ValidatorSet._batch_verify_lanes — one copy for every call site."""
     from ..types.sign_batch import CommitSignBatch, MergedSignBatch
 
-    msgs = vals.structured_or_bytes(
-        lanes_all,
-        lambda: MergedSignBatch([
-            CommitSignBatch(chain_id, c, slots)
-            for c, slots in per_commit
-        ]),
-        lambda: [c.vote_sign_bytes(chain_id, s)
-                 for c, slots in per_commit for s in slots],
-    )
+    with tracing.TRACER.span(tracing.VERIFY_SIGN_BATCH,
+                             lanes=len(lanes_all)):
+        msgs = vals.structured_or_bytes(
+            lanes_all,
+            lambda: MergedSignBatch([
+                CommitSignBatch(chain_id, c, slots)
+                for c, slots in per_commit
+            ]),
+            lambda: [c.vote_sign_bytes(chain_id, s)
+                     for c, slots in per_commit for s in slots],
+        )
     from ..crypto.tpu import ledger as tpu_ledger
 
     with tpu_ledger.workload("fastsync"):
@@ -178,9 +185,11 @@ class WindowPipeline:
         off the event loop) and batch-verify. Returns (items,
         parts_list, results) so the consumer — prefetch hit or not —
         reuses both instead of re-serializing the window."""
-        items, parts_list = window_items(blocks)
-        return (items, parts_list,
-                _batch_verify_window(vals, chain_id, items))
+        with tracing.TRACER.span(tracing.VERIFY_WINDOW,
+                                 blocks=len(blocks) - 1):
+            items, parts_list = window_items(blocks)
+            return (items, parts_list,
+                    _batch_verify_window(vals, chain_id, items))
 
     @staticmethod
     def _retrieve_stale(fut) -> None:

@@ -232,8 +232,14 @@ class ConsensusState(Service):
         # and a height must never parent under a vote batch.
         self._ht_span = t.begin(tracing.CONSENSUS_HEIGHT,
                                 parent=tracing.NOOP_SPAN, height=height)
+        # RoundStepNewHeight: from here to round 0 the node waits out
+        # timeout_commit; the first _new_step (propose) seals it, so
+        # the wait and the height's work no longer share one label
+        self._step_span = t.begin(tracing.CONSENSUS_NEW_HEIGHT,
+                                  parent=self._ht_span, height=height)
         if self.trace_node:
             self._ht_span.set_attr("node", self.trace_node)
+            self._step_span.set_attr("node", self.trace_node)
 
     def reconstruct_last_commit(self) -> None:
         """Rebuild rs.last_commit from the stored seen commit

@@ -228,52 +228,67 @@ def _xcore(wpi: int = WINDOWS_PER_ITER):
     L = fe.NLIMB  # payload layout: 4 coords of L limbs per table row
 
     def core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab):
+        # The phase scopes follow the order the operations are traced
+        # in (gather and msm open twice): moving one would change the
+        # program and with it every cached executable.
         n = idx.shape[0]
-        # Pubkey bytes gathered from the device-resident key array —
-        # the host sends (N,) indices, not (N, 32) pubkey rows.
-        ab = jnp.take(akeys, idx, axis=0)
-        # SHA-512(R || A || M) + fold, exactly as the general kernel.
-        full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
-        digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
-        digk = sc.fold_digest(sh.digest_bytes_le(digest))[::-1]  # LSB-first
-        # Signed recode: nibbles (0..15) -> digits in [-8, 8] with
-        # binary carries LSB -> MSB (nib + c >= 8 emits). The folded
-        # value is < 2^271 so nibble 68 is 0 and the final carry is
-        # absorbed (d_68 <= 1). Log-depth carry lookahead instead of a
-        # 69-step sequential scan (fixed launch latency).
-        from . import field as _field
+        with jax.named_scope(tv.PHASE_GATHER):
+            # Pubkey bytes gathered from the device-resident key array
+            # — the host sends (N,) indices, not (N, 32) pubkey rows.
+            ab = jnp.take(akeys, idx, axis=0)
+        with jax.named_scope(tv.PHASE_SHA512):
+            # SHA-512(R || A || M) + fold, exactly as the general
+            # kernel.
+            full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
+            digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
+            digk = sc.fold_digest(
+                sh.digest_bytes_le(digest))[::-1]  # LSB-first
+            # Signed recode: nibbles (0..15) -> digits in [-8, 8] with
+            # binary carries LSB -> MSB (nib + c >= 8 emits). The
+            # folded value is < 2^271 so nibble 68 is 0 and the final
+            # carry is absorbed (d_68 <= 1). Log-depth carry lookahead
+            # instead of a 69-step sequential scan (fixed launch
+            # latency).
+            from . import field as _field
 
-        cin, _ = _field.carry_lookahead(digk >= 8, digk >= 7)
-        t = digk + cin.astype(jnp.int32)
-        digk = t - 16 * (t >= 8).astype(jnp.int32)
-        sig_bytes = sb.astype(jnp.int32).T  # (64, N)
-        digs = sc.bytes_to_nibbles(sig_bytes[32:])  # (64, N) LSB-first
-        digs = jnp.concatenate(
-            [digs, jnp.zeros((_WINDOWS - 64, n), jnp.int32)], axis=0
-        )
-        # R decompression (per-signature; the only uncacheable curve work).
-        r_sign = sig_bytes[31] >> 7
-        r_top = (sig_bytes[31] & 0x7F)[None]
-        r_y = fe.limbs_from_bytes(
-            jnp.concatenate([sig_bytes[:31], r_top]))
-        R, r_ok = ed.decompress(r_y, r_sign)
-        neg_r = ed.neg(R)
+            cin, _ = _field.carry_lookahead(digk >= 8, digk >= 7)
+            t = digk + cin.astype(jnp.int32)
+            digk = t - 16 * (t >= 8).astype(jnp.int32)
+        with jax.named_scope(tv.PHASE_DECOMPRESS):
+            sig_bytes = sb.astype(jnp.int32).T  # (64, N)
+        with jax.named_scope(tv.PHASE_MSM):
+            digs = sc.bytes_to_nibbles(sig_bytes[32:])  # (64, N) LSB-first
+            digs = jnp.concatenate(
+                [digs, jnp.zeros((_WINDOWS - 64, n), jnp.int32)], axis=0
+            )
+        with jax.named_scope(tv.PHASE_DECOMPRESS):
+            # R decompression (per-signature; the only uncacheable
+            # curve work).
+            r_sign = sig_bytes[31] >> 7
+            r_top = (sig_bytes[31] & 0x7F)[None]
+            r_y = fe.limbs_from_bytes(
+                jnp.concatenate([sig_bytes[:31], r_top]))
+            R, r_ok = ed.decompress(r_y, r_sign)
+            neg_r = ed.neg(R)
 
-        # Gather every window's selected entry in ONE flat row-gather.
-        dsign = digk < 0
-        dmag = jnp.abs(digk)  # (69, N) in 0..8
-        flat = (
-            idx[None, :] * (_WINDOWS * _ENTRIES)
-            + jnp.arange(_WINDOWS, dtype=jnp.int32)[:, None] * _ENTRIES
-            + dmag
-        )  # (69, N)
-        sel = jnp.take(atab, flat.reshape(-1), axis=0)  # (69*N, 128)
-        # ONE transpose to the kernel's limb-major layout; slicing any
-        # pad ints fuses into it. Doing this per window instead
-        # (69 small transposes out of a lane-major buffer) costs ~60 ms
-        # of device time at 16k lanes — measured, not hypothetical.
-        sel = jnp.transpose(sel.reshape(_WINDOWS, n, _ROW), (0, 2, 1))
-        sel = sel[:, : 4 * L, :]  # (69, 4L, N)
+        with jax.named_scope(tv.PHASE_GATHER):
+            # Gather every window's selected entry in ONE flat
+            # row-gather.
+            dsign = digk < 0
+            dmag = jnp.abs(digk)  # (69, N) in 0..8
+            flat = (
+                idx[None, :] * (_WINDOWS * _ENTRIES)
+                + jnp.arange(_WINDOWS, dtype=jnp.int32)[:, None] * _ENTRIES
+                + dmag
+            )  # (69, N)
+            sel = jnp.take(atab, flat.reshape(-1), axis=0)  # (69*N, 128)
+            # ONE transpose to the kernel's limb-major layout; slicing
+            # any pad ints fuses into it. Doing this per window instead
+            # (69 small transposes out of a lane-major buffer) costs
+            # ~60 ms of device time at 16k lanes — measured, not
+            # hypothetical.
+            sel = jnp.transpose(sel.reshape(_WINDOWS, n, _ROW), (0, 2, 1))
+            sel = sel[:, : 4 * L, :]  # (69, 4L, N)
 
         def one_window(w, acc_a, acc_b):
             e = jax.lax.dynamic_index_in_dim(sel, w, 0, keepdims=False)
@@ -294,17 +309,19 @@ def _xcore(wpi: int = WINDOWS_PER_ITER):
                 acc_a, acc_b = one_window(i * wpi + j, acc_a, acc_b)
             return (acc_a, acc_b)
 
-        acc_a, acc_b = jax.lax.fori_loop(
-            0, _WINDOWS // wpi, body, (ed.identity(n), ed.identity(n))
-        )
-        v = ed.add(ed.add(acc_a, acc_b), neg_r)
-        v = ed.double(ed.double(ed.double(v)))
-        return (
-            ed.is_identity(v)
-            & r_ok
-            & jnp.asarray(s_ok)
-            & key_ok[idx]
-        )
+        with jax.named_scope(tv.PHASE_MSM):
+            acc_a, acc_b = jax.lax.fori_loop(
+                0, _WINDOWS // wpi, body, (ed.identity(n), ed.identity(n))
+            )
+            v = ed.add(ed.add(acc_a, acc_b), neg_r)
+            v = ed.double(ed.double(ed.double(v)))
+        with jax.named_scope(tv.PHASE_COMPARE):
+            return (
+                ed.is_identity(v)
+                & r_ok
+                & jnp.asarray(s_ok)
+                & key_ok[idx]
+            )
 
     return core
 
@@ -354,8 +371,10 @@ def assemble_core():
     SHA-512 padding tail. Shared by `_skernel` (expanded-table path)
     and crypto/tpu/resident.py's arena kernel (general-kernel path
     over device-resident buffers)."""
+    import jax
     import jax.numpy as jnp
 
+    @jax.named_scope(tv.PHASE_ASSEMBLE)
     def assemble(pre, pre_len, suf, suf_len, patch, split, patch_len,
                  group, width):
         j = jnp.arange(width, dtype=jnp.int32)[None, :]       # (1, W)
@@ -445,6 +464,24 @@ def _skernel_sharded(wpi: int = WINDOWS_PER_ITER):
                              patch, split, patch_len, group)
 
     return skernel
+
+
+def _aval(a):
+    """Shape, dtype and (for a device array) placement of a launch
+    argument: what `.lower()` needs in its stead."""
+    import jax
+
+    return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                sharding=getattr(a, "sharding", None))
+
+
+def _count_compile(kernel: str, shape: tuple) -> None:
+    """tv.count_compile, its answer put on the launch record open
+    around this call (the ledger's compile_cache hit|miss)."""
+    hit = tv.count_compile(kernel, shape)
+    rec = _ledger.current()
+    if rec is not None:
+        rec.compile_hit = hit
 
 
 class _RoutedVerdicts:
@@ -893,7 +930,7 @@ class ExpandedKeys:
         """Device side of verify: one kernel launch over packed lanes."""
         if self.sharded:
             lidx, routed, btab, _repl_s, slot = self._route(idx, packed)
-            tv.count_compile(
+            _count_compile(
                 "expanded_sharded",
                 (self.n_shards, lidx.shape[1], routed["msg"].shape[2]))
             out = _xkernel_sharded(WINDOWS_PER_ITER)(
@@ -908,8 +945,8 @@ class ExpandedKeys:
         idx, packed, btab = self._shard_args(idx, packed)
         # count at the POST-padding shape: mesh_lane_pad may merge two
         # requested buckets into one compiled shape
-        tv.count_compile("expanded",
-                         (idx.shape[0], packed["msg"].shape[1]))
+        _count_compile("expanded",
+                       (idx.shape[0], packed["msg"].shape[1]))
         return _xkernel(WINDOWS_PER_ITER)(
             idx=idx,
             akeys=self.akeys,
@@ -1053,8 +1090,8 @@ class ExpandedKeys:
             per = {k: v for k, v in fields.items()
                    if k not in self._S_REPL}
             lidx, routed, btab, repl_s, slot = self._route(idx, per)
-            tv.count_compile("structured_sharded",
-                             (self.n_shards, lidx.shape[1], width))
+            _count_compile("structured_sharded",
+                           (self.n_shards, lidx.shape[1], width))
             repl = {k: jax.device_put(fields[k], repl_s)
                     for k in self._S_REPL}
             out = _skernel_sharded(WINDOWS_PER_ITER)(
@@ -1070,16 +1107,44 @@ class ExpandedKeys:
             return _RoutedVerdicts(out, slot)
         idx, fields, btab = self._shard_args(
             idx, fields, repl_keys=self._S_REPL)
-        tv.count_compile("structured", (idx.shape[0], width))
+        _count_compile("structured", (idx.shape[0], width))
         return _skernel(WINDOWS_PER_ITER)(
-            idx=idx,
-            akeys=self.akeys,
-            key_ok=self.key_ok,
-            atab=self.tables,
-            btab=btab,
-            width=width,
-            **fields,
-        )
+            idx=idx, akeys=self.akeys, key_ok=self.key_ok,
+            atab=self.tables, btab=btab, width=width, **fields)
+
+    def _structured_avals(self, bucket: int) -> dict:
+        """What _launch_structured hands the program for `bucket`
+        lanes (the shape after _shard_args' padding), as shapes, dtypes
+        and placements alone: _prepare_structured's layout (templates
+        padded to _S_GROUPS x 128 / 64 B, types/sign_batch.py's
+        per-lane fields) without a batch to prepare."""
+        import jax
+
+        from ...types.sign_batch import PATCH_W
+
+        kp = self._S_GROUPS
+        lane = dict(idx=((), np.int32), sb=((64,), np.uint8),
+                    s_ok=((), np.bool_), patch=((PATCH_W,), np.uint8),
+                    split=((), np.int32), patch_len=((), np.int32),
+                    group=((), np.int32))
+        templ = dict(pre=((128,), np.uint8), pre_len=((), np.int32),
+                     suf=((64,), np.uint8), suf_len=((), np.int32))
+        lane_s = repl_s = None
+        if self.mesh is not None and bucket >= tv._SHARD_MIN:
+            _row_s, lane_s, repl_s = tv._shardings(self.mesh)
+        btab = tv.b_comb_tables()
+        avals = {k: jax.ShapeDtypeStruct((bucket,) + tail, dt,
+                                         sharding=lane_s)
+                 for k, (tail, dt) in lane.items()}
+        avals.update({k: jax.ShapeDtypeStruct((kp,) + tail, dt,
+                                              sharding=repl_s)
+                      for k, (tail, dt) in templ.items()})
+        avals.update(
+            akeys=_aval(self.akeys), key_ok=_aval(self.key_ok),
+            atab=_aval(self.tables),
+            btab=jax.ShapeDtypeStruct(btab.shape, btab.dtype,
+                                      sharding=repl_s))
+        return avals
 
     def verify_structured(self, indices, sbatch, sigs) -> np.ndarray:
         """verify() for commit votes in structured form: identical
@@ -1171,6 +1236,44 @@ def get_expanded(pubkeys: list[bytes]) -> ExpandedKeys:
         with _CACHE_LOCK:
             _BUILDS.pop(key, None)
         ev.set()
+
+
+def structured_phases() -> dict[str, str]:
+    """{instruction: ed25519.* phase} (tv.phase_of_instructions) of the
+    structured program, at the shape this process compiled last and
+    over the tables used last: for whoever lays a profiler trace's
+    device operations on the kernels' phases. A trace names an
+    operation by its optimized-HLO instruction and holds no scope, and
+    an executable loaded from the persistent cache carries the source
+    names of whichever process compiled it first (the cache key leaves
+    metadata out, and stays so: with it in, a line shift in this file
+    would recompile every shape). So the program is lowered once more,
+    from a new function object (jit's in-memory caches would hand back
+    the loaded executable), and compiled under a key that holds its
+    metadata, for this thread and this compile only: a hit there was
+    compiled from these very names. Tens of seconds at a 10,240-lane
+    shape where it misses; nothing on the launch path keeps anything
+    for it."""
+    import jax
+    from jax._src import config as jax_config  # no public per-thread form
+
+    with _CACHE_LOCK:
+        keys = next(reversed(_CACHE.values()), None)
+    shape = next((k[1:] for k in reversed(tv._COMPILED_SHAPES)
+                  if k[0] == "structured"), None)
+    if keys is None or shape is None or keys.sharded:
+        raise ValueError("no structured launch on one chip's tables "
+                         "yet: nothing to map")
+    bucket, width = shape
+    inner = _skernel(WINDOWS_PER_ITER).__wrapped__
+
+    def skernel(**kw):
+        return inner(**kw)
+
+    with jax_config.compilation_cache_include_metadata_in_key(True):
+        compiled = jax.jit(skernel, static_argnames=("width",)).lower(
+            width=width, **keys._structured_avals(bucket)).compile()
+    return tv.phase_of_instructions(compiled.as_text())
 
 
 def warm_async(pubkeys: list[bytes]) -> threading.Thread:

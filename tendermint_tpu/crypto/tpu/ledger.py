@@ -28,7 +28,7 @@ A record is a plain dict:
                        DELTA actually shipped, not the resident bytes)
     bytes_d2h          verdict readback bytes
     compile_cache      hit|miss (verify.count_compile's shape set)
-    stages_ms          queue_wait/pack/dispatch/exec/readback — timed
+    stages_ms          pack/dispatch/exec/readback — timed
                        around the SAME blocks the PR-1 span kinds
                        already bracket (zero new hot-path span sites)
     shard_lanes        per-device lane distribution on the mesh
@@ -70,6 +70,12 @@ _EVICTED = 0
 
 _WORKLOAD: contextvars.ContextVar[str] = contextvars.ContextVar(
     "tm_tpu_launch_workload", default="consensus")
+
+# The record of the `with ledger.launch(...)` block this thread is in:
+# how a launch function called inside the block (ExpandedKeys._launch*)
+# reaches the record its caller opened.
+_OPEN: contextvars.ContextVar["LaunchRecord | None"] = \
+    contextvars.ContextVar("tm_tpu_launch_record", default=None)
 
 
 # ---------------------------------------------------------------- workload
@@ -210,8 +216,8 @@ class LaunchRecord:
         self._restamp = True
 
     def stage(self, name: str) -> _StageCtx:
-        """Time a pipeline stage (pack/dispatch/exec/readback/
-        queue_wait) — wrapped around the SAME blocks the existing
+        """Time a pipeline stage (pack/dispatch/exec/readback) —
+        wrapped around the SAME blocks the existing
         crypto.* spans bracket, so stage attribution and the span
         kinds can never disagree."""
         return _StageCtx(self, name)
@@ -300,15 +306,17 @@ class _LaunchCtx:
     """with ledger.launch("general") as rec: — fail() on exception
     (exception propagates), done() otherwise."""
 
-    __slots__ = ("_rec",)
+    __slots__ = ("_rec", "_token")
 
     def __init__(self, rec: LaunchRecord):
         self._rec = rec
 
     def __enter__(self) -> LaunchRecord:
+        self._token = _OPEN.set(self._rec)
         return self._rec
 
     def __exit__(self, etype, exc, tb) -> bool:
+        _OPEN.reset(self._token)
         if exc is not None:
             self._rec.fail(exc)
         else:
@@ -324,6 +332,11 @@ def begin(kernel: str) -> LaunchRecord:
 
 def launch(kernel: str) -> _LaunchCtx:
     return _LaunchCtx(begin(kernel))
+
+
+def current() -> LaunchRecord | None:
+    """The record of the enclosing `with launch(...)` block, if any."""
+    return _OPEN.get()
 
 
 def _append(record: dict) -> None:

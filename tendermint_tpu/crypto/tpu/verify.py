@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import re
 
 import numpy as np
 
@@ -169,6 +170,43 @@ def s_range_ok(sig_raw: np.ndarray) -> np.ndarray:
     return lt
 
 
+# Phase names of the verify programs: jax.named_scope labels that
+# survive any edit to a kernel (XLA's own fusion.N numbering does not),
+# one vocabulary for the general, expanded and structured programs.
+# phase_of_instructions() maps a compiled program's instructions onto
+# them.
+PHASE_ASSEMBLE = "ed25519.assemble"      # sign bytes built on device
+PHASE_GATHER = "ed25519.gather"          # key bytes + comb-table rows
+PHASE_SHA512 = "ed25519.sha512"          # hash, fold, digit recode
+PHASE_DECOMPRESS = "ed25519.decompress"  # point decompression
+PHASE_MSM = "ed25519.msm"                # the windowed double-scalar mul
+PHASE_COMPARE = "ed25519.compare"        # identity check + lane flags
+PHASES = (PHASE_ASSEMBLE, PHASE_GATHER, PHASE_SHA512, PHASE_DECOMPRESS,
+          PHASE_MSM, PHASE_COMPARE)
+
+_HLO_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = [^\n]*?metadata=\{op_name="([^"]*)"',
+    re.M)
+_PHASE_IN_OP_NAME = re.compile(r"(?<=/)ed25519\.\w+")
+
+
+def phase_of_instructions(hlo_text: str) -> dict[str, str]:
+    """{instruction: phase} of a compiled verify program, from its
+    optimized HLO (`compiled.as_text()`): each instruction whose
+    `op_name` metadata passes through a PHASES scope, under the
+    innermost one. A fusion carries the op_name of its root, so it
+    counts whole under that phase; what the compiler added on its own
+    (copies, bitcasts) has no op_name and is left out. A profiler trace
+    names device operations by these instruction names and holds no
+    op_name of its own, which is why the map comes from here."""
+    out = {}
+    for name, op_name in _HLO_INSTRUCTION.findall(hlo_text):
+        phases = _PHASE_IN_OP_NAME.findall(op_name)
+        if phases:
+            out[name] = phases[-1]
+    return out
+
+
 @functools.cache
 def general_core():
     """The general-kernel verify body as a traceable function of
@@ -186,37 +224,43 @@ def general_core():
     from .fieldsel import F as fe
 
     def kernel(ab, sb, msg, nblocks, s_ok, btab):
+        # The phase scopes follow the order the operations are traced
+        # in (msm opens twice): moving one would change the program and
+        # with it every cached executable.
         n = ab.shape[0]
-        # --- SHA-512 of R || A || M, all lanes at once.
-        full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
-        digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
-        digk = sc.fold_digest(sh.digest_bytes_le(digest))  # (69, N) MSB-first
-        # --- byte rows.
-        a_bytes = ab.astype(jnp.int32).T  # (32, N)
-        sig_bytes = sb.astype(jnp.int32).T  # (64, N)
-        digs = sc.bytes_to_nibbles(sig_bytes[32:])  # (64, N) LSB-first
-        digs = jnp.concatenate(
-            [digs, jnp.zeros((_DIGITS_K - 64, n), jnp.int32)], axis=0
-        )
-        a_sign = a_bytes[31] >> 7
-        r_sign = sig_bytes[31] >> 7
-        a_top = (a_bytes[31] & 0x7F)[None]
-        r_top = (sig_bytes[31] & 0x7F)[None]
-        a_y = fe.limbs_from_bytes(jnp.concatenate([a_bytes[:31], a_top]))
-        r_y = fe.limbs_from_bytes(jnp.concatenate([sig_bytes[:31], r_top]))
+        with jax.named_scope(PHASE_SHA512):
+            # --- SHA-512 of R || A || M, all lanes at once.
+            full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
+            digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
+            digk = sc.fold_digest(
+                sh.digest_bytes_le(digest))  # (69, N) MSB-first
+        with jax.named_scope(PHASE_DECOMPRESS):
+            # --- byte rows.
+            a_bytes = ab.astype(jnp.int32).T  # (32, N)
+            sig_bytes = sb.astype(jnp.int32).T  # (64, N)
+        with jax.named_scope(PHASE_MSM):
+            digs = sc.bytes_to_nibbles(sig_bytes[32:])  # (64, N) LSB-first
+            digs = jnp.concatenate(
+                [digs, jnp.zeros((_DIGITS_K - 64, n), jnp.int32)], axis=0
+            )
+        with jax.named_scope(PHASE_DECOMPRESS):
+            a_sign = a_bytes[31] >> 7
+            r_sign = sig_bytes[31] >> 7
+            a_top = (a_bytes[31] & 0x7F)[None]
+            r_top = (sig_bytes[31] & 0x7F)[None]
+            a_y = fe.limbs_from_bytes(
+                jnp.concatenate([a_bytes[:31], a_top]))
+            r_y = fe.limbs_from_bytes(
+                jnp.concatenate([sig_bytes[:31], r_top]))
 
-        # --- decompress A and R fused at width 2N (halves the number of
-        # expensive sqrt-exponentiation op dispatches).
-        y2 = jnp.concatenate([a_y, r_y], axis=1)
-        s2 = jnp.concatenate([a_sign, r_sign])
-        p2, ok2 = ed.decompress(y2, s2)
-        A = ed.Point(p2.x[:, :n], p2.y[:, :n], p2.z[:, :n], p2.t[:, :n])
-        R = ed.Point(p2.x[:, n:], p2.y[:, n:], p2.z[:, n:], p2.t[:, n:])
-        a_ok, r_ok = ok2[:n], ok2[n:]
-
-        neg_a = ed.neg(A)
-        tbl = ed.build_window_table(neg_a, 16)  # (16, 4, 22, N)
-        neg_r = ed.neg(R)
+            # --- decompress A and R fused at width 2N (halves the
+            # number of expensive sqrt-exponentiation op dispatches).
+            y2 = jnp.concatenate([a_y, r_y], axis=1)
+            s2 = jnp.concatenate([a_sign, r_sign])
+            p2, ok2 = ed.decompress(y2, s2)
+            A = ed.Point(p2.x[:, :n], p2.y[:, :n], p2.z[:, :n], p2.t[:, :n])
+            R = ed.Point(p2.x[:, n:], p2.y[:, n:], p2.z[:, n:], p2.t[:, n:])
+            a_ok, r_ok = ok2[:n], ok2[n:]
 
         def body(w, accs):
             acc_a, acc_b = accs
@@ -229,13 +273,18 @@ def general_core():
             acc_b = ed.add_z1(acc_b, qx, qy, qt)
             return (acc_a, acc_b)
 
-        acc_a, acc_b = jax.lax.fori_loop(
-            0, _DIGITS_K, body, (ed.identity(n), ed.identity(n))
-        )
-        v = ed.add(acc_a, acc_b)
-        v = ed.add(v, neg_r)
-        v = ed.double(ed.double(ed.double(v)))
-        return ed.is_identity(v) & a_ok & r_ok & jnp.asarray(s_ok)
+        with jax.named_scope(PHASE_MSM):
+            neg_a = ed.neg(A)
+            tbl = ed.build_window_table(neg_a, 16)  # (16, 4, 22, N)
+            neg_r = ed.neg(R)
+            acc_a, acc_b = jax.lax.fori_loop(
+                0, _DIGITS_K, body, (ed.identity(n), ed.identity(n))
+            )
+            v = ed.add(acc_a, acc_b)
+            v = ed.add(v, neg_r)
+            v = ed.double(ed.double(ed.double(v)))
+        with jax.named_scope(PHASE_COMPARE):
+            return ed.is_identity(v) & a_ok & r_ok & jnp.asarray(s_ok)
 
     return kernel
 
@@ -486,7 +535,7 @@ def verify_batch(pubs, msgs, sigs) -> np.ndarray:
 # tpu_jit_compiles_total counts THESE — not the once-per-process
 # memoized wrapper builds, which would stay flat through a
 # shape-churn compile storm.
-_COMPILED_SHAPES: set[tuple] = set()
+_COMPILED_SHAPES: dict[tuple, None] = {}
 
 
 def count_compile(kernel: str, shape: tuple) -> bool:
@@ -496,7 +545,7 @@ def count_compile(kernel: str, shape: tuple) -> bool:
     key = (kernel,) + shape
     if key in _COMPILED_SHAPES:
         return True
-    _COMPILED_SHAPES.add(key)
+    _COMPILED_SHAPES[key] = None
     from ...libs.metrics import tpu_metrics
 
     tpu_metrics().jit_compiles.inc(kernel=kernel)

@@ -14,7 +14,10 @@ import bisect
 import logging
 import os
 import struct
+import time
 import zlib
+
+from .tracing import DB_WRITE, TRACER
 
 logger = logging.getLogger("libs.db")
 
@@ -145,16 +148,26 @@ class SqliteDB(DB):
             "SELECT v FROM kv WHERE k = ?", (key,)).fetchone()
         return None if row is None else bytes(row[0])
 
+    # Every durable commit — an autocommit set/delete, the COMMIT of
+    # a batch — is one db.write span (under synchronous=FULL, one
+    # fsync of the sqlite WAL). Callers that commit once a tx (the
+    # kvstore app's DeliverTx, the tx indexer) make runs of them, which
+    # TRACER.leaf folds: count = the sum of `n`, time = of `busy_ns`.
+
     def set(self, key: bytes, value: bytes) -> None:
         from . import failpoints
 
         failpoints.hit("db.set")
+        t0 = time.perf_counter_ns()
         self._c.execute(
             "INSERT INTO kv (k, v) VALUES (?, ?) "
             "ON CONFLICT(k) DO UPDATE SET v = excluded.v", (key, value))
+        TRACER.leaf(DB_WRITE, t0, ops=1, bytes=len(key) + len(value))
 
     def delete(self, key: bytes) -> None:
+        t0 = time.perf_counter_ns()
         self._c.execute("DELETE FROM kv WHERE k = ?", (key,))
+        TRACER.leaf(DB_WRITE, t0, ops=1, bytes=len(key))
 
     def write_batch(self, ops) -> None:
         from . import failpoints
@@ -162,10 +175,14 @@ class SqliteDB(DB):
         failpoints.hit("db.set")
         self._c.execute("BEGIN IMMEDIATE")
         try:
+            n = nbytes = 0
             for k, v in ops:
+                n += 1
+                nbytes += len(k)
                 if v is None:
                     self._c.execute("DELETE FROM kv WHERE k = ?", (k,))
                 else:
+                    nbytes += len(v)
                     self._c.execute(
                         "INSERT INTO kv (k, v) VALUES (?, ?) "
                         "ON CONFLICT(k) DO UPDATE SET v = excluded.v",
@@ -173,7 +190,9 @@ class SqliteDB(DB):
             # COMMIT inside the guard: if it fails (disk full, BUSY)
             # the transaction must still be rolled back, or every
             # later BEGIN dies with "transaction within a transaction"
+            t0 = time.perf_counter_ns()
             self._c.execute("COMMIT")
+            TRACER.leaf(DB_WRITE, t0, ops=n, bytes=nbytes)
         except BaseException:
             try:
                 self._c.execute("ROLLBACK")

@@ -20,6 +20,11 @@ Reference: node/node.go:807-812 serves net/http/pprof on
                                windows to the trailing N s (default:
                                the whole ring)
   GET /debug/trace/rollup      per-span-kind p50/p95/p99 rollup JSON
+  GET /debug/profile?seconds=N device profile (jax.profiler) of the next
+                               N s (default 1, at most 10; one at a
+                               time) with a clock-sync annotation, plus
+                               the span ring's spans of the interval:
+                               device idle time by host activity
   GET /debug/launches?workload=W&seconds=N
                                device launch-ledger records + per-
                                workload rollup + watchdog classification
@@ -411,6 +416,67 @@ def _parse_seconds(raw, default: float, cap: float) -> float:
     return min(v, cap)
 
 
+# The annotation benchmark/trace_reduce.py looks for: stamped with
+# perf_counter_ns at the moment it is emitted, it lays the span ring's
+# clock on the profiler's, so reduce_trace(xplane, sync_ns=...,
+# program_spans=...) labels a live node's device idle gaps by span kind
+# exactly as it does a benchmark run's.
+CLOCK_SYNC = "bench:clock_sync"
+DEVICE_PROFILE_CAP_S = 10.0   # stopping costs far more than the slice
+_device_profile_running = False
+
+
+async def _device_profile(seconds: float) -> dict:
+    """jax.profiler on for `seconds`; returns where the trace went, the
+    perf_counter_ns stamp of its clock-sync annotation and the ring's
+    spans that overlap the interval. A second request while one runs
+    is refused. start/stop run in a worker thread: stopping a slice
+    with a few launches of a verify program in it takes tens of seconds
+    (one launch is ~100,000 device events) and must not stall consensus."""
+    global _device_profile_running
+    import tempfile
+
+    from .tracing import TRACER
+
+    if _device_profile_running:
+        return {"error": "a device profile is already running"}
+    _device_profile_running = True
+    try:
+        import jax
+
+        out_dir = tempfile.mkdtemp(prefix="tm-tpu-profile-")
+        loop = asyncio.get_running_loop()
+
+        def start() -> int:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 2
+            jax.profiler.start_trace(out_dir, profiler_options=opts)
+            sync_ns = time.perf_counter_ns()
+            with jax.profiler.TraceAnnotation(CLOCK_SYNC):
+                pass
+            return sync_ns
+
+        sync_ns = await loop.run_in_executor(None, start)
+        try:
+            await asyncio.sleep(seconds)
+        finally:
+            end_ns = time.perf_counter_ns()
+            await loop.run_in_executor(None, jax.profiler.stop_trace)
+        return {
+            "trace_dir": out_dir,
+            "sync_ns": sync_ns,
+            "seconds": (end_ns - sync_ns) / 1e9,
+            "stop_s": (time.perf_counter_ns() - end_ns) / 1e9,
+            # (kind, start_ns, dur_ns): trace_reduce's program_spans
+            "spans": [(r[0], r[4], r[5]) for r in TRACER.snapshot()
+                      if r[4] < end_ns and r[4] + r[5] > sync_ns],
+            "spans_dropped": TRACER.dropped,
+        }
+    finally:
+        _device_profile_running = False
+
+
 async def _profile(seconds: float) -> str:
     import cProfile
     import pstats
@@ -493,6 +559,7 @@ class DebugServer:
             return (b"pprof endpoints: goroutine, heap?seconds=N, "
                     b"profile?seconds=N; also /metrics, /status, "
                     b"/debug/trace?seconds=N, /debug/trace/rollup, "
+                    b"/debug/profile?seconds=N, "
                     b"/debug/launches?workload=W&seconds=N, "
                     b"/debug/failpoint (GET state / POST arm)\n")
         if path == "/debug/failpoint":
@@ -553,6 +620,13 @@ class DebugServer:
             body = await asyncio.get_running_loop().run_in_executor(
                 None, render)
             return body, b"application/json"
+        if path == "/debug/profile":
+            import json
+
+            secs = _parse_seconds(params.get("seconds"), 1.0,
+                                  cap=DEVICE_PROFILE_CAP_S)
+            return (json.dumps(await _device_profile(secs)).encode(),
+                    b"application/json")
         if path == "/debug/trace/anchor":
             import json
             import time as _t

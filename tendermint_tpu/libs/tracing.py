@@ -8,22 +8,47 @@ monotonic-clock spans with parent/child
 links over the hot paths:
 
   consensus.height                     one root span per height
+    consensus.new_height               update_to_state -> round 0 (the
+                                       timeout_commit wait)
     consensus.propose / .prevote / .precommit / .commit ...
       wal.fsync                        every write_sync
+      store.save_block                 the block store's one batch
       state.apply_block                ApplyBlock wall time
-        crypto.batch                   a BatchVerifier.verify call
-          crypto.verify                one device verify
-            crypto.pack                host byte packing (numpy)
-            crypto.dispatch            kernel-launch enqueue
-            crypto.device_exec         wait-until-verdicts-ready
-            crypto.readback            device->host verdict copy
+        state.validate                 validate_block (worker thread)
+          verify.commit                one commit check, any form
+            verify.collect             basics + the loop over CommitSigs
+            verify.sign_batch          sign bytes (structured or full)
+            verify.tables              key-bytes list + table lookup
+            crypto.batch               a BatchVerifier.verify call
+            crypto.verify              one device verify
+              crypto.pack              host byte packing (numpy)
+              crypto.dispatch          kernel-launch enqueue
+              crypto.device_exec       wait-until-verdicts-ready
+              crypto.readback          device->host verdict copy
+        state.exec                     BeginBlock, DeliverTx xN, EndBlock
+        state.save_responses           ABCI responses to the state store
+        state.app_commit               mempool lock, flush, app Commit,
+                                       mempool update
+        state.save                     the new State to the state store
+        state.events                   event-bus publishes
+  db.write                             a durable commit of SqliteDB,
+                                       child of whichever span wrote it
+  verify.window                        a fast-sync window in its worker:
+                                       part sets, then collect /
+                                       sign_batch / tables / crypto.*
+  admission.queue_wait                 first pending envelope -> the cut
+  admission.flush                      the cut -> last verdict delivered
+    crypto.verify ...                  (executor thread, via wrap)
   p2p.send_flush / p2p.recv_msg        wire-side attribution
 
 Design constraints (this stays ON in production):
 
   * Fixed-size ring buffer (collections.deque(maxlen=N), default 16k
-    spans): ending a span is one tuple append; overflow evicts the
-    oldest — memory is bounded no matter the load.
+    spans): ending a span is one tuple append under the ring's lock;
+    overflow evicts the oldest — memory is bounded no matter the load.
+    One ring is one node's: a block costs it ~17 entries more since
+    its verify.* / state.* / store.* children exist (docs/
+    OBSERVABILITY.md gives the horizon in heights).
   * time.perf_counter_ns() start/stop; no datetime, no wall clock.
   * Task-local context via contextvars: asyncio tasks inherit the
     active span automatically. Executor threads do NOT (run_in_executor
@@ -33,6 +58,13 @@ Design constraints (this stays ON in production):
     worker thread. This is how a crypto.verify span recorded in the
     BatchVerifier executor thread still parents under the event loop's
     consensus span.
+  * Spans are per batch, per block, per commit — never per tx or per
+    request: a few kinds at the tx rate would push a window's spans
+    out of the ring before anyone reads them. The one site whose unit
+    can be a tx (a durable db commit; the kvstore app and the tx
+    indexer commit once a tx) records through leaf(), which folds a
+    run of back-to-back repeats into one ring entry (attrs `n`,
+    `busy_ns`, summed counters).
   * Span kinds are a closed registry: every instrumented site names a
     constant registered here (tools/check_spans.py lints for ad-hoc
     string literals). An unregistered kind raises at span start — a
@@ -72,16 +104,22 @@ def registered_kinds() -> frozenset[str]:
     return frozenset(_KINDS)
 
 
-# Consensus timeline (one root per height; step children follow
+def _require_registered(kind: str) -> None:
+    if kind not in _KINDS:
+        raise ValueError(f"unregistered span kind {kind!r} "
+                         "(register_kind / tools/check_spans.py)")
+
+
+# Consensus timeline (one root per height; consensus.new_height from
+# update_to_state to round 0, then step children that follow
 # consensus/cstypes.py RoundStep names via consensus_step_kind()).
 CONSENSUS_HEIGHT = register_kind("consensus.height")
+CONSENSUS_NEW_HEIGHT = register_kind("consensus.new_height")
 CONSENSUS_PROPOSE = register_kind("consensus.propose")
 CONSENSUS_PREVOTE = register_kind("consensus.prevote")
 CONSENSUS_PREVOTE_WAIT = register_kind("consensus.prevote_wait")
 CONSENSUS_PRECOMMIT = register_kind("consensus.precommit")
-CONSENSUS_PRECOMMIT_WAIT = register_kind("consensus.precommit_wait")
 CONSENSUS_COMMIT = register_kind("consensus.commit")
-CONSENSUS_NEW_ROUND = register_kind("consensus.new_round")
 CONSENSUS_VOTE_BATCH = register_kind("consensus.vote_batch")
 
 _STEP_KINDS = {
@@ -89,15 +127,16 @@ _STEP_KINDS = {
     "PREVOTE": CONSENSUS_PREVOTE,
     "PREVOTE_WAIT": CONSENSUS_PREVOTE_WAIT,
     "PRECOMMIT": CONSENSUS_PRECOMMIT,
-    "PRECOMMIT_WAIT": CONSENSUS_PRECOMMIT_WAIT,
     "COMMIT": CONSENSUS_COMMIT,
 }
 
 
 def consensus_step_kind(step_name: str) -> str:
-    """RoundStep name -> registered step-span kind (NEW_HEIGHT /
-    NEW_ROUND transitions fold into consensus.new_round)."""
-    return _STEP_KINDS.get(step_name, CONSENSUS_NEW_ROUND)
+    """RoundStep name -> registered step-span kind, for the steps
+    ConsensusState._new_step enters (NEW_HEIGHT has its own span,
+    opened by update_to_state; NEW_ROUND and PRECOMMIT_WAIT are passed
+    through without a step of their own)."""
+    return _STEP_KINDS[step_name]
 
 
 # Device pipeline (crypto/batch.py, crypto/tpu/verify.py + expanded.py).
@@ -117,8 +156,29 @@ SPECULATION_SPECULATE = register_kind("speculation.speculate")
 SPECULATION_PATCH = register_kind("speculation.patch")
 SPECULATION_RECONCILE = register_kind("speculation.reconcile")
 
-# State machine + durability + wire.
+# Verify sites (types/validator_set.py, blockchain/verify_ahead.py):
+# what a commit check costs the host around its crypto.* launch.
+VERIFY_COMMIT = register_kind("verify.commit")
+VERIFY_COLLECT = register_kind("verify.collect")
+VERIFY_SIGN_BATCH = register_kind("verify.sign_batch")
+VERIFY_TABLES = register_kind("verify.tables")
+VERIFY_WINDOW = register_kind("verify.window")
+
+# Admission micro-batcher (mempool/admission.py), one pair per batch.
+ADMISSION_QUEUE_WAIT = register_kind("admission.queue_wait")
+ADMISSION_FLUSH = register_kind("admission.flush")
+
+# State machine + durability + wire. The state.* children follow
+# BlockExecutor._apply_block in order.
 STATE_APPLY_BLOCK = register_kind("state.apply_block")
+STATE_VALIDATE = register_kind("state.validate")
+STATE_EXEC = register_kind("state.exec")
+STATE_SAVE_RESPONSES = register_kind("state.save_responses")
+STATE_APP_COMMIT = register_kind("state.app_commit")
+STATE_SAVE = register_kind("state.save")
+STATE_EVENTS = register_kind("state.events")
+STORE_SAVE_BLOCK = register_kind("store.save_block")
+DB_WRITE = register_kind("db.write")
 WAL_FSYNC = register_kind("wal.fsync")
 P2P_SEND_FLUSH = register_kind("p2p.send_flush")
 P2P_RECV_MSG = register_kind("p2p.recv_msg")
@@ -141,7 +201,7 @@ class Span:
                  "_tracer", "_done")
 
     def __init__(self, tracer: "Tracer", kind: str, parent_id: int,
-                 attrs: dict | None):
+                 attrs: dict | None, start_ns: int | None = None):
         self.kind = kind
         self.span_id = next(_ids)
         self.parent_id = parent_id
@@ -149,7 +209,7 @@ class Span:
         self.attrs = attrs
         self._tracer = tracer
         self._done = False
-        self.t0 = time.perf_counter_ns()
+        self.t0 = time.perf_counter_ns() if start_ns is None else start_ns
 
     def set_attr(self, key: str, value) -> None:
         if self.attrs is None:
@@ -162,20 +222,7 @@ class Span:
         self._done = True
         t1 = time.perf_counter_ns()
         tracer = self._tracer
-        # Ring-overflow accounting: deque(maxlen=N) evicts silently, so
-        # a truncated timeline would be indistinguishable from a complete
-        # one. len() on a deque is O(1); the increment is GIL-atomic
-        # enough for a monitoring counter (exactness is not load-bearing,
-        # non-zero-ness is).
-        if len(tracer._ring) >= tracer.capacity:
-            tracer._dropped += 1
-            dsink = tracer.drop_sink
-            if dsink is not None:
-                try:
-                    dsink(1)
-                except Exception:
-                    pass
-        tracer._ring.append((
+        tracer._append((
             self.kind, self.span_id, self.parent_id, self.tid,
             self.t0, t1 - self.t0, self.attrs,
         ))
@@ -185,12 +232,7 @@ class Span:
         # never take down the instrumented path, hence the blanket
         # except; the sink itself is a dict lookup + bucket scan,
         # inside the tools/check_spans.py per-span budget.
-        sink = tracer.metrics_sink
-        if sink is not None:
-            try:
-                sink(self.kind, (t1 - self.t0) / 1e9)
-            except Exception:
-                pass
+        tracer._observe(self.kind, t1 - self.t0)
 
 
 class _NoopSpan:
@@ -258,18 +300,26 @@ class _AttachCtx:
 # ---------------------------------------------------------------- tracer
 
 DEFAULT_CAPACITY = int(os.environ.get("TM_TPU_TRACE_CAPACITY", "16384"))
+# leaf(): the longest pause between two repeats that still fold into
+# one entry. A tx-rate run of db commits has well under a millisecond
+# of caller time between them; writes of different blocks are far
+# apart and stay apart.
+LEAF_FOLD_NS = 2_000_000
 
 
 class Tracer:
-    """Ring-buffered span recorder. Thread-safe by construction: the
-    only shared mutation is deque.append / popleft-on-overflow, both
-    atomic under the GIL; snapshots copy the ring."""
+    """Ring-buffered span recorder. Every change to the ring (a span's
+    append, leaf()'s fold of the newest entry, resize) and every copy
+    of it happens under one lock, so a snapshot never misses a span
+    another thread has sealed and entries keep the order they ended
+    in."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  enabled: bool = True):
         self.capacity = capacity
         self.enabled = enabled
         self._ring: deque = deque(maxlen=capacity)
+        self._lock = threading.Lock()
         self._dropped = 0
         # tracing→metrics bridge: fn(kind, seconds) called on every
         # span close (libs/metrics.py installs span_metrics_sink on
@@ -293,20 +343,85 @@ class Tracer:
 
     # -- recording --
 
-    def begin(self, kind: str, parent: Span | None = None, **attrs) -> Span:
+    def begin(self, kind: str, parent: Span | None = None,
+              start_ns: int | None = None, **attrs) -> Span:
         """Start a span. Parent defaults to the task-local current
         span; pass `parent=` to link manually-managed spans (the
-        consensus height/step timeline). Returns NOOP_SPAN when
-        disabled — callers never branch."""
+        consensus height/step timeline). `start_ns` backdates the span
+        to a perf_counter_ns the caller stamped (a queue wait is only
+        known at the cut). Returns NOOP_SPAN when disabled — callers
+        never branch."""
         if not self.enabled:
             return NOOP_SPAN
-        if kind not in _KINDS:
-            raise ValueError(f"unregistered span kind {kind!r} "
-                             "(register_kind / tools/check_spans.py)")
+        _require_registered(kind)
         if parent is None:
             parent = _CURRENT.get()
         return Span(self, kind, parent.span_id if parent else 0,
-                    attrs or None)
+                    attrs or None, start_ns)
+
+    def leaf(self, kind: str, start_ns: int, **sums) -> None:
+        """Record a finished childless span [start_ns, now) under the
+        current span — the form for a site whose unit may be a tx (a
+        durable db commit). A repeat that follows the ring's newest
+        entry — same kind, parent and thread, begun within
+        LEAF_FOLD_NS of its end — extends that entry instead of
+        adding one: `n` counts the repeats, `busy_ns` sums their own
+        durations (the entry's duration then includes the caller's
+        time between them) and each of `sums` adds up. A tx-rate
+        writer thus costs the ring one entry per run, not one per tx."""
+        if not self.enabled:
+            return
+        _require_registered(kind)
+        t1 = time.perf_counter_ns()
+        dur = t1 - start_ns
+        parent = _CURRENT.get()
+        pid = parent.span_id if parent else 0
+        tid = threading.get_ident()
+        with self._lock:
+            ring = self._ring
+            last = ring[-1] if ring else None
+            folds = (last is not None and last[0] == kind
+                     and last[2] == pid and last[3] == tid
+                     and 0 <= start_ns - (last[4] + last[5]) <= LEAF_FOLD_NS)
+            if folds:
+                attrs = dict(last[6] or ())
+                attrs["n"] = attrs.get("n", 1) + 1
+                attrs["busy_ns"] = attrs.get("busy_ns", last[5]) + dur
+                for k, v in sums.items():
+                    attrs[k] = attrs.get(k, 0) + v
+                ring[-1] = (kind, last[1], pid, tid, last[4],
+                            t1 - last[4], attrs)
+        if not folds:
+            self._append((kind, next(_ids), pid, tid, start_ns, dur, sums))
+        self._observe(kind, dur)
+
+    def _append(self, rec: tuple) -> None:
+        """Seal one finished span into the ring. Overflow accounting:
+        deque(maxlen=N) evicts silently, so a truncated timeline would
+        be indistinguishable from a complete one."""
+        with self._lock:
+            full = len(self._ring) >= self.capacity
+            self._ring.append(rec)
+        if full:
+            self._count_drop()
+
+    def _observe(self, kind: str, dur_ns: int) -> None:
+        """Feed one closed span to the metrics bridge, if there is one."""
+        sink = self.metrics_sink
+        if sink is not None:
+            try:
+                sink(kind, dur_ns / 1e9)
+            except Exception:
+                pass
+
+    def _count_drop(self) -> None:
+        self._dropped += 1
+        dsink = self.drop_sink
+        if dsink is not None:
+            try:
+                dsink(1)
+            except Exception:
+                pass
 
     def span(self, kind: str, **attrs) -> _SpanCtx:
         """`with TRACER.span(KIND, k=v): ...` — the instrumented-site
@@ -340,8 +455,18 @@ class Tracer:
     # -- reading --
 
     def clear(self) -> None:
-        self._ring.clear()
-        self._dropped = 0
+        with self._lock:
+            self._ring.clear()
+            self._dropped = 0
+
+    def resize(self, capacity: int) -> None:
+        """Give the ring another size, keeping the newest spans. The
+        default holds one node's recent heights; a process that hosts
+        several nodes (sim/scenario.py) asks for one node's worth
+        each."""
+        with self._lock:
+            self._ring = deque(self._ring, maxlen=capacity)
+            self.capacity = capacity
 
     def __len__(self) -> int:
         return len(self._ring)
@@ -349,7 +474,8 @@ class Tracer:
     def snapshot(self, seconds: float | None = None) -> list[tuple]:
         """Finished spans, oldest first; `seconds` keeps only spans
         that ENDED within the trailing window."""
-        recs = list(self._ring)
+        with self._lock:
+            recs = list(self._ring)
         if seconds is None:
             return recs
         cutoff = time.perf_counter_ns() - int(seconds * 1e9)

@@ -44,7 +44,9 @@ import time
 
 import numpy as np
 
+from ..libs import tracing
 from ..libs.overload import CONTROLLER
+from ..libs.tracing import TRACER
 from ..types import tx_envelope
 
 logger = logging.getLogger("mempool.admission")
@@ -96,7 +98,7 @@ class AdmissionCollector:
         self.device_threshold = cbatch._DEVICE_THRESHOLD \
             if device_threshold is None else device_threshold
         self._controller = controller or CONTROLLER
-        # (envelope, future) pairs awaiting a flush
+        # (envelope, future, enqueue perf_counter_ns) awaiting a flush
         self._pending: collections.deque = collections.deque()
         self._in_flight = 0
         self._item_evt = asyncio.Event()   # set on every enqueue
@@ -120,7 +122,7 @@ class AdmissionCollector:
         if self._flusher is not None:
             self._flusher.cancel()
             self._flusher = None
-        for _, fut in self._pending:
+        for _, fut, _ in self._pending:
             if not fut.done():
                 fut.cancel()
         self._pending.clear()
@@ -145,7 +147,7 @@ class AdmissionCollector:
             raise AdmissionQueueFullError(self.depth(), self.queue_max)
         self._ensure_flusher()
         fut = asyncio.get_running_loop().create_future()
-        self._pending.append((env, fut))
+        self._pending.append((env, fut, time.perf_counter_ns()))
         self._item_evt.set()
         if len(self._pending) >= self.batch_max:
             self._full_evt.set()
@@ -154,6 +156,13 @@ class AdmissionCollector:
     # -- flusher -------------------------------------------------------
 
     async def _flush_loop(self) -> None:
+        # The flusher outlives the request whose arrival started it:
+        # detach from that request's span so that each batch's
+        # queue_wait / flush pair is a root of its own.
+        with TRACER.attach(None):
+            await self._flush_batches()
+
+    async def _flush_batches(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
             while not self._pending:
@@ -172,25 +181,36 @@ class AdmissionCollector:
                                            remaining)
                 except asyncio.TimeoutError:
                     break
+            full = len(self._pending) >= self.batch_max
             batch = [self._pending.popleft()
                      for _ in range(min(len(self._pending),
                                         self.batch_max))]
             self._in_flight = len(batch)
+            cut = time.perf_counter_ns()
+            TRACER.begin(
+                tracing.ADMISSION_QUEUE_WAIT, start_ns=batch[0][2],
+                lanes=len(batch), cut="full" if full else "deadline",
+                wait_sum_ms=sum(cut - t for _, _, t in batch) / 1e6,
+            ).end()
             try:
-                envs = [env for env, _ in batch]
-                verdicts = await loop.run_in_executor(
-                    None, self._verify_batch, envs)
-                for (_, fut), ok in zip(batch, verdicts):
-                    if not fut.done():
-                        fut.set_result(bool(ok))
+                # the span goes to the worker thread by hand
+                # (TRACER.wrap): crypto.verify is then its child
+                with TRACER.span(tracing.ADMISSION_FLUSH,
+                                 lanes=len(batch)):
+                    envs = [env for env, _, _ in batch]
+                    verdicts = await loop.run_in_executor(
+                        None, TRACER.wrap(self._verify_batch), envs)
+                    for (_, fut, _), ok in zip(batch, verdicts):
+                        if not fut.done():
+                            fut.set_result(bool(ok))
             except asyncio.CancelledError:
-                for _, fut in batch:
+                for _, fut, _ in batch:
                     if not fut.done():
                         fut.cancel()
                 raise
             except Exception as e:  # defensive: a verdict must always land
                 logger.exception("admission verify batch died")
-                for _, fut in batch:
+                for _, fut, _ in batch:
                     if not fut.done():
                         fut.set_exception(e)
             finally:
@@ -217,6 +237,15 @@ class AdmissionCollector:
         n = len(envs)
         met.batch_lanes.observe(n)
         met.batch_occupancy.observe(n / self.batch_max)
+        flush_span = TRACER.current() or tracing.NOOP_SPAN
+
+        def launched(backend: str) -> None:
+            """One launch on `backend`: the counter, and the answer on
+            this batch's admission.flush span (the last one stands: a
+            device launch re-checked on the host reads host_recheck)."""
+            met.launches.inc(backend=backend)
+            flush_span.set_attr("backend", backend)
+
         t0 = time.perf_counter()
         try:
             try:
@@ -224,7 +253,7 @@ class AdmissionCollector:
             except failpoints.FailpointError:
                 # injected launch failure: degrade to the host oracle,
                 # exactly like a raising device launch
-                met.launches.inc(backend="host")
+                launched("host")
                 crypto_metrics().batch_lanes.inc(n, backend="host")
                 return self._host_verify(envs)
             want_dev = n >= self.device_threshold
@@ -259,7 +288,7 @@ class AdmissionCollector:
                              for e in envs] + [smsg],
                             [e.signature for e in envs] + [ssig]),
                             bool)
-                    met.launches.inc(backend="device")
+                    launched("device")
                     crypto_metrics().batch_lanes.inc(
                         n, backend=tpu_backend.platform())
                     if out[-1]:
@@ -274,7 +303,7 @@ class AdmissionCollector:
                         "known-answer sentinel; breaker open %.1fs, "
                         "re-verifying on host", n,
                         cbatch.breaker("ed25519").cooldown_remaining())
-                    met.launches.inc(backend="host_recheck")
+                    launched("host_recheck")
                     tpu_metrics().host_fallbacks.inc()
                     return self._host_verify(envs)
                 except Exception:
@@ -288,7 +317,7 @@ class AdmissionCollector:
                 # raised, or sentinel-failed: same fallback signal as
                 # BatchVerifier._verify_group
                 tpu_metrics().host_fallbacks.inc()
-            met.launches.inc(backend="host")
+            launched("host")
             crypto_metrics().batch_lanes.inc(n, backend="host")
             return self._host_verify(envs)
         finally:

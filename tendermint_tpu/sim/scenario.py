@@ -116,7 +116,8 @@ class Scenario:
     # sets) at virtual times without patching the runner
     probe = None
     # Height forensics: when True, the runner clears the global TRACER
-    # at scenario start and folds per-height TIMELINE dicts (tools/
+    # at scenario start, gives its ring one node's capacity per sim
+    # node for the run, and folds per-height TIMELINE dicts (tools/
     # forensics.py) into report["timeline"], checked by the
     # timeline_attribution invariant. Off by default — a cleared
     # tracer ring is process-global state a test may not expect.
@@ -237,10 +238,15 @@ def run_scenario(scenario: Scenario, seed: int) -> dict:
         "final_heights": [], "restarts": [], "net": {}, "chain": [],
         "app_hashes": [], "evidence_committed": 0,
     }
+    ring_was = None
     if scenario.collect_timeline:
         from ..libs import tracing as _tracing
 
         _tracing.TRACER.clear()
+        # the ring is sized for ONE node's history and every sim node
+        # of this process records into it: one node's worth each
+        ring_was = _tracing.TRACER.capacity
+        _tracing.TRACER.resize(ring_was * scenario.nodes)
     try:
         loop.run_until_complete(_run(scenario, seed, report))
     except SimStallError as e:
@@ -265,6 +271,8 @@ def run_scenario(scenario: Scenario, seed: int) -> dict:
             _batch.set_force_host(prev_force)
             restore_memo()
             libs_clock.uninstall()
+            if ring_was is not None:
+                _tracing.TRACER.resize(ring_was)
     report["wall_s"] = round(_wall.perf_counter() - t0, 3)
     return report
 

@@ -13,7 +13,9 @@ from __future__ import annotations
 
 from ..abci import types as abci_t
 from ..abci.client import Client
+from ..libs import tracing
 from ..libs.failpoints import hit as _failpoint
+from ..libs.tracing import TRACER
 from ..mempool import Mempool, NopMempool, TxPostCheck, TxPreCheck
 from ..types.block import Block, BlockID, Commit
 from ..types.events import (
@@ -136,8 +138,6 @@ class BlockExecutor:
         thread so the commit-verify crypto spans keep their lineage."""
         import asyncio
 
-        from ..libs.tracing import TRACER
-
         await asyncio.get_running_loop().run_in_executor(
             None, TRACER.wrap(self.validate_block), state, block
         )
@@ -146,21 +146,28 @@ class BlockExecutor:
                           block: Block) -> tuple[State, int]:
         """Returns (new_state, retain_height). Raises on invalid block."""
         from ..libs.metrics import state_metrics
-        from ..libs.tracing import STATE_APPLY_BLOCK, TRACER
 
         with state_metrics().block_processing_seconds.time(), \
-                TRACER.span(STATE_APPLY_BLOCK, height=block.header.height):
+                TRACER.span(tracing.STATE_APPLY_BLOCK,
+                            height=block.header.height):
             return await self._apply_block(state, block_id, block)
 
     async def _apply_block(self, state: State, block_id: BlockID,
                            block: Block) -> tuple[State, int]:
-        await self.validate_block_async(state, block)
+        # the six state.* spans below are what a block costs the host,
+        # in order; update_state between them is apply_block's own time
+        with TRACER.span(tracing.STATE_VALIDATE):
+            await self.validate_block_async(state, block)
 
-        abci_responses = await self._exec_block_on_proxy_app(state, block)
+        with TRACER.span(tracing.STATE_EXEC, txs=len(block.data.txs)):
+            abci_responses = await self._exec_block_on_proxy_app(
+                state, block)
 
         _failpoint("state.apply.block_executed")
 
-        self.store.save_abci_responses(block.header.height, abci_responses)
+        with TRACER.span(tracing.STATE_SAVE_RESPONSES):
+            self.store.save_abci_responses(block.header.height,
+                                           abci_responses)
 
         _failpoint("state.apply.responses_saved")
 
@@ -181,19 +188,22 @@ class BlockExecutor:
             new_state.next_validators.warm_device_tables()
 
         # Commit app + update mempool (reference: execution.go:210-254)
-        app_hash, retain_height = await self._commit(new_state, block,
-                                                     abci_responses["deliver_txs"])
+        with TRACER.span(tracing.STATE_APP_COMMIT):
+            app_hash, retain_height = await self._commit(
+                new_state, block, abci_responses["deliver_txs"])
         if self.evpool is not None:
             self.evpool.update(new_state, block.evidence.evidence)
 
         _failpoint("state.apply.app_committed")
 
         new_state.app_hash = app_hash
-        self.store.save(new_state)
+        with TRACER.span(tracing.STATE_SAVE):
+            self.store.save(new_state)
 
         _failpoint("state.apply.state_saved")
 
-        self._fire_events(block, block_id, abci_responses, val_updates)
+        with TRACER.span(tracing.STATE_EVENTS):
+            self._fire_events(block, block_id, abci_responses, val_updates)
         return new_state, retain_height
 
     async def _exec_block_on_proxy_app(self, state: State, block: Block) -> dict:

@@ -11,6 +11,7 @@ import json
 import struct
 
 from ..libs.db import DB
+from ..libs.tracing import STORE_SAVE_BLOCK, TRACER
 from ..types.block import Block, BlockID, Commit, Part, PartSet
 from ..types.block_meta import BlockMeta
 
@@ -76,6 +77,12 @@ class BlockStore:
     # -- writes --
 
     def save_block(self, block: Block, parts: PartSet, seen_commit: Commit) -> None:
+        with TRACER.span(STORE_SAVE_BLOCK, height=block.header.height,
+                         parts=parts.total):
+            self._save_block(block, parts, seen_commit)
+
+    def _save_block(self, block: Block, parts: PartSet,
+                    seen_commit: Commit) -> None:
         height = block.header.height
         if self.height and height != self.height + 1:
             raise ValueError(

@@ -15,6 +15,8 @@ from __future__ import annotations
 
 from ..crypto import merkle
 from ..crypto.batch import BatchVerifier
+from ..libs import tracing
+from ..libs.tracing import TRACER
 from .block import BlockID
 from .validator import Validator
 
@@ -42,15 +44,16 @@ class CommitVerifyPlan:
     into a single wide device launch, while the classic verify_commit*
     methods just plan + execute inline."""
 
-    __slots__ = ("valset", "lanes", "slots", "sigs", "msgs")
+    __slots__ = ("valset", "lanes", "slots", "sigs", "msgs", "form")
 
     def __init__(self, valset: "ValidatorSet", lanes: list[int],
-                 slots: list[int], sigs: list[bytes], msgs):
+                 slots: list[int], sigs: list[bytes], msgs, form: str):
         self.valset = valset
         self.lanes = lanes    # indices into valset.validators (tables)
         self.slots = slots    # commit signature slots (error reports)
         self.sigs = sigs
         self.msgs = msgs      # list[bytes] | StructuredSignBytes
+        self.form = form      # light | trusting (the span's attr)
 
     def __len__(self) -> int:
         return len(self.lanes)
@@ -80,10 +83,19 @@ class CommitVerifyPlan:
     def execute(self) -> None:
         """Verify this plan alone (the classic inline path): one
         batch through the owning set's expanded tables / BatchVerifier."""
-        ok, verdicts = self.valset._batch_verify_lanes(
-            self.lanes, self.msgs, self.sigs)
-        if not ok:
-            self.raise_invalid(verdicts)
+        with TRACER.span(tracing.VERIFY_COMMIT, form=self.form,
+                         lanes=len(self.lanes),
+                         structured=_is_structured(self.msgs)):
+            ok, verdicts = self.valset._batch_verify_lanes(
+                self.lanes, self.msgs, self.sigs)
+            if not ok:
+                self.raise_invalid(verdicts)
+
+
+def _is_structured(msgs) -> bool:
+    from .sign_batch import StructuredSignBytes
+
+    return isinstance(msgs, StructuredSignBytes)
 
 
 class ValidatorSet:
@@ -339,11 +351,13 @@ class ValidatorSet:
             return []
         from .sign_batch import CommitSignBatch
 
-        return self.structured_or_bytes(
-            lanes,
-            lambda: CommitSignBatch(chain_id, commit, slots),
-            lambda: [commit.vote_sign_bytes(chain_id, s) for s in slots],
-        )
+        with TRACER.span(tracing.VERIFY_SIGN_BATCH, lanes=len(slots)):
+            return self.structured_or_bytes(
+                lanes,
+                lambda: CommitSignBatch(chain_id, commit, slots),
+                lambda: [commit.vote_sign_bytes(chain_id, s)
+                         for s in slots],
+            )
 
     def _batch_verify_lanes(self, lanes: list[int], msgs,
                             sigs: list[bytes]):
@@ -361,9 +375,8 @@ class ValidatorSet:
         redundant sign bytes per lane; every fallback materializes the
         identical full bytes."""
         from ..crypto import batch as _batch
-        from .sign_batch import StructuredSignBytes
 
-        structured = isinstance(msgs, StructuredSignBytes)
+        structured = _is_structured(msgs)
         # structured implies _use_expanded held when the batch was
         # built (_commit_msgs) — don't repeat the O(n) key-type scan.
         if structured or self._use_expanded(lanes):
@@ -372,8 +385,10 @@ class ValidatorSet:
 
             try:
                 failpoints.hit("device.verify")
-                exp = expanded.get_expanded(
-                    [v.pub_key.bytes() for v in self.validators])
+                with TRACER.span(tracing.VERIFY_TABLES,
+                                 keys=len(self.validators)):
+                    exp = expanded.get_expanded(
+                        [v.pub_key.bytes() for v in self.validators])
                 if structured:
                     try:
                         verdicts = exp.verify_structured(
@@ -414,23 +429,32 @@ class ValidatorSet:
                       commit) -> None:
         """Verify ALL non-absent signatures; tally for-block power must
         exceed 2/3 (reference: validator_set.go:662)."""
-        self._check_commit_basics(block_id, height, commit)
+        with TRACER.span(tracing.VERIFY_COMMIT, form="full") as span:
+            self._verify_commit(chain_id, block_id, height, commit, span)
+
+    def _verify_commit(self, chain_id: str, block_id: BlockID,
+                       height: int, commit, span) -> None:
         lanes: list[int] = []
         sigs: list[bytes] = []
         tallied = 0
-        for idx, cs in enumerate(commit.signatures):
-            if cs.is_absent():
-                continue
-            val = self.validators[idx]
-            if cs.validator_address and cs.validator_address != val.address:
-                raise VerificationError(
-                    f"wrong validator address in slot {idx}"
-                )
-            lanes.append(idx)
-            sigs.append(cs.signature)
-            if cs.for_block():
-                tallied += val.voting_power
+        with TRACER.span(tracing.VERIFY_COLLECT):
+            self._check_commit_basics(block_id, height, commit)
+            for idx, cs in enumerate(commit.signatures):
+                if cs.is_absent():
+                    continue
+                val = self.validators[idx]
+                if cs.validator_address and \
+                        cs.validator_address != val.address:
+                    raise VerificationError(
+                        f"wrong validator address in slot {idx}"
+                    )
+                lanes.append(idx)
+                sigs.append(cs.signature)
+                if cs.for_block():
+                    tallied += val.voting_power
         msgs = self._commit_msgs(chain_id, commit, lanes, lanes)
+        span.set_attr("lanes", len(lanes))
+        span.set_attr("structured", _is_structured(msgs))
         ok, verdicts = self._batch_verify_lanes(lanes, msgs, sigs)
         if not ok:
             bad = [lanes[i] for i in range(len(lanes)) if not verdicts[i]]
@@ -446,26 +470,27 @@ class ValidatorSet:
         cheapest 2/3 of for-block power, NO signature work. Raises
         VerificationError before planning any cryptography when the
         power cannot reach the threshold."""
-        self._check_commit_basics(block_id, height, commit)
         lanes: list[int] = []
         sigs: list[bytes] = []
         tallied = 0
         need = 2 * self.total_voting_power()
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            val = self.validators[idx]
-            lanes.append(idx)
-            sigs.append(cs.signature)
-            tallied += val.voting_power
-            if 3 * tallied > need:
-                break
+        with TRACER.span(tracing.VERIFY_COLLECT):
+            self._check_commit_basics(block_id, height, commit)
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                val = self.validators[idx]
+                lanes.append(idx)
+                sigs.append(cs.signature)
+                tallied += val.voting_power
+                if 3 * tallied > need:
+                    break
         if 3 * tallied <= need:
             raise VerificationError(
                 f"insufficient voting power: {tallied} of {self.total_voting_power()}"
             )
         msgs = self._commit_msgs(chain_id, commit, lanes, lanes)
-        return CommitVerifyPlan(self, lanes, lanes, sigs, msgs)
+        return CommitVerifyPlan(self, lanes, lanes, sigs, msgs, "light")
 
     def verify_commit_light(self, chain_id: str, block_id: BlockID,
                             height: int, commit) -> None:
@@ -489,27 +514,30 @@ class ValidatorSet:
         tallied = 0
         need = self.total_voting_power() * trust_num
         seen: set[int] = set()
-        for idx, cs in enumerate(commit.signatures):
-            if not cs.for_block():
-                continue
-            vi, val = self.get_by_address(cs.validator_address)
-            if vi < 0:
-                continue
-            if vi in seen:
-                raise VerificationError("double vote from same validator")
-            seen.add(vi)
-            lanes.append(vi)
-            slots.append(idx)
-            sigs.append(cs.signature)
-            tallied += val.voting_power
-            if tallied * trust_den > need:
-                break
+        with TRACER.span(tracing.VERIFY_COLLECT):
+            for idx, cs in enumerate(commit.signatures):
+                if not cs.for_block():
+                    continue
+                vi, val = self.get_by_address(cs.validator_address)
+                if vi < 0:
+                    continue
+                if vi in seen:
+                    raise VerificationError(
+                        "double vote from same validator")
+                seen.add(vi)
+                lanes.append(vi)
+                slots.append(idx)
+                sigs.append(cs.signature)
+                tallied += val.voting_power
+                if tallied * trust_den > need:
+                    break
         if tallied * trust_den <= need:
             raise VerificationError(
                 f"insufficient trusted power: {tallied}"
             )
         msgs = self._commit_msgs(chain_id, commit, slots, lanes)
-        return CommitVerifyPlan(self, lanes, slots, sigs, msgs)
+        return CommitVerifyPlan(self, lanes, slots, sigs, msgs,
+                                "trusting")
 
     def verify_commit_light_trusting(self, chain_id: str, commit,
                                      trust_num: int, trust_den: int) -> None:

@@ -72,6 +72,11 @@ def test_sim_4net_timeline_connected_and_fully_attributed():
     assert set(summ["stages"]) == set(forensics.STAGES)
     assert summ["coverage_min"] >= 0.9
     assert r["timeline_dropped_spans"] == 0
+    # the run had one node's ring per sim node; the process has its own
+    # size back
+    from tendermint_tpu.libs.tracing import DEFAULT_CAPACITY
+
+    assert TRACER.capacity == DEFAULT_CAPACITY
 
 
 def test_sim_timeline_fingerprint_is_deterministic():

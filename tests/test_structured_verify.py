@@ -122,6 +122,52 @@ def test_structured_long_chain_id():
     assert bool(np.asarray(got).all())
 
 
+@pytest.mark.parametrize("lanes_n, mesh", [(48, False), (2100, True)])
+def test_structured_avals_are_what_a_launch_hands_the_program(
+        lanes_n, mesh):
+    """structured_phases() compiles from shapes alone: they must be the
+    shapes, dtypes and placements _launch_structured would pass for a
+    real batch, below and above the lane-sharding threshold."""
+    pubs, commit, lanes, sigs, _ = _mk(n_lanes=lanes_n)
+    sb = CommitSignBatch(CHAIN, commit, list(range(len(lanes))))
+    e = object.__new__(ex.ExpandedKeys)     # no tables: host side only
+    e.pubkeys, e.sharded = tuple(pubs), False
+    e.mesh = ex.tv._mesh() if mesh else None
+    e.akeys = np.zeros((len(pubs), 32), np.uint8)
+    e.key_ok = np.ones(len(pubs), bool)
+    e.tables = np.zeros((len(pubs), 4), np.int32)
+    idx, fields, _wf, width = e._prepare_structured(lanes, sb, sigs)
+    idx, fields, btab = e._shard_args(idx, fields, repl_keys=e._S_REPL)
+    args = dict(idx=idx, akeys=e.akeys, key_ok=e.key_ok, atab=e.tables,
+                btab=btab, **fields)
+    avals = e._structured_avals(idx.shape[0])
+    assert width == 192 and set(avals) == set(args)
+    for k, v in args.items():
+        assert (avals[k].shape, avals[k].dtype) == (v.shape, v.dtype), k
+        assert avals[k].sharding == getattr(v, "sharding", None), k
+    assert (avals["idx"].sharding is not None) == mesh
+
+
+def test_structured_phases_of_the_shape_launched_last(monkeypatch):
+    """The instruction -> phase map comes from a compile of this
+    process's own names, at the (24 keys, bucket 64, width 192) shape
+    the tests above launch; no launch keeps anything for it."""
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    pubs, commit, lanes, sigs, expect = _mk()
+    sb = CommitSignBatch(CHAIN, commit, list(range(len(lanes))))
+    monkeypatch.setattr(ex, "_CACHE", type(ex._CACHE)())
+    with pytest.raises(ValueError):
+        ex.structured_phases()
+    e = ex.ExpandedKeys(pubs)
+    ex._CACHE[b"k"] = e
+    assert list(e.verify_structured(lanes, sb, sigs)) == expect
+    phase_of = ex.structured_phases()
+    assert set(phase_of.values()) <= set(tv.PHASES)
+    assert {tv.PHASE_ASSEMBLE, tv.PHASE_SHA512, tv.PHASE_MSM} <= set(
+        phase_of.values())
+
+
 @pytest.mark.slow
 def test_merged_window_batch():
     """Fast-sync window shape: several commits (distinct heights /

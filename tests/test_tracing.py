@@ -284,6 +284,26 @@ def test_consensus_height_timeline_and_trace_endpoint(tmp_path):
              tracing.CONSENSUS_PRECOMMIT, tracing.CONSENSUS_COMMIT}
     assert any(steps <= children_of(h) for h in heights), \
         "no height span carrying all four step children"
+    # update_to_state -> round 0 (the timeout_commit wait) has a span
+    # of its own, first child of its height, sealed when propose opens
+    waited = [h for h in heights
+              if tracing.CONSENSUS_NEW_HEIGHT in children_of(h)]
+    assert waited, "no height with a consensus.new_height child"
+    for h in waited:
+        kids = sorted((e for e in evs
+                       if e["args"].get("parent_id") == h["args"]["span_id"]),
+                      key=lambda e: e["ts"])
+        assert kids[0]["name"] == tracing.CONSENSUS_NEW_HEIGHT
+        assert abs(kids[0]["ts"] - h["ts"]) < 1e3   # us: begun together
+        if len(kids) > 1:
+            assert kids[1]["name"] == tracing.CONSENSUS_PROPOSE
+            assert kids[0]["ts"] + kids[0]["dur"] <= kids[1]["ts"] + 1
+    applied = [e for e in evs if e["name"] == tracing.STATE_APPLY_BLOCK]
+    assert any(children_of(a) >= {
+        tracing.STATE_VALIDATE, tracing.STATE_EXEC,
+        tracing.STATE_SAVE_RESPONSES, tracing.STATE_APP_COMMIT,
+        tracing.STATE_SAVE, tracing.STATE_EVENTS} for a in applied)
+    assert any(e["name"] == tracing.STORE_SAVE_BLOCK for e in evs)
     assert any(e["name"] == tracing.STATE_APPLY_BLOCK for e in evs)
     assert any(e["name"] == tracing.WAL_FSYNC for e in evs)
 

@@ -1,0 +1,636 @@
+"""The program spans beneath the three host gaps (verify site, apply
+path, admission and height), the folding leaf form, compile_cache on
+expanded launch records, the kernels' phase names and
+GET /debug/profile.
+
+No kernel is compiled here: the verify sites run against fake device
+programs (the launch sites, spans and ledger records around them are
+the real ones), the apply path against sqlite under tmp_path."""
+
+from __future__ import annotations
+
+import asyncio
+import json
+
+import numpy as np
+import pytest
+
+from tendermint_tpu.libs import tracing
+from tendermint_tpu.libs.tracing import TRACER, Tracer
+
+from helpers import (
+    commit_for, make_genesis, make_genesis_state_and_pvs, next_block,
+    sign_commit,
+)
+
+NEW_KINDS = (
+    "verify.commit", "verify.collect", "verify.sign_batch",
+    "verify.tables", "verify.window",
+    "state.validate", "state.exec", "state.save_responses",
+    "state.app_commit", "state.save", "state.events",
+    "store.save_block", "db.write",
+    "consensus.new_height", "admission.queue_wait", "admission.flush",
+)
+APPLY_CHILDREN = [
+    "state.validate", "state.exec", "state.save_responses",
+    "state.app_commit", "state.save", "state.events",
+]
+
+
+def run(coro):
+    return asyncio.run(coro)
+
+
+def children(recs, parent):
+    """Records whose parent_id is `parent`'s span id, by start."""
+    return sorted((r for r in recs if r[2] == parent[1]),
+                  key=lambda r: r[4])
+
+
+def inside(child, parent) -> bool:
+    return parent[4] <= child[4] and \
+        child[4] + child[5] <= parent[4] + parent[5]
+
+
+def ancestors(recs, rec):
+    by_id = {r[1]: r for r in recs}
+    out = []
+    while rec[2] in by_id:
+        rec = by_id[rec[2]]
+        out.append(rec[0])
+    return out
+
+
+# ------------------------------------------------------------- registry
+
+
+@pytest.mark.parametrize("kind", NEW_KINDS)
+def test_new_kind_is_registered_and_required(kind):
+    from tools.check_spans import REQUIRED_KINDS, missing_required_kinds
+
+    assert kind in tracing.registered_kinds()
+    # the benchmark's per-layer readers find these by name
+    assert kind in REQUIRED_KINDS
+    assert missing_required_kinds() == []
+
+
+def test_kinds_no_site_begins_are_gone():
+    kinds = tracing.registered_kinds()
+    assert "consensus.new_round" not in kinds
+    assert "consensus.precommit_wait" not in kinds
+    # the five steps ConsensusState._new_step enters still resolve
+    for step in ("PROPOSE", "PREVOTE", "PREVOTE_WAIT", "PRECOMMIT",
+                 "COMMIT"):
+        assert tracing.consensus_step_kind(step) in kinds
+
+
+# ----------------------------------------------------------- leaf / begin
+
+
+def test_leaf_folds_a_run_into_one_entry():
+    import time
+
+    t = Tracer(capacity=64)
+    with t.span(tracing.STATE_EXEC) as parent:
+        for i in range(5):
+            t0 = time.perf_counter_ns()
+            t.leaf(tracing.DB_WRITE, t0, ops=1, bytes=10 + i)
+    recs = t.snapshot()
+    writes = [r for r in recs if r[0] == tracing.DB_WRITE]
+    assert len(writes) == 1
+    w = writes[0]
+    assert w[2] == parent.span_id
+    assert w[6]["n"] == 5 and w[6]["ops"] == 5
+    assert w[6]["bytes"] == sum(10 + i for i in range(5))
+    assert 0 < w[6]["busy_ns"] <= w[5]
+    assert inside(w, next(r for r in recs if r[0] == tracing.STATE_EXEC))
+
+
+def test_leaf_does_not_fold_across_parents_pauses_or_kinds():
+    import time
+
+    t = Tracer(capacity=64)
+    now = time.perf_counter_ns
+    with t.span(tracing.STATE_EXEC):
+        t.leaf(tracing.DB_WRITE, now(), ops=1)
+    with t.span(tracing.STATE_SAVE):
+        t.leaf(tracing.DB_WRITE, now(), ops=1)   # another parent
+        with t.span(tracing.CRYPTO_PACK):
+            pass                                  # another kind between
+        t.leaf(tracing.DB_WRITE, now(), ops=1)
+        # a repeat begun long after the last one ended stands alone
+        last = t.snapshot()[-1]
+        t.leaf(tracing.DB_WRITE,
+               last[4] + last[5] + tracing.LEAF_FOLD_NS + 1, ops=1)
+    writes = [r for r in t.snapshot() if r[0] == tracing.DB_WRITE]
+    assert len(writes) == 4
+    assert all("n" not in w[6] for w in writes)
+
+
+def test_leaf_counts_a_drop_when_the_ring_is_full():
+    import time
+
+    t = Tracer(capacity=4)
+    for _ in range(4):
+        with t.span(tracing.CRYPTO_PACK):
+            pass
+    t.leaf(tracing.DB_WRITE, time.perf_counter_ns(), ops=1)
+    assert len(t) == 4 and t.dropped == 1
+    t.leaf(tracing.DB_WRITE, time.perf_counter_ns(), ops=1)  # folds
+    assert len(t) == 4 and t.dropped == 1
+    with pytest.raises(ValueError):
+        t.leaf("db.nope", time.perf_counter_ns())
+    off = Tracer(enabled=False)
+    off.leaf(tracing.DB_WRITE, time.perf_counter_ns())
+    assert len(off) == 0
+
+
+def test_leaf_folding_beside_another_thread_loses_and_reorders_nothing():
+    """The fold rewrites the ring's newest entry: under the tracer's
+    lock, so a span another thread seals meanwhile is neither
+    overwritten nor moved."""
+    import threading
+    import time
+
+    t = Tracer(capacity=1 << 16)
+    n = 4000
+    stop = threading.Event()
+
+    def writer():
+        while not stop.is_set():
+            t.leaf(tracing.DB_WRITE, time.perf_counter_ns(), ops=1)
+
+    th = threading.Thread(target=writer)
+    th.start()
+    try:
+        for _ in range(n):
+            with t.span(tracing.CRYPTO_PACK):
+                pass
+            assert len(t.snapshot()) >= 1
+    finally:
+        stop.set()
+        th.join()
+    recs = t.snapshot()
+    packs = [r for r in recs if r[0] == tracing.CRYPTO_PACK]
+    assert len(packs) == n and t.dropped == 0
+    ends = [r[4] + r[5] for r in packs]
+    assert ends == sorted(ends)
+    writes = [r for r in recs if r[0] == tracing.DB_WRITE]
+    assert sum(w[6].get("n", 1) for w in writes) == sum(
+        w[6]["ops"] for w in writes)
+
+
+def test_resize_keeps_the_newest_spans():
+    t = Tracer(capacity=4)
+    for _ in range(4):
+        with t.span(tracing.CRYPTO_PACK):
+            pass
+    ids = [r[1] for r in t.snapshot()]
+    t.resize(8)
+    for _ in range(4):
+        with t.span(tracing.CRYPTO_PACK):
+            pass
+    assert t.capacity == 8 and len(t) == 8 and t.dropped == 0
+    assert [r[1] for r in t.snapshot()][:4] == ids
+    t.resize(2)
+    assert len(t) == 2 and [r[1] for r in t.snapshot()][0] > ids[-1]
+
+
+def test_begin_backdates_to_a_stamp():
+    import time
+
+    t = Tracer(capacity=8)
+    stamp = time.perf_counter_ns() - 5_000_000
+    t.begin(tracing.ADMISSION_QUEUE_WAIT, start_ns=stamp, lanes=3).end()
+    (rec,) = t.snapshot()
+    assert rec[4] == stamp and rec[5] >= 5_000_000
+    assert rec[6] == {"lanes": 3}
+
+
+# ------------------------------------------------------------ verify site
+
+
+def _fake_expanded(monkeypatch, vals):
+    """An ExpandedKeys for `vals` with no tables and a structured
+    program that accepts every lane: the host side of the launch
+    (prepare, shard args, compile count, spans, ledger) is the real one."""
+    import tendermint_tpu.types.validator_set as vs_mod
+    from tendermint_tpu.crypto.tpu import expanded as ex
+
+    keys = object.__new__(ex.ExpandedKeys)
+    keys.pubkeys = tuple(v.pub_key.bytes() for v in vals.validators)
+    keys.sharded = False
+    keys.mesh = None
+    n = len(keys.pubkeys)
+    keys.akeys = np.zeros((n, 32), np.uint8)
+    keys.key_ok = np.ones(n, bool)
+    keys.tables = np.zeros((n, 4), np.int32)
+    keys._maybe_reshard = lambda: None
+    monkeypatch.setattr(vs_mod, "_EXPAND_MIN", 2)
+    monkeypatch.setattr(ex, "get_expanded", lambda pubkeys: keys)
+    monkeypatch.setattr(ex, "max_keys", lambda: 1 << 20)
+    monkeypatch.setattr(
+        ex, "_skernel",
+        lambda wpi=None: lambda *, idx, width, **kw: np.ones(
+            idx.shape[0], bool))
+    return keys
+
+
+def _commit(n_vals=4, height=3):
+    from tendermint_tpu.types.block import BlockID, PartSetHeader
+
+    state, pvs = make_genesis_state_and_pvs(n_vals)
+    bid = BlockID(bytes([height]) * 32,
+                  PartSetHeader(1, bytes([height]) * 32))
+    commit = sign_commit(state.validators, pvs, state.chain_id, height, 0,
+                         bid, 1_700_000_000 * 10**9 + height)
+    return state, bid, commit
+
+
+def test_verify_commit_span_tree_and_compile_cache(monkeypatch):
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.crypto.tpu import ledger
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    cbatch.reset_breakers()
+    state, bid, commit = _commit()
+    vals = state.validators
+    _fake_expanded(monkeypatch, vals)
+    # a shape no other test of this process has launched
+    monkeypatch.setattr(tv, "_COMPILED_SHAPES", {})
+    ledger.reset()
+    TRACER.clear()
+    vals.verify_commit(state.chain_id, bid, 3, commit)
+    vals.verify_commit(state.chain_id, bid, 3, commit)
+
+    recs = TRACER.snapshot()
+    roots = [r for r in recs if r[0] == tracing.VERIFY_COMMIT]
+    assert len(roots) == 2
+    for root in roots:
+        assert root[2] == 0
+        assert root[6] == {"form": "full", "lanes": 4, "structured": True}
+        kids = children(recs, root)
+        assert [k[0] for k in kids] == [
+            tracing.VERIFY_COLLECT, tracing.VERIFY_SIGN_BATCH,
+            tracing.VERIFY_TABLES, tracing.CRYPTO_VERIFY]
+        assert all(inside(k, root) for k in kids)
+        # children follow one another; what is left is verdict
+        # handling, verify.commit's own time
+        for a, b in zip(kids, kids[1:]):
+            assert a[4] + a[5] <= b[4]
+        launch = kids[-1]
+        assert {k[0] for k in children(recs, launch)} >= {
+            tracing.CRYPTO_PACK, tracing.CRYPTO_DISPATCH,
+            tracing.CRYPTO_READBACK}
+
+    # the structured launch's record says what count_compile answered
+    launches = [r for r in ledger.snapshot() if r["kernel"] == "structured"]
+    assert [r["compile_cache"] for r in launches] == ["miss", "hit"]
+
+
+def test_verify_commit_light_and_trusting_forms(monkeypatch):
+    from tendermint_tpu.crypto import batch as cbatch
+
+    cbatch.reset_breakers()
+    state, bid, commit = _commit()
+    vals = state.validators
+    _fake_expanded(monkeypatch, vals)
+    TRACER.clear()
+    vals.verify_commit_light(state.chain_id, bid, 3, commit)
+    vals.verify_commit_light_trusting(state.chain_id, commit, 1, 3)
+    recs = TRACER.snapshot()
+    forms = [r[6]["form"] for r in recs if r[0] == tracing.VERIFY_COMMIT]
+    assert forms == ["light", "trusting"]
+    for root in (r for r in recs if r[0] == tracing.VERIFY_COMMIT):
+        assert root[6]["lanes"] >= 2 and root[6]["structured"] is True
+        assert [k[0] for k in children(recs, root)] == [
+            tracing.VERIFY_TABLES, tracing.CRYPTO_VERIFY]
+    # the selection half (plan_commit_*) is spanned too, before execute
+    assert [r[0] for r in recs if r[0] in (
+        tracing.VERIFY_COLLECT, tracing.VERIFY_SIGN_BATCH)] == [
+        tracing.VERIFY_COLLECT, tracing.VERIFY_SIGN_BATCH] * 2
+
+
+def test_verify_window_span_has_its_building_apart_from_its_launch(
+        monkeypatch):
+    from tendermint_tpu.blockchain.verify_ahead import WindowPipeline
+    from tendermint_tpu.crypto import batch as cbatch
+
+    cbatch.reset_breakers()
+    state, pvs = make_genesis_state_and_pvs(4)
+    _fake_expanded(monkeypatch, state.validators)
+    blocks, last_commit, st = [], None, state
+    for _ in range(4):
+        block, bid = next_block(st, pvs, last_commit, [b"k=v"])
+        last_commit = commit_for(st, pvs, block, bid)
+        blocks.append(block)
+        # only the header chain matters to the window: keep the valset
+        st = st.copy() if hasattr(st, "copy") else st
+        st.last_block_height = block.header.height
+        st.last_block_id = bid
+        st.last_block_time = block.header.time
+    TRACER.clear()
+    items, parts, results = WindowPipeline._verify_window_job(
+        state.validators, state.chain_id, blocks)
+    assert len(items) == len(parts) == len(results) == 3
+    recs = TRACER.snapshot()
+    (window,) = [r for r in recs if r[0] == tracing.VERIFY_WINDOW]
+    assert window[6]["blocks"] == 3 and window[6]["lanes"] >= 3 * 3
+    kids = children(recs, window)
+    assert [k[0] for k in kids][:2] == [
+        tracing.VERIFY_COLLECT, tracing.VERIFY_SIGN_BATCH]
+    # the part sets are built before collect begins: window time that
+    # is neither a child nor a launch
+    assert kids[0][4] > window[4]
+    assert tracing.VERIFY_WINDOW in ancestors(
+        recs, next(r for r in recs if r[0] == tracing.CRYPTO_VERIFY))
+
+
+# -------------------------------------------------------------- apply path
+
+
+def test_apply_block_children_in_order_and_db_writes(tmp_path,
+                                                     monkeypatch):
+    from tendermint_tpu.abci.client import LocalClient
+    from tendermint_tpu.abci.kvstore import PersistentKVStoreApp
+    from tendermint_tpu.libs.db import SqliteDB
+    from tendermint_tpu.state import make_genesis_state
+    from tendermint_tpu.state.execution import BlockExecutor
+    from tendermint_tpu.state.store import Store
+    from tendermint_tpu.store import BlockStore
+
+    commits = {"n": 0}
+    for name in ("set", "delete", "write_batch"):
+        real = getattr(SqliteDB, name)
+
+        def counted(self, *a, _real=real, **kw):
+            commits["n"] += 1   # each is one durable commit
+            return _real(self, *a, **kw)
+
+        monkeypatch.setattr(SqliteDB, name, counted)
+
+    async def go():
+        gdoc, pvs = make_genesis(4)
+        state = make_genesis_state(gdoc)
+        dbs = [SqliteDB(str(tmp_path / f"{n}.sqlite"))
+               for n in ("state", "blockstore", "app")]
+        store = Store(dbs[0])
+        store.save(state)
+        block_store = BlockStore(dbs[1])
+        client = LocalClient(PersistentKVStoreApp(dbs[2]))
+        await client.start()
+        executor = BlockExecutor(store, client)
+        last_commit = None
+        per_block = []
+        for h in range(2):
+            block, bid = next_block(state, pvs, last_commit,
+                                    [b"a%d=1" % h, b"b%d=2" % h,
+                                     b"c%d=3" % h])
+            seen = commit_for(state, pvs, block, bid)
+            TRACER.clear()
+            commits["n"] = 0
+            block_store.save_block(block, block.make_part_set(), seen)
+            state, _ = await executor.apply_block(state, bid, block)
+            per_block.append((TRACER.snapshot(), commits["n"]))
+            last_commit = seen
+        await client.stop()
+        for d in dbs:
+            d.close()
+        return per_block
+
+    for recs, durable in run(go()):
+        (apply_,) = [r for r in recs if r[0] == tracing.STATE_APPLY_BLOCK]
+        kids = [k for k in children(recs, apply_)]
+        assert [k[0] for k in kids] == APPLY_CHILDREN
+        assert all(inside(k, apply_) for k in kids)
+        for a, b in zip(kids, kids[1:]):
+            assert a[4] + a[5] <= b[4]
+        assert kids[1][6] == {"txs": 3}
+        (saved,) = [r for r in recs if r[0] == tracing.STORE_SAVE_BLOCK]
+        assert saved[4] + saved[5] <= apply_[4]
+        # as many db.write as durable commits: one a tx in DeliverTx
+        # (a run, folded), one for each of the store's batches
+        writes = [r for r in recs if r[0] == tracing.DB_WRITE]
+        assert sum(w[6].get("n", 1) for w in writes) == durable >= 7
+        by_parent = {}
+        by_id = {r[1]: r[0] for r in recs}
+        for w in writes:
+            kind = by_id.get(w[2])
+            by_parent[kind] = by_parent.get(kind, 0) + w[6].get("n", 1)
+        assert by_parent[tracing.STATE_EXEC] == 3
+        assert by_parent[tracing.STORE_SAVE_BLOCK] == 1
+        assert by_parent[tracing.STATE_SAVE_RESPONSES] == 1
+        assert by_parent[tracing.STATE_APP_COMMIT] == 1
+        assert by_parent[tracing.STATE_SAVE] >= 1
+        # the three DeliverTx commits are ONE ring entry
+        assert len([w for w in writes
+                    if by_id.get(w[2]) == tracing.STATE_EXEC]) == 1
+
+
+# --------------------------------------------------------------- admission
+
+
+def test_admission_spans_cut_reasons_and_lineage(monkeypatch):
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.crypto.ed25519 import Ed25519PrivKey
+    from tendermint_tpu.crypto.tpu import verify as tv
+    from tendermint_tpu.mempool.admission import AdmissionCollector
+    from tendermint_tpu.types import tx_envelope
+
+    cbatch.reset_breakers()
+    monkeypatch.setattr(tv, "_mesh", lambda: None)
+    monkeypatch.setattr(
+        tv, "_kernel",
+        lambda: lambda btab, **packed: np.ones(
+            packed["s_ok"].shape[0], bool))
+    signer = Ed25519PrivKey.from_secret(b"admission-span-signer")
+
+    def env(i):
+        return tx_envelope.parse(
+            tx_envelope.sign_tx(signer, b"tx-%d" % i))
+
+    async def go():
+        col = AdmissionCollector(batch_max=4, flush_ms=30.0,
+                                 device_threshold=1)
+        try:
+            # a request's span is current when the flusher starts: the
+            # batch spans must not hang beneath it
+            with TRACER.span(tracing.P2P_RECV_MSG):
+                full = await asyncio.gather(
+                    *(col.verify(env(i)) for i in range(4)))
+            late = await col.verify(env(9))
+            return full, late
+        finally:
+            col.close()
+
+    TRACER.clear()
+    full, late = run(go())
+    assert all(full) and late
+    recs = TRACER.snapshot()
+    waits = [r for r in recs if r[0] == tracing.ADMISSION_QUEUE_WAIT]
+    assert [(w[6]["lanes"], w[6]["cut"]) for w in waits] == [
+        (4, "full"), (1, "deadline")]
+    assert all(w[2] == 0 for w in waits)
+    assert waits[1][5] >= 25_000_000          # it waited its deadline out
+    assert waits[1][6]["wait_sum_ms"] >= 25.0
+    assert waits[0][6]["wait_sum_ms"] >= 0.0
+    flushes = [r for r in recs if r[0] == tracing.ADMISSION_FLUSH]
+    assert [(f[6]["lanes"], f[6]["backend"]) for f in flushes] == [
+        (4, "device"), (1, "device")]
+    assert all(f[2] == 0 for f in flushes)
+    for w, f in zip(waits, flushes):
+        assert w[4] + w[5] <= f[4]             # the cut ends one, opens the other
+    verifies = [r for r in recs if r[0] == tracing.CRYPTO_VERIFY]
+    assert len(verifies) == 2
+    for v in verifies:
+        assert v[2] != 0
+        assert tracing.ADMISSION_FLUSH in ancestors(recs, v)
+        assert v[3] != flushes[0][3]           # it ran in a worker thread
+
+
+def test_admission_flush_names_the_host_backend():
+    from tendermint_tpu.crypto.ed25519 import Ed25519PrivKey
+    from tendermint_tpu.mempool.admission import AdmissionCollector
+    from tendermint_tpu.types import tx_envelope
+
+    signer = Ed25519PrivKey.from_secret(b"admission-span-signer")
+
+    async def go():
+        col = AdmissionCollector(batch_max=2, flush_ms=1.0,
+                                 device_threshold=1 << 20)
+        try:
+            return await col.verify(tx_envelope.parse(
+                tx_envelope.sign_tx(signer, b"small")))
+        finally:
+            col.close()
+
+    TRACER.clear()
+    assert run(go())
+    (flush,) = [r for r in TRACER.snapshot()
+                if r[0] == tracing.ADMISSION_FLUSH]
+    assert flush[6] == {"lanes": 1, "backend": "host"}
+
+
+# ------------------------------------------------------------ device names
+
+
+def test_jitted_program_names_are_pinned():
+    """benchmark/layer_metrics/trace_module.py finds the programs in a
+    profiler trace by these names (`jit_skernel`, `jit_kernel`)."""
+    from tendermint_tpu.crypto.tpu import expanded as ex
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    assert ex._skernel().__name__ == "skernel"
+    assert ex._skernel_sharded().__name__ == "skernel"
+    assert ex._xkernel().__name__ == "kernel"
+    assert tv._kernel().__name__ == "kernel"
+
+
+def test_assemble_is_traced_under_its_phase():
+    import jax
+
+    from tendermint_tpu.crypto.tpu import expanded as ex
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    n = 8
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ex.assemble_core()(*a, 192))(
+        np.zeros((32, 128), np.uint8), np.zeros(32, np.int32),
+        np.zeros((32, 64), np.uint8), np.zeros(32, np.int32),
+        np.zeros((n, 24), np.uint8), np.zeros(n, np.int32),
+        np.zeros(n, np.int32), np.zeros(n, np.int32))
+    stacks = {str(e.source_info.name_stack) for e in jaxpr.jaxpr.eqns}
+    assert stacks == {tv.PHASE_ASSEMBLE}
+    assert set(tv.PHASES) == {
+        "ed25519.assemble", "ed25519.gather", "ed25519.sha512",
+        "ed25519.decompress", "ed25519.msm", "ed25519.compare"}
+
+
+def test_phase_of_instructions_reads_optimized_hlo():
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    hlo = '''
+%fused_computation.7 (p: s32[8]) -> s32[8] {
+  %select.3 = s32[8]{0} select(%a, %b, %c), metadata={op_name="jit(skernel)/ed25519.assemble/select_n" stack_frame_id=4}
+}
+ENTRY %main {
+  %fusion.7 = s32[1966080]{0:T(1024)} fusion(%p.1), kind=kLoop, calls=%fused_computation.7, metadata={op_name="jit(skernel)/ed25519.assemble/select_n" stack_frame_id=4}
+  %fusion.5 = s32[706560,128]{1,0} fusion(%atab, %bitcast.2), kind=kCustom, calls=%fc.5, metadata={op_name="jit(skernel)/ed25519.gather/gather"}
+  %while.1 = (s32[], s32[22,8]) while(%tuple.3), condition=%cond, body=%body, metadata={op_name="jit(skernel)/ed25519.msm/while"}
+  %mul.9 = s32[22,8]{1,0} multiply(%x, %y), metadata={op_name="jit(skernel)/ed25519.msm/while/body/ed25519.decompress/mul"}
+  %copy.139 = s32[69,88,8]{2,1,0} copy(%bitcast.77)
+  %add.1 = s32[8]{0} add(%x, %y), metadata={op_name="jit(skernel)/not_ed25519.msm/add"}
+  ROOT %and.4 = pred[8]{0} and(%l, %r), metadata={op_name="jit(skernel)/ed25519.compare/and"}
+}
+'''
+    assert tv.phase_of_instructions(hlo) == {
+        "select.3": "ed25519.assemble",
+        "fusion.7": "ed25519.assemble",
+        "fusion.5": "ed25519.gather",
+        "while.1": "ed25519.msm",
+        "mul.9": "ed25519.decompress",     # the innermost scope
+        "and.4": "ed25519.compare",
+    }
+
+
+# ----------------------------------------------------------- /debug/profile
+
+
+def test_debug_profile_endpoint(tmp_path, monkeypatch):
+    """On the CPU backend: the profiler runs, the clock-sync stamp and
+    the interval's spans come back, a second session is refused."""
+    import glob
+    import os
+    import time
+
+    from tendermint_tpu.libs import debugsrv
+
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    import tempfile
+
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+
+    async def get(port, path):
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        writer.write(f"GET {path} HTTP/1.0\r\n\r\n".encode())
+        await writer.drain()
+        raw = await reader.read()
+        writer.close()
+        head, _, body = raw.partition(b"\r\n\r\n")
+        assert b"200 OK" in head and b"application/json" in head
+        return json.loads(body)
+
+    async def go():
+        srv = debugsrv.DebugServer()
+        port = await srv.start()
+        try:
+            first = asyncio.ensure_future(
+                get(port, "/debug/profile?seconds=0.4"))
+            while not debugsrv._device_profile_running:
+                await asyncio.sleep(0.01)
+            second = await get(port, "/debug/profile?seconds=0.1")
+            while not first.done():   # the node's work goes on meanwhile
+                with TRACER.span(tracing.CRYPTO_PACK, lanes=1):
+                    await asyncio.sleep(0.01)
+            return await first, second
+        finally:
+            srv.close()
+
+    before = time.perf_counter_ns()
+    TRACER.clear()
+    first, second = run(go())
+    assert second == {"error": "a device profile is already running"}
+    assert before < first["sync_ns"] < time.perf_counter_ns()
+    assert 0.4 <= first["seconds"] < 5.0
+    assert os.path.dirname(first["trace_dir"]) == str(tmp_path)
+    assert glob.glob(os.path.join(first["trace_dir"], "plugins", "profile",
+                                  "*", "*.xplane.pb"))
+    assert first["spans"] and {s[0] for s in first["spans"]} == {
+        tracing.CRYPTO_PACK}
+    assert all(s[1] < first["sync_ns"] + first["seconds"] * 1e9
+               and s[1] + s[2] > first["sync_ns"] for s in first["spans"])
+    assert first["spans_dropped"] == 0
+    # the cap holds whatever is asked for
+    assert debugsrv._parse_seconds(
+        "3600", 1.0, cap=debugsrv.DEVICE_PROFILE_CAP_S) == 10.0
+    assert debugsrv._device_profile_running is False

@@ -40,7 +40,7 @@ PKG = os.path.join(REPO, "tendermint_tpu")
 ENABLED_BUDGET_S = 50e-6
 DISABLED_BUDGET_S = 10e-6
 
-_SPAN_METHODS = {"span", "begin"}
+_SPAN_METHODS = {"span", "begin", "leaf"}
 
 # Stage kinds the rollup/export surfaces (BENCH
 # stage_breakdown, /debug/trace/rollup, the tracer-pinned acceptance
@@ -54,6 +54,14 @@ REQUIRED_KINDS = frozenset({
     "speculation.speculate", "speculation.patch",
     "speculation.reconcile",
     "state.apply_block", "wal.fsync",
+    # benchmark/layer_metrics/*.json read these by name (the splits of
+    # the verify site, the apply path, the admission plane, a height)
+    "verify.commit", "verify.collect", "verify.sign_batch",
+    "verify.tables", "verify.window",
+    "state.validate", "state.exec", "state.save_responses",
+    "state.app_commit", "state.save", "state.events",
+    "store.save_block", "db.write",
+    "admission.queue_wait", "admission.flush", "consensus.new_height",
     # height forensics reads these two by name: recv spans carry the
     # rehydrated origin tags, send_flush is the wire-side counterpart
     "p2p.recv_msg", "p2p.send_flush",
