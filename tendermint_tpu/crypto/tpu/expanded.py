@@ -39,6 +39,7 @@ Memory: V * 69 * 9 * 512 B ≈ 318 KB/key — 3.3 GB for 10,240 keys.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import hashlib
 import threading
@@ -213,9 +214,11 @@ WINDOWS_PER_ITER = int(__import__("os").environ.get(
 
 @functools.cache
 def _xcore(wpi: int = WINDOWS_PER_ITER):
-    """The shared verify body: everything after the (N, W) message
-    buffer exists on device. Both front-ends — bytes (`_xkernel`) and
-    structured template+patch (`_skernel`) — trace through this."""
+    """The shared verify body: everything after the padded message
+    exists on device. Both front-ends trace through this — bytes
+    (`_xkernel`: msg is the (N, W) buffer the host packed) and
+    structured template+patch (`_skernel`: msg is the words
+    assemble_core forms); sha512.challenge_words takes either."""
     import jax
     import jax.numpy as jnp
 
@@ -239,8 +242,8 @@ def _xcore(wpi: int = WINDOWS_PER_ITER):
         with jax.named_scope(tv.PHASE_SHA512):
             # SHA-512(R || A || M) + fold, exactly as the general
             # kernel.
-            full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
-            digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
+            digest = sh.compress_blocks(
+                sh.challenge_words(sb[:, :32], ab, msg), nblocks)
             digk = sc.fold_digest(
                 sh.digest_bytes_le(digest))[::-1]  # LSB-first
             # Signed recode: nibbles (0..15) -> digits in [-8, 8] with
@@ -364,63 +367,97 @@ def _xkernel_sharded(wpi: int = WINDOWS_PER_ITER):
 def assemble_core():
     """The structured message-assembly body as a traceable function:
     (pre, pre_len, suf, suf_len, patch, split, patch_len, group,
-    width) -> (msg uint8 (N, width), nblocks (N,)). Builds each lane's
-    sign bytes ON DEVICE from commit-wide templates plus a <=24-byte
-    per-lane timestamp patch (types/sign_batch.py layout:
-    outer_varint ‖ pre[group] ‖ ts_field ‖ suf[group]) and applies the
-    SHA-512 padding tail. Shared by `_skernel` (expanded-table path)
-    and crypto/tpu/resident.py's arena kernel (general-kernel path
-    over device-resident buffers)."""
+    width) -> (words uint32 (width/8, 2, N), nblocks (N,)). Builds each
+    lane's sign bytes ON DEVICE from commit-wide templates plus a
+    <=24-byte per-lane timestamp patch (types/sign_batch.py layout:
+    outer_varint ‖ pre[group] ‖ ts_field ‖ suf[group]), applies the
+    SHA-512 padding tail and forms the hash's words
+    (sha512.challenge_words puts R ‖ A in front). Shared by `_skernel`
+    (expanded-table path) and crypto/tpu/resident.py's arena kernels
+    (general-kernel path over device-resident buffers).
+
+    Lanes are the minor axis throughout, (rows, N), and no element is
+    gathered: a lane's template rows are selected group by group, and
+    a source lands at its per-lane row offset by a barrel shift, one
+    select of a statically shifted copy per bit of the offset. (The
+    same bytes placed by take_along_axis over an (N, width) index
+    plane were 60 % of the 10,240-lane kernel: PERF.md §6, PR 25.)"""
     import jax
     import jax.numpy as jnp
+
+    from . import sha512 as sh
+
+    def by_group(table, group):
+        """(K, C) template rows -> (C, N): row group[lane] of the
+        table, one lane a column (group is the (1, N) row)."""
+        cols = table.T.astype(jnp.int32)
+        out = jnp.zeros((cols.shape[0], group.shape[1]), jnp.int32)
+        for g in range(cols.shape[1]):
+            out = jnp.where(group == g, cols[:, g:g + 1], out)
+        return out
+
+    def live(src, lo, hi):
+        """src (C, N) with each lane's rows outside [lo, hi) zeroed,
+        so that placed sources can be OR-ed together."""
+        j = jnp.arange(src.shape[0], dtype=jnp.int32)[:, None]
+        return jnp.where((j >= lo) & (j < hi), src, 0)
+
+    def place(src, offset, bound, width):
+        """src (C, N) -> (width, N): lane l's row i lands on row
+        i + offset[l], for 0 <= offset <= bound (static). Stage k
+        moves the lanes whose offset has bit k set down by 2^k rows;
+        the plane grows as it goes, so the early stages are narrow."""
+        x = src
+        for k in range(bound.bit_length()):
+            step = 1 << k
+            rows = min(x.shape[0] + step, width)
+            moved = jnp.pad(x, ((step, 0), (0, 0)))[:rows]
+            x = jnp.pad(x, ((0, rows - x.shape[0]), (0, 0)))
+            x = jnp.where((offset & step) != 0, moved, x)
+        return jnp.pad(x, ((0, width - x.shape[0]), (0, 0)))
 
     @jax.named_scope(tv.PHASE_ASSEMBLE)
     def assemble(pre, pre_len, suf, suf_len, patch, split, patch_len,
                  group, width):
-        j = jnp.arange(width, dtype=jnp.int32)[None, :]       # (1, W)
-        p_len = pre_len[group][:, None]                       # (N, 1)
-        s_len = suf_len[group][:, None]
-        a = split[:, None].astype(jnp.int32)
-        b = (patch_len - split)[:, None].astype(jnp.int32)
-        c1 = a + p_len
-        c2 = c1 + b
-        c3 = c2 + s_len                                       # = mlen
-        pre_g = pre[group].astype(jnp.int32)                  # (N, PW)
-        suf_g = suf[group].astype(jnp.int32)
-        patch_i = patch.astype(jnp.int32)
-
-        def gat(src, col):
-            return jnp.take_along_axis(
-                src, jnp.clip(col, 0, src.shape[1] - 1), axis=1)
-
-        msg = jnp.where(
-            j < a, gat(patch_i, j),
-            jnp.where(j < c1, gat(pre_g, j - a),
-                      jnp.where(j < c2, gat(patch_i, a + (j - c1)),
-                                jnp.where(j < c3, gat(suf_g, j - c2),
-                                          0))))
+        # the two live bytes of the 16-byte bit length
+        assert (64 + width) * 8 < 1 << 16
+        pw, tw = pre.shape[1], patch.shape[1]
+        # per-lane scalars as (1, N) rows, beside the (rows, N) planes
+        group = group[None, :]
+        p_len = by_group(pre_len[:, None], group)
+        s_len = by_group(suf_len[:, None], group)
+        a = split[None, :].astype(jnp.int32)
+        t_end = patch_len[None, :].astype(jnp.int32)          # a + b
+        c3 = t_end + p_len + s_len                            # = mlen
+        patch_t = patch.T.astype(jnp.int32)                   # (TW, N)
+        msg = (
+            place(live(patch_t, 0, a), 0, 0, width)
+            | place(live(by_group(pre, group), 0, p_len), a, 2, width)
+            | place(live(patch_t, a, t_end), p_len, pw, width)
+            | place(live(by_group(suf, group), 0, s_len),
+                    t_end + p_len, pw + tw, width))
+        j = jnp.arange(width, dtype=jnp.int32)[:, None]       # (W, 1)
         msg = jnp.where(j == c3, 0x80, msg)
         # SHA-512 padding tail: 16-byte big-endian bit length at the
-        # end of the lane's last block (bit length < 2^13 here, so
+        # end of the lane's last block (bit length < 2^16 here, so
         # only the low 2 bytes are ever nonzero).
-        mlen = c3
-        nblocks = (64 + mlen + 17 + 127) // 128               # (N, 1)
-        bitlen = (64 + mlen) * 8
-        k = 15 - (j - (nblocks * 128 - 16 - 64))              # 15..0
-        lenbyte = jnp.where(k < 4, (bitlen >> (8 * jnp.clip(k, 0, 3)))
-                            & 0xFF, 0)
-        msg = jnp.where((k >= 0) & (k < 16), lenbyte, msg)
-        return msg.astype(jnp.uint8), nblocks[:, 0]
+        nblocks = (64 + c3 + 17 + 127) // 128
+        bitlen = (64 + c3) * 8
+        last = nblocks * 128 - 64 - 1
+        msg = jnp.where(j == last, bitlen & 0xFF,
+                        jnp.where(j == last - 1, bitlen >> 8, msg))
+        return sh.rows_to_words(msg), nblocks[0]
 
     return assemble
 
 
 @functools.cache
 def _skernel(wpi: int = WINDOWS_PER_ITER):
-    """Structured front-end: assemble the (N, width) message buffer ON
-    DEVICE (assemble_core) then verify through the expanded-table body
-    (_xcore). Per-lane transfer drops from ~190 B of sign bytes to the
-    patch + two ints; the templates ship once per launch."""
+    """Structured front-end: assemble each lane's padded message ON
+    DEVICE, as the words SHA-512 reads (assemble_core), then verify
+    through the expanded-table body (_xcore). Per-lane transfer drops
+    from ~190 B of sign bytes to the patch + two ints; the templates
+    ship once per launch."""
     import jax
 
     core = _xcore(wpi)
@@ -466,13 +503,35 @@ def _skernel_sharded(wpi: int = WINDOWS_PER_ITER):
     return skernel
 
 
+@contextlib.contextmanager
+def _phase_names_in_key():
+    """What a structured launch is lowered and compiled under: the
+    operations' names (`op_name`: the ed25519.* scope and the
+    primitive) are in the module and in the persistent cache's key, and
+    no file, line or traceback is. An executable loaded from the cache
+    was then compiled from these very names, whoever compiled it, so
+    structured_phases() reads them off the launch's own executable. A
+    line shift in these files still hits; renaming or moving a scope
+    compiles anew. Both settings are per thread and are read only
+    when a shape is lowered and looked up, on its first launch."""
+    from jax._src import config as jax_config  # no public per-thread form
+
+    with jax_config.traceback_in_locations_limit(0), \
+            jax_config.compilation_cache_include_metadata_in_key(True):
+        yield
+
+
 def _aval(a):
-    """Shape, dtype and (for a device array) placement of a launch
-    argument: what `.lower()` needs in its stead."""
+    """Shape, dtype and placement of a launch argument: what `.lower()`
+    needs in its stead. The placement only of an array that is
+    committed to it, as a call takes it: the tables of one chip are
+    not (they follow the default device), and with a sharding named
+    for them the program would lower, and key, as another one."""
     import jax
 
+    placed = getattr(a, "committed", False)
     return jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                sharding=getattr(a, "sharding", None))
+                                sharding=a.sharding if placed else None)
 
 
 def _count_compile(kernel: str, shape: tuple) -> None:
@@ -1094,23 +1153,25 @@ class ExpandedKeys:
                            (self.n_shards, lidx.shape[1], width))
             repl = {k: jax.device_put(fields[k], repl_s)
                     for k in self._S_REPL}
-            out = _skernel_sharded(WINDOWS_PER_ITER)(
-                idx=lidx,
-                akeys=self.akeys,
-                key_ok=self.key_ok,
-                atab=self.tables,
-                btab=btab,
-                width=width,
-                **routed,
-                **repl,
-            )
+            with _phase_names_in_key():
+                out = _skernel_sharded(WINDOWS_PER_ITER)(
+                    idx=lidx,
+                    akeys=self.akeys,
+                    key_ok=self.key_ok,
+                    atab=self.tables,
+                    btab=btab,
+                    width=width,
+                    **routed,
+                    **repl,
+                )
             return _RoutedVerdicts(out, slot)
         idx, fields, btab = self._shard_args(
             idx, fields, repl_keys=self._S_REPL)
         _count_compile("structured", (idx.shape[0], width))
-        return _skernel(WINDOWS_PER_ITER)(
-            idx=idx, akeys=self.akeys, key_ok=self.key_ok,
-            atab=self.tables, btab=btab, width=width, **fields)
+        with _phase_names_in_key():
+            return _skernel(WINDOWS_PER_ITER)(
+                idx=idx, akeys=self.akeys, key_ok=self.key_ok,
+                atab=self.tables, btab=btab, width=width, **fields)
 
     def _structured_avals(self, bucket: int) -> dict:
         """What _launch_structured hands the program for `bucket`
@@ -1243,20 +1304,15 @@ def structured_phases() -> dict[str, str]:
     structured program, at the shape this process compiled last and
     over the tables used last: for whoever lays a profiler trace's
     device operations on the kernels' phases. A trace names an
-    operation by its optimized-HLO instruction and holds no scope, and
-    an executable loaded from the persistent cache carries the source
-    names of whichever process compiled it first (the cache key leaves
-    metadata out, and stays so: with it in, a line shift in this file
-    would recompile every shape). So the program is lowered once more,
-    from a new function object (jit's in-memory caches would hand back
-    the loaded executable), and compiled under a key that holds its
-    metadata, for this thread and this compile only: a hit there was
-    compiled from these very names. Tens of seconds at a 10,240-lane
-    shape where it misses; nothing on the launch path keeps anything
-    for it."""
-    import jax
-    from jax._src import config as jax_config  # no public per-thread form
-
+    operation by its optimized-HLO instruction and holds no scope. The
+    names are read off the executable the launches run: structured
+    launches are lowered and cached with their operations' names in
+    the key (_phase_names_in_key), so even one loaded from the
+    persistent cache was compiled from these very names. Lowering the
+    program again from the launch's shapes and placements
+    (_structured_avals) is answered by jit's own caches with that
+    executable: nothing compiles and nothing is loaded. Nothing on the
+    launch path keeps anything for it."""
     with _CACHE_LOCK:
         keys = next(reversed(_CACHE.values()), None)
     shape = next((k[1:] for k in reversed(tv._COMPILED_SHAPES)
@@ -1265,13 +1321,8 @@ def structured_phases() -> dict[str, str]:
         raise ValueError("no structured launch on one chip's tables "
                          "yet: nothing to map")
     bucket, width = shape
-    inner = _skernel(WINDOWS_PER_ITER).__wrapped__
-
-    def skernel(**kw):
-        return inner(**kw)
-
-    with jax_config.compilation_cache_include_metadata_in_key(True):
-        compiled = jax.jit(skernel, static_argnames=("width",)).lower(
+    with _phase_names_in_key():
+        compiled = _skernel(WINDOWS_PER_ITER).lower(
             width=width, **keys._structured_avals(bucket)).compile()
     return tv.phase_of_instructions(compiled.as_text())
 

@@ -248,6 +248,34 @@ def bytes_to_words(msg_bytes):
     return jnp.stack([hi, lo], axis=3).transpose(1, 2, 3, 0)  # (B, 16, 2, N)
 
 
+def rows_to_words(rows):
+    """(W, N) byte rows, lanes minor, W a multiple of 8 -> (W/8, 2, N)
+    uint32: the big-endian 64-bit words of each lane's W bytes as
+    (hi, lo) pairs, in the order compress_blocks reads them. The lanes
+    stay where they are, so nothing is transposed."""
+    jax, jnp = _jnp()
+    w, n = rows.shape
+    x = rows.astype(jnp.uint32).reshape(w // 8, 8, n)
+    hi = (x[:, 0] << 24) | (x[:, 1] << 16) | (x[:, 2] << 8) | x[:, 3]
+    lo = (x[:, 4] << 24) | (x[:, 5] << 16) | (x[:, 6] << 8) | x[:, 7]
+    return jnp.stack([hi, lo], axis=1)
+
+
+def challenge_words(r, a, msg):
+    """(B, 16, 2, N) words of R || A || M for compress_blocks. r, a:
+    (N, 32) byte rows. msg is what follows them, padded: (N, W) byte
+    rows (the bytes front-ends), or the (W/8, 2, N) words the
+    structured assembly forms lanes-minor (expanded.assemble_core) —
+    R || A is 64 bytes, eight whole words, so its words are formed
+    apart and put in front."""
+    jax, jnp = _jnp()
+    if msg.ndim == 2:
+        return bytes_to_words(jnp.concatenate([r, a, msg], axis=1))
+    head = rows_to_words(jnp.concatenate([r, a], axis=1).T)
+    words = jnp.concatenate([head, msg], axis=0)
+    return words.reshape(words.shape[0] // 16, 16, 2, words.shape[2])
+
+
 def digest_bytes_le(state):
     """(8, 2, N) uint32 digest -> (64, N) int32 bytes, little-endian order
     (byte row j = j-th byte of the digest as an integer's LE expansion)."""

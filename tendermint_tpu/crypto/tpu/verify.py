@@ -211,7 +211,9 @@ def phase_of_instructions(hlo_text: str) -> dict[str, str]:
 def general_core():
     """The general-kernel verify body as a traceable function of
     (ab, sb, msg, nblocks, s_ok, btab) — per-lane pubkey BYTES, fully
-    assembled message buffers. Shared by the jitted `_kernel` here and
+    assembled messages ((N, W) padded bytes, or the words the
+    structured assembly forms: sha512.challenge_words takes either).
+    Shared by the jitted `_kernel` here and
     by crypto/tpu/resident.py's arena kernel (device-resident buffers
     + on-device structured message assembly in front of this exact
     body, so both paths verify bit-identically)."""
@@ -230,8 +232,8 @@ def general_core():
         n = ab.shape[0]
         with jax.named_scope(PHASE_SHA512):
             # --- SHA-512 of R || A || M, all lanes at once.
-            full = jnp.concatenate([sb[:, :32], ab, msg], axis=1)
-            digest = sh.compress_blocks(sh.bytes_to_words(full), nblocks)
+            digest = sh.compress_blocks(
+                sh.challenge_words(sb[:, :32], ab, msg), nblocks)
             digk = sc.fold_digest(
                 sh.digest_bytes_le(digest))  # (69, N) MSB-first
         with jax.named_scope(PHASE_DECOMPRESS):

@@ -149,9 +149,9 @@ def test_structured_avals_are_what_a_launch_hands_the_program(
 
 
 def test_structured_phases_of_the_shape_launched_last(monkeypatch):
-    """The instruction -> phase map comes from a compile of this
-    process's own names, at the (24 keys, bucket 64, width 192) shape
-    the tests above launch; no launch keeps anything for it."""
+    """The instruction -> phase map is read off the executable the
+    launches run, at the (24 keys, bucket 64, width 192) shape the
+    tests above launch; no launch keeps anything for it."""
     from tendermint_tpu.crypto.tpu import verify as tv
 
     pubs, commit, lanes, sigs, expect = _mk()
@@ -162,10 +162,50 @@ def test_structured_phases_of_the_shape_launched_last(monkeypatch):
     e = ex.ExpandedKeys(pubs)
     ex._CACHE[b"k"] = e
     assert list(e.verify_structured(lanes, sb, sigs)) == expect
-    phase_of = ex.structured_phases()
+    # the launch's own executable serves it: nothing compiles, nothing
+    # is loaded from the persistent cache (JAX times both as one event)
+    from jax import monitoring
+
+    compiles = []
+
+    def on(event, secs, **kw):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(secs)
+
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        phase_of = ex.structured_phases()
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    assert compiles == []
     assert set(phase_of.values()) <= set(tv.PHASES)
     assert {tv.PHASE_ASSEMBLE, tv.PHASE_SHA512, tv.PHASE_MSM} <= set(
         phase_of.values())
+
+
+def test_structured_launches_are_keyed_by_their_names_alone():
+    """Under what a structured launch is lowered with, a module holds
+    the operations' names (scope and primitive) and no file or line:
+    the cache key that includes them survives a line shift, and a
+    loaded executable carries the names structured_phases() reads."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    def scoped(x):
+        with jax.named_scope(tv.PHASE_MSM):
+            return x * 2
+
+    x = jnp.ones(4)
+    with ex._phase_names_in_key():
+        text = jax.jit(scoped).lower(x).as_text(debug_info=True)
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    assert f'loc("jit(scoped)/{tv.PHASE_MSM}/mul")' in text
+    assert ".py" not in text
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+    assert ".py" in jax.jit(lambda v: scoped(v)).lower(x).as_text(
+        debug_info=True)
 
 
 @pytest.mark.slow
@@ -256,3 +296,161 @@ def test_vote_batch_structured_verdicts(monkeypatch):
     sb = VoteSignBatch(CHAIN, votes)
     all_ok, verdicts = vals._batch_verify_lanes(lanes, sb, sigs)
     assert list(verdicts) == expect and not all_ok
+
+
+# ---------------------------------------------- the assembly alone
+
+
+_SECS = 1_753_928_000
+_NANOS = (1, 300, 70_000, 9_000_000, 999_999_999)   # varints of 1-5 B
+_ALL_TS = ((0, 10**9, _SECS * 10**9) + _NANOS
+           + tuple(_SECS * 10**9 + v for v in _NANOS))
+
+
+def _sign_batch(chain, ts, n=40, nil_every=0, height=977, total=2):
+    """A CommitSignBatch of n unsigned slots cycling through `ts`
+    (the assembly never reads a signature)."""
+    bid = BlockID(hash=bytes(range(32)),
+                  part_set_header=PartSetHeader(total, bytes(32)))
+    css = [CommitSig(
+        BlockIDFlag.NIL if nil_every and i % nil_every == 1
+        else BlockIDFlag.COMMIT,
+        bytes([i % 256] * 20), ts[i % len(ts)], b"") for i in range(n)]
+    commit = Commit(height=height, round=1, block_id=bid, signatures=css)
+    return CommitSignBatch(chain, commit, list(range(n)))
+
+
+def _merged_32_groups():
+    from tendermint_tpu.types.sign_batch import MergedSignBatch
+
+    return MergedSignBatch([
+        _sign_batch(CHAIN, _ALL_TS[b % 5:], n=8, nil_every=2,
+                    height=100 + b) for b in range(16)])
+
+
+# id -> (batch, the outer-varint lengths, groups and width it must
+# show, how it is run): every layout class _build_patches can emit
+_ASSEMBLE_CASES = {
+    "ts0-varint1": (lambda: _sign_batch("c", (0,)), {1}, 1, 192, ""),
+    "ts0-varint2": (lambda: _sign_batch("c" * 50, (0,)), {2}, 1, 192, ""),
+    "secs-only-varint1": (
+        lambda: _sign_batch("c", (10**9, _SECS * 10**9)), {1}, 1, 192, ""),
+    "secs-only-varint2": (
+        lambda: _sign_batch("c" * 50, (10**9, _SECS * 10**9)),
+        {2}, 1, 192, ""),
+    **{f"nanos-{k + 1}B": (
+        lambda v=v: _sign_batch("c", (v,)), {1}, 1, 192, "")
+       for k, v in enumerate(_NANOS)},
+    "secs+nanos-varint2": (
+        lambda: _sign_batch("c" * 50, _ALL_TS[-5:]), {2}, 1, 192, ""),
+    "mixed-varint": (
+        lambda: _sign_batch("c" * 24, _ALL_TS), {1, 2}, 1, 192, ""),
+    "nil-second-group": (
+        lambda: _sign_batch("c" * 24, _ALL_TS, nil_every=3),
+        {1, 2}, 2, 192, ""),
+    "merged-32-groups": (_merged_32_groups, {1}, 32, 192, ""),
+    "width-448": (
+        lambda: _sign_batch("c" * 62, _ALL_TS, total=1 << 31),
+        {2}, 1, 448, ""),
+    "short-at-width-448": (
+        lambda: _sign_batch("c", _ALL_TS, nil_every=3), {1}, 2, 192,
+        "wide"),
+    "padded-tail": (
+        lambda: _sign_batch("c" * 24, _ALL_TS, n=130, nil_every=7),
+        {1, 2}, 2, 192, ""),
+    "vmap": (
+        lambda: _sign_batch("c" * 24, _ALL_TS, n=130, nil_every=7),
+        {1, 2}, 2, 192, "vmap"),
+}
+
+
+def _tableless_keys(n_keys: int, tables=None):
+    """An ExpandedKeys of n_keys pubkeys on one device with no tables
+    built: the host side (_prepare_structured, _structured_avals)."""
+    e = object.__new__(ex.ExpandedKeys)
+    e.pubkeys, e.sharded, e.mesh = (bytes(32),) * n_keys, False, None
+    e.akeys = np.zeros((n_keys, 32), np.uint8)
+    e.key_ok = np.ones(n_keys, bool)
+    e.tables = tables
+    return e
+
+
+def _sha_padded(msg: bytes, width: int):
+    """What follows R || A in the hash's input, padded, in numpy's
+    terms: msg, 0x80, zeros, the 16-byte big-endian bit length at the
+    end of the last block; and the number of 128-byte blocks."""
+    total = 64 + len(msg)
+    nblocks = (total + 17 + 127) // 128
+    buf = np.zeros(width, np.uint8)
+    buf[:len(msg)] = np.frombuffer(msg, np.uint8)
+    buf[len(msg)] = 0x80
+    end = nblocks * 128 - 64
+    buf[end - 16:end] = np.frombuffer(
+        (total * 8).to_bytes(16, "big"), np.uint8)
+    return buf.tobytes(), nblocks
+
+
+@pytest.mark.parametrize("case", list(_ASSEMBLE_CASES))
+def test_assembled_bytes_equal_host_assemble(case):
+    """assemble_core() alone (no curve work): every real lane's words
+    are the bytes of host_assemble(i) with the SHA-512 pad, and its
+    block count, for each layout class."""
+    import jax
+
+    build, splits, groups, width, how = _ASSEMBLE_CASES[case]
+    sb = build()
+    n = len(sb)
+    _idx, f, _wf, got_width = _tableless_keys(1)._prepare_structured(
+        [0] * n, sb, [bytes(64)] * n)
+    assert set(sb.split.tolist()) == splits
+    assert sb.pre.shape[0] == groups and got_width == width
+    assert f["patch"].shape[0] == ex.ExpandedKeys._bucket(n) >= n
+    if how == "wide":
+        width = 448
+    templates = (f["pre"], f["pre_len"], f["suf"], f["suf_len"])
+    lanes = (f["patch"], f["split"], f["patch_len"], f["group"])
+    assemble = ex.assemble_core()
+    if how == "vmap":   # the sharded form: lanes split over devices
+        lanes = tuple(a.reshape((4, -1) + a.shape[1:]) for a in lanes)
+        words, nblocks = jax.jit(jax.vmap(
+            lambda *per: assemble(*templates, *per, width)))(*lanes)
+        words = np.concatenate(list(np.asarray(words)), axis=2)
+        nblocks = np.asarray(nblocks).reshape(-1)
+    else:
+        words, nblocks = jax.jit(assemble, static_argnums=8)(
+            *templates, *lanes, width)
+    assert words.shape == (width // 8, 2, f["patch"].shape[0])
+    got = np.asarray(words).transpose(2, 0, 1).astype(">u4")
+    full = sb.materialize()
+    for i in range(n):
+        assert sb.host_assemble(i) == full[i]
+        want, want_blocks = _sha_padded(full[i], width)
+        assert got[i].tobytes() == want, f"lane {i}"
+        assert int(nblocks[i]) == want_blocks, f"lane {i}"
+
+
+def test_no_per_element_gather_in_the_assembly():
+    """The program pin: `_skernel` as lowered holds ONE gather of more
+    indices than there are lanes, the comb-table rows (69 a lane).
+    Row gathers by `idx` or `group` take a lane's worth; an (N, width)
+    index plane under ed25519.assemble, which cost 60 % of the
+    10,240-lane kernel, cannot come back unseen. (Over the whole
+    program, not the scope alone: a gather lowered inside a shared
+    private function, as jnp.take's is, has no scope in its location.)"""
+    import re
+
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    keys, lanes = 24, 128
+    e = _tableless_keys(keys, np.zeros(
+        (keys * ex._WINDOWS * ex._ENTRIES, ex._ROW), np.int32))
+    text = ex._skernel().lower(
+        width=192, **e._structured_avals(lanes)).as_text(debug_info=True)
+    assert f"/{tv.PHASE_ASSEMBLE}/" in text
+    index_dims = re.findall(
+        r'"stablehlo\.gather".*?: \(tensor<[^>]*>, tensor<([\dx]+)xi32>\)',
+        text)
+    counts = [int(np.prod([int(d) for d in dims.split("x")]))
+              for dims in index_dims]
+    assert lanes in counts                      # akeys[idx], key_ok[idx]
+    assert sorted(c for c in counts if c > lanes) == [ex._WINDOWS * lanes]
