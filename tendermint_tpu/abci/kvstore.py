@@ -6,6 +6,11 @@ tx count (matching the reference's size-as-apphash trick).
 PersistentKVStoreApp adds durable state, height tracking for crash
 replay (the Handshaker relies on Info.last_block_height), validator
 updates via "val:<pubkey-hex>!<power>" txs, and statesync snapshots.
+
+Every app writes its db through a BlockOverlay: a block's writes are
+staged in memory, visible to every read, and land in ONE write_batch
+at Commit — ABCI's durability point — so the db holds whole blocks
+only and a block costs one durable commit, not one a tx.
 """
 
 from __future__ import annotations
@@ -25,24 +30,80 @@ def encode_validator_tx(pub_key_hex: str, power: int) -> bytes:
     return VALIDATOR_TX_PREFIX + f"{pub_key_hex}!{power}".encode()
 
 
+class BlockOverlay(DB):
+    """The app's view of its db while a block is in flight: set and
+    delete stage in memory, get and iterate read the staged writes
+    over `base`, and write_batch lands its ops TOGETHER WITH everything
+    staged in one atomic batch of `base`. Nothing reaches `base`
+    before that, so a block that never commits leaves no trace there;
+    `discard` forgets it."""
+
+    def __init__(self, base: DB):
+        self.base = base
+        self._staged: dict[bytes, bytes | None] = {}
+
+    def get(self, key: bytes) -> bytes | None:
+        if key in self._staged:
+            return self._staged[key]
+        return self.base.get(key)
+
+    def set(self, key: bytes, value: bytes) -> None:
+        self._staged[key] = value
+
+    def delete(self, key: bytes) -> None:
+        self._staged[key] = None
+
+    def write_batch(self, ops) -> None:
+        self._staged.update(ops)
+        # cleared only once the batch is in: a failed write leaves the
+        # block staged, for the replay's BeginBlock to discard
+        self.base.write_batch(list(self._staged.items()))
+        self._staged.clear()
+
+    def discard(self) -> None:
+        self._staged.clear()
+
+    def iterate(self, start: bytes = b"", end: bytes | None = None):
+        staged = sorted(
+            (k, v) for k, v in self._staged.items()
+            if k >= start and (end is None or k < end))
+        i = 0
+        for k, v in self.base.iterate(start, end):
+            while i < len(staged) and staged[i][0] < k:
+                if staged[i][1] is not None:
+                    yield staged[i]
+                i += 1
+            if i < len(staged) and staged[i][0] == k:
+                k, v = staged[i]
+                i += 1
+                if v is None:
+                    continue
+            yield k, v
+        for k, v in staged[i:]:
+            if v is not None:
+                yield k, v
+
+    def close(self) -> None:
+        self.base.close()
+
+
 class KVStoreApp(t.Application):
-    """DeliverTx applies immediately (reference kvstore.go behavior —
-    queries see uncommitted writes, as the abci-cli goldens capture)
-    but every write is journaled, and BeginBlock ROLLS BACK any
-    journal left by a block that never reached Commit. This makes
-    block replay idempotent: if a node dies mid-block while its
-    external app process lives on (observed: a graceful restart
-    interrupting delivery — randomized campaign seed 131), the
-    handshake's BeginBlock for the same height undoes the
-    half-applied writes instead of double-applying them — the
+    """DeliverTx stages its write for the block (queries see it at
+    once: reference kvstore.go behavior, as the abci-cli goldens
+    capture), Commit lands the block in one batch, and BeginBlock
+    DROPS whatever a block that never reached Commit left staged.
+    This makes block replay idempotent: if a node dies mid-block
+    while its external app process lives on (observed: a graceful
+    restart interrupting delivery — randomized campaign seed 131),
+    the handshake's BeginBlock for the same height forgets the
+    half-delivered block instead of double-applying it — the
     deliverState-reset semantics production ABCI apps implement."""
 
-    def __init__(self):
-        self.db: DB = MemDB()
+    def __init__(self, db: DB | None = None):
+        self.db = BlockOverlay(db or MemDB())
         self.size = 0
         self.height = 0
         self.app_hash = b""
-        self._undo: list[tuple[bytes, bytes | None]] = []
         self._committed_size = 0
 
     def info(self, req: t.RequestInfo) -> t.ResponseInfo:
@@ -57,28 +118,17 @@ class KVStoreApp(t.Application):
     def check_tx(self, req: t.RequestCheckTx) -> t.ResponseCheckTx:
         return t.ResponseCheckTx(code=t.CODE_TYPE_OK, gas_wanted=1)
 
-    def _rollback_partial(self) -> None:
-        if not self._undo:
-            return
-        for k, old in reversed(self._undo):
-            if old is None:
-                self.db.delete(k)
-            else:
-                self.db.set(k, old)
-        self._undo.clear()
-        self.size = self._committed_size
-
     def begin_block(self, req: t.RequestBeginBlock) -> t.ResponseBeginBlock:
-        self._rollback_partial()
+        # a block that never reached Commit: nothing of it is in the db
+        self.db.discard()
+        self.size = self._committed_size
         return t.ResponseBeginBlock()
 
     def deliver_tx(self, req: t.RequestDeliverTx) -> t.ResponseDeliverTx:
         key, sep, value = req.tx.partition(b"=")
         if not sep:
             key = value = req.tx
-        k = b"kv:" + key
-        self._undo.append((k, self.db.get(k)))
-        self.db.set(k, value)
+        self.db.set(b"kv:" + key, value)
         self.size += 1
         return t.ResponseDeliverTx(
             code=t.CODE_TYPE_OK,
@@ -92,17 +142,15 @@ class KVStoreApp(t.Application):
         )
 
     def _mark_committed(self) -> None:
-        """Seal the journal: current state is now the rollback point.
-        Called at Commit AND after a statesync restore (a stale
-        journal replayed into freshly restored state would corrupt
-        it)."""
-        self._undo.clear()
+        """Current state is now what BeginBlock falls back to: called
+        at Commit AND at a statesync restore."""
         self._committed_size = self.size
 
     def commit(self, req: t.RequestCommit) -> t.ResponseCommit:
-        self._mark_committed()
         self.app_hash = struct.pack(">Q", self.size)
         self.height += 1
+        self.db.write_batch(())
+        self._mark_committed()
         return t.ResponseCommit(data=self.app_hash)
 
     def query(self, req: t.RequestQuery) -> t.ResponseQuery:
@@ -122,8 +170,7 @@ class PersistentKVStoreApp(KVStoreApp):
 
     def __init__(self, db: DB | None = None, snapshot_interval: int = 0,
                  keep_snapshots: int = 4):
-        super().__init__()
-        self.db = db or MemDB()
+        super().__init__(db)
         self.val_updates: list[t.ValidatorUpdate] = []
         self._undo_vals: list[tuple[str, int | None]] = []
         self.validators: dict[str, int] = {}  # pubkey hex -> power
@@ -148,7 +195,7 @@ class PersistentKVStoreApp(KVStoreApp):
         return t.ResponseInitChain()
 
     def begin_block(self, req: t.RequestBeginBlock) -> t.ResponseBeginBlock:
-        super().begin_block(req)  # roll back any half-applied kv block
+        super().begin_block(req)  # drop any half-delivered kv block
         for hx, old in reversed(self._undo_vals):
             if old is None:
                 self.validators.pop(hx, None)
@@ -176,8 +223,8 @@ class PersistentKVStoreApp(KVStoreApp):
                 code=1, log=f"invalid validator tx {tx!r}"
             )
         vu = t.ValidatorUpdate("ed25519", pub_key, power)
-        # journaled like the kv writes: a replayed half-block rolls
-        # the set back before re-applying
+        # journaled: a replayed half-block rolls the set back before
+        # re-applying
         self._undo_vals.append(
             (pub_key.hex(), self.validators.get(pub_key.hex())))
         self._update_validator(vu)
@@ -201,16 +248,19 @@ class PersistentKVStoreApp(KVStoreApp):
         super()._mark_committed()
         self._undo_vals.clear()
 
-    def commit(self, req: t.RequestCommit) -> t.ResponseCommit:
-        self._mark_committed()
-        self.app_hash = self._compute_app_hash()
-        self.height += 1
-        self.db.set(_STATE_KEY, json.dumps({
+    def _state_record(self) -> bytes:
+        return json.dumps({
             "size": self.size,
             "height": self.height,
             "app_hash": self.app_hash.hex(),
             "validators": self.validators,
-        }).encode())
+        }).encode()
+
+    def commit(self, req: t.RequestCommit) -> t.ResponseCommit:
+        # hash and snapshot read the db THROUGH the overlay, so they
+        # cover this block's writes before those are on disk
+        self.app_hash = self._compute_app_hash()
+        self.height += 1
         if self.snapshot_interval and \
                 self.height % self.snapshot_interval == 0:
             self.db.set(b"snap:%016x" % self.height,
@@ -218,6 +268,10 @@ class PersistentKVStoreApp(KVStoreApp):
             snaps = [k for k, _ in self.db.iterate_prefix(b"snap:")]
             for k in snaps[:-self.keep_snapshots]:
                 self.db.delete(k)
+        # one durable commit a block: its keys, a snapshot if this
+        # height takes one, and the height/size that count them
+        self.db.write_batch([(_STATE_KEY, self._state_record())])
+        self._mark_committed()
         resp = t.ResponseCommit(data=self.app_hash)
         if self.retain_blocks > 0 and self.height > self.retain_blocks:
             resp.retain_height = self.height - self.retain_blocks
@@ -313,14 +367,12 @@ class PersistentKVStoreApp(KVStoreApp):
         self.height = d["height"]
         self.app_hash = bytes.fromhex(d["app_hash"])
         self.validators = d["validators"]
-        # restored state is the new rollback point; a stale journal
-        # from a block interrupted before the restore must never
-        # replay into it
+        # restored state is the new fall-back point; a block left
+        # staged by a delivery interrupted before the restore must
+        # never land with it
+        self.db.discard()
         self._mark_committed()
-        ops.append((_STATE_KEY, json.dumps({
-            "size": self.size, "height": self.height,
-            "app_hash": self.app_hash.hex(), "validators": self.validators,
-        }).encode()))
+        ops.append((_STATE_KEY, self._state_record()))
         self.db.write_batch(ops)
         return t.ResponseApplySnapshotChunk(t.ApplySnapshotChunkResult.ACCEPT)
 
@@ -339,7 +391,7 @@ class MerkleKVStoreApp(PersistentKVStoreApp):
         super().__init__(*args, **kwargs)
         # Snapshot at construction: nothing is mid-block yet, so the
         # db IS the committed state (a lazy first-query rebuild could
-        # race a half-applied block and cache an unprovable tree).
+        # race a half-delivered block and cache an unprovable tree).
         self._snapshot_committed()
 
     def _sorted_pairs(self) -> list[tuple[bytes, bytes]]:
@@ -349,9 +401,10 @@ class MerkleKVStoreApp(PersistentKVStoreApp):
 
     def _snapshot_committed(self) -> bytes:
         """Queries must prove against the last COMMITTED state —
-        deliver_tx writes the live db mid-block, and a proof over
-        half-applied state matches no header's app_hash. The proof
-        tree is built once here, not per query."""
+        the db shows a block's staged writes mid-block, and a proof
+        over a half-delivered block matches no header's app_hash. The
+        proof tree is built once here (at Commit: over the overlay,
+        before the batch lands), not per query."""
         from . import kv_proofs
 
         self._committed_pairs = self._sorted_pairs()

@@ -150,9 +150,9 @@ class SqliteDB(DB):
 
     # Every durable commit — an autocommit set/delete, the COMMIT of
     # a batch — is one db.write span (under synchronous=FULL, one
-    # fsync of the sqlite WAL). Callers that commit once a tx (the
-    # kvstore app's DeliverTx, the tx indexer) make runs of them, which
-    # TRACER.leaf folds: count = the sum of `n`, time = of `busy_ns`.
+    # fsync of the sqlite WAL). A caller that commits once a tx makes
+    # runs of them, which TRACER.leaf folds: count = the sum of `n`,
+    # time = of `busy_ns`.
 
     def set(self, key: bytes, value: bytes) -> None:
         from . import failpoints

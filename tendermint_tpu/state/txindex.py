@@ -1,9 +1,10 @@
 """Transaction indexer (reference: state/txindex/).
 
-IndexerService subscribes to the EventBus Tx stream and writes each
-TxResult into a kv index: primary record by tx hash, secondary keys
-for height and for every ABCI event attribute (`type.key=value`), so
-`tx_search` can answer the same query language the pubsub uses."""
+IndexerService subscribes to the EventBus Tx stream and writes a
+block's TxResults into a kv index in one batch: primary record by tx
+hash, secondary keys for height and for every ABCI event attribute
+(`type.key=value`), so `tx_search` can answer the same query language
+the pubsub uses."""
 
 from __future__ import annotations
 
@@ -40,22 +41,30 @@ class TxIndexer:
         self.db = db
 
     def index(self, tr: TxResult) -> None:
-        h = tr.hash()
-        payload = json.dumps({
-            "height": tr.height, "index": tr.index,
-            "tx": tr.tx.hex(), "result": tr.result,
-        }).encode()
-        ops = [(_PRIMARY + h, payload),
-               (_BY_HEIGHT + _u64(tr.height) + _u32(tr.index) + h, b"")]
-        for ev in tr.result.get("events", []):
-            etype = ev.get("type", "")
-            for attr in ev.get("attributes", []):
-                k, v = attr.get("key", ""), attr.get("value", "")
-                if not etype or not k:
-                    continue
-                composite = f"{etype}.{k}={v}".encode()
-                ops.append((_BY_EVENT + composite + b"/" +
-                            _u64(tr.height) + _u32(tr.index) + h, b""))
+        self.index_batch([tr])
+
+    def index_batch(self, results: list[TxResult]) -> None:
+        """One atomic write_batch for all of `results` (reference
+        kv.go AddBatch: a block's txs, one synced batch)."""
+        ops = []
+        for tr in results:
+            h = tr.hash()
+            payload = json.dumps({
+                "height": tr.height, "index": tr.index,
+                "tx": tr.tx.hex(), "result": tr.result,
+            }).encode()
+            ops.append((_PRIMARY + h, payload))
+            ops.append(
+                (_BY_HEIGHT + _u64(tr.height) + _u32(tr.index) + h, b""))
+            for ev in tr.result.get("events", []):
+                etype = ev.get("type", "")
+                for attr in ev.get("attributes", []):
+                    k, v = attr.get("key", ""), attr.get("value", "")
+                    if not etype or not k:
+                        continue
+                    composite = f"{etype}.{k}={v}".encode()
+                    ops.append((_BY_EVENT + composite + b"/" +
+                                _u64(tr.height) + _u32(tr.index) + h, b""))
         self.db.write_batch(ops)
 
     def get(self, tx_hash: bytes) -> TxResult | None:
@@ -292,17 +301,26 @@ class IndexerService:
 
         while True:
             try:
-                msg = await self._sub.next()
+                msgs = [await self._sub.next()]
             except asyncio.CancelledError:
                 return
-            data = msg.data
-            if isinstance(data, EventDataTx):
-                try:
-                    self.indexer.index(TxResult(data.height, data.index,
-                                                data.tx, data.result))
-                except Exception:
-                    logger.exception("failed to index tx at height %d",
-                                     data.height)
+            # a block's Tx events are published in one synchronous run
+            # (BlockExecutor._fire_events), so by the time this task
+            # wakes they are all queued: drain them into one batch
+            while not self._sub.queue.empty():
+                msgs.append(self._sub.queue.get_nowait())
+            results = [
+                TxResult(m.data.height, m.data.index, m.data.tx,
+                         m.data.result)
+                for m in msgs if isinstance(m.data, EventDataTx)]
+            if not results:
+                continue
+            try:
+                self.indexer.index_batch(results)
+            except Exception:
+                logger.exception(
+                    "failed to index %d txs at heights %d-%d",
+                    len(results), results[0].height, results[-1].height)
 
     async def _run_blocks(self) -> None:
         import asyncio

@@ -287,3 +287,159 @@ def test_statesync_restore_clears_stale_journal():
     assert dst.db.get(b"kv:x") == b"1"
     res = dst.commit(t.RequestCommit())
     assert res.data == src.app_hash
+
+
+def _deliver_block(app, txs):
+    app.begin_block(t.RequestBeginBlock())
+    for tx in txs:
+        app.deliver_tx(t.RequestDeliverTx(tx))
+    app.end_block(t.RequestEndBlock(app.height + 1))
+
+
+def test_block_is_one_durable_commit(tmp_path):
+    """N DeliverTx + Commit on a SqliteDB: nothing is written before
+    Commit, and Commit is ONE durable commit (a `db.write`) of the N
+    keys and the app's state record — also on a height that takes and
+    prunes a snapshot."""
+    from tendermint_tpu.libs.db import SqliteDB
+    from tendermint_tpu.libs.tracing import DB_WRITE, TRACER
+
+    def commits():
+        return [r for r in TRACER.snapshot() if r[0] == DB_WRITE]
+
+    db = SqliteDB(str(tmp_path / "app.sqlite"))
+    app = PersistentKVStoreApp(db, snapshot_interval=2, keep_snapshots=1)
+    was, TRACER.enabled = TRACER.enabled, True
+    try:
+        for h in range(1, 5):
+            TRACER.clear()
+            _deliver_block(app, [b"k%d-%d=v" % (h, i) for i in range(50)])
+            assert not commits() and db.get(b"kv:k%d-0" % h) is None
+            app.commit(t.RequestCommit())
+            (w,) = commits()
+            # 50 keys + the state record; a snapshot height adds its
+            # snapshot, and from the second one the pruned one's delete
+            assert w[6].get("n", 1) == 1
+            assert w[6]["ops"] == 51 + (h % 2 == 0) + (h == 4)
+            assert db.get(b"kv:k%d-49" % h) == b"v"
+    finally:
+        TRACER.enabled = was
+        TRACER.clear()
+    assert [k for k, _ in db.iterate_prefix(b"snap:")] == \
+        [b"snap:%016x" % 4]
+    db.close()
+
+
+def test_crash_before_commit_leaves_whole_blocks_only(tmp_path):
+    """A process that dies between DeliverTx and Commit: the reopened
+    db holds none of that block's keys under the old state record, and
+    replaying the block gives the app hash and size of a clean run."""
+    from tendermint_tpu.abci.kvstore import _STATE_KEY
+    from tendermint_tpu.libs.db import SqliteDB
+
+    block1 = [b"a=1", b"b=2"]
+    block2 = [b"a=9", b"c=3", encode_validator_tx("11" * 32, 5)]
+
+    clean = PersistentKVStoreApp()
+    for txs in (block1, block2):
+        _deliver_block(clean, txs)
+        clean.commit(t.RequestCommit())
+
+    path = str(tmp_path / "app.sqlite")
+    app = PersistentKVStoreApp(SqliteDB(path))
+    _deliver_block(app, block1)
+    app.commit(t.RequestCommit())
+    state1 = app.db.get(_STATE_KEY)
+    _deliver_block(app, block2)          # ... and the process dies
+    app.db.close()
+
+    db = SqliteDB(path)
+    assert db.get(b"kv:c") is None and db.get(b"kv:a") == b"1"
+    assert db.get(_STATE_KEY) == state1
+    app = PersistentKVStoreApp(db)
+    assert (app.height, app.size) == (1, 2)
+    assert "11" * 32 not in app.validators
+    # the handshake replays block 2 from BeginBlock
+    _deliver_block(app, block2)
+    res = app.commit(t.RequestCommit())
+    assert res.data == clean.app_hash
+    assert (app.height, app.size) == (clean.height, clean.size) == (2, 4)
+    assert app.validators == clean.validators
+    assert db.get(b"kv:a") == b"9" and db.get(b"kv:c") == b"3"
+    db.close()
+
+
+def test_query_sees_the_staged_block():
+    """Reads of the live state go through the overlay: a query
+    mid-block answers with the staged value (the abci-cli goldens'
+    behaviour), a dropped block's value is gone again."""
+    app = PersistentKVStoreApp()
+    _deliver_block(app, [b"k=old"])
+    app.commit(t.RequestCommit())
+    _deliver_block(app, [b"k=new", b"fresh=1"])
+    assert app.query(t.RequestQuery(data=b"k")).value == b"new"
+    assert app.query(t.RequestQuery(data=b"fresh")).log == "exists"
+    assert app.db.base.get(b"kv:k") == b"old"
+    assert dict(app.db.iterate_prefix(b"kv:")) == \
+        {b"kv:fresh": b"1", b"kv:k": b"new"}
+    app.begin_block(t.RequestBeginBlock())      # the block never commits
+    assert app.query(t.RequestQuery(data=b"k")).value == b"old"
+    assert app.query(t.RequestQuery(data=b"fresh")).log == "does not exist"
+    assert app.size == 1
+
+
+def test_block_overlay_iterates_like_the_landed_db():
+    """The overlay's merged view (staged sets, overwrites and deletes
+    over the base, in range) equals the base's own after landing."""
+    from tendermint_tpu.abci.kvstore import BlockOverlay
+
+    base = MemDB()
+    for k in (b"a", b"c", b"e", b"g"):
+        base.set(k, b"base-" + k)
+    ov = BlockOverlay(base)
+    ov.set(b"0", b"first")
+    ov.set(b"c", b"over")
+    ov.set(b"d", b"between")
+    ov.delete(b"e")
+    ov.delete(b"never-there")
+    ov.set(b"z", b"last")
+    ranges = [(b"", None), (b"b", b"f"), (b"c", b"d"), (b"h", None)]
+    before = [list(ov.iterate(lo, hi)) for lo, hi in ranges]
+    assert before[0] == [(b"0", b"first"), (b"a", b"base-a"),
+                         (b"c", b"over"), (b"d", b"between"),
+                         (b"g", b"base-g"), (b"z", b"last")]
+    assert ov.get(b"e") is None and not ov.has(b"e") and base.has(b"e")
+    ov.write_batch([(b"y", b"with-the-batch")])
+    assert base.get(b"y") == b"with-the-batch" and base.get(b"e") is None
+    assert [[kv for kv in base.iterate(lo, hi) if kv[0] != b"y"]
+            for lo, hi in ranges] == before
+
+
+def test_merkle_app_hash_and_proof_cover_the_staged_block():
+    """MerkleKVStoreApp hashes and proves at Commit THROUGH the
+    overlay: root and value proof equal those computed straight from
+    the pairs, as the unstaged app computed them from its db."""
+    from tendermint_tpu.abci import kv_proofs
+    from tendermint_tpu.abci.kvstore import MerkleKVStoreApp
+    from tendermint_tpu.crypto import merkle
+
+    app = MerkleKVStoreApp(MemDB())
+    pairs = {}
+    for txs in ([b"m=1", b"a=2"], [b"m=3", b"z=4", b"b=5"]):
+        _deliver_block(app, txs)
+        # a proof mid-block still answers from the last commit
+        stale = app.query(t.RequestQuery(data=b"m", prove=True))
+        assert stale.value == pairs.get(b"m", b"")
+        res = app.commit(t.RequestCommit())
+        pairs.update(tx.split(b"=") for tx in txs)
+        want = sorted(pairs.items())
+        root, proofs = merkle.proofs_from_byte_slices(
+            [kv_proofs.kv_leaf(k, v) for k, v in want])
+        assert res.data == app.app_hash == root
+        resp = app.query(t.RequestQuery(data=b"m", prove=True))
+        assert resp.value == pairs[b"m"]
+        assert resp.proof_ops == [kv_proofs.KVValueOp.encode(
+            b"m", len(want), proofs[[k for k, _ in want].index(b"m")])]
+        assert kv_proofs.kv_proof_runtime().verify_value(
+            [merkle.ProofOp(o["type"], o["key"], o["data"])
+             for o in resp.proof_ops], root, [b"m"], pairs[b"m"])

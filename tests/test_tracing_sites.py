@@ -408,23 +408,25 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
         assert kids[1][6] == {"txs": 3}
         (saved,) = [r for r in recs if r[0] == tracing.STORE_SAVE_BLOCK]
         assert saved[4] + saved[5] <= apply_[4]
-        # as many db.write as durable commits: one a tx in DeliverTx
-        # (a run, folded), one for each of the store's batches
+        # as many db.write as durable commits: one for each of the
+        # stores' batches and ONE for the app's block, at Commit
         writes = [r for r in recs if r[0] == tracing.DB_WRITE]
-        assert sum(w[6].get("n", 1) for w in writes) == durable >= 7
+        assert sum(w[6].get("n", 1) for w in writes) == durable >= 4
         by_parent = {}
         by_id = {r[1]: r[0] for r in recs}
         for w in writes:
             kind = by_id.get(w[2])
             by_parent[kind] = by_parent.get(kind, 0) + w[6].get("n", 1)
-        assert by_parent[tracing.STATE_EXEC] == 3
+        # DeliverTx stages: nothing is written inside state.exec
+        assert tracing.STATE_EXEC not in by_parent
         assert by_parent[tracing.STORE_SAVE_BLOCK] == 1
         assert by_parent[tracing.STATE_SAVE_RESPONSES] == 1
         assert by_parent[tracing.STATE_APP_COMMIT] == 1
         assert by_parent[tracing.STATE_SAVE] >= 1
-        # the three DeliverTx commits are ONE ring entry
-        assert len([w for w in writes
-                    if by_id.get(w[2]) == tracing.STATE_EXEC]) == 1
+        # the app's commit carries the block: 3 keys + its state record
+        (app_write,) = [w for w in writes
+                        if by_id.get(w[2]) == tracing.STATE_APP_COMMIT]
+        assert app_write[6]["ops"] == 4
 
 
 # --------------------------------------------------------------- admission
