@@ -637,3 +637,126 @@ class BatchVerifier:
                 bool,
                 count=len(items),
             )
+
+
+# -- the guarded general-kernel launch of the verify planes -----------
+#
+# The mempool admission plane and the light serving plane each cut
+# batches of raw ed25519 triples that mix keys of any origin, so they
+# launch the general kernel (per-lane keys), not one validator set's
+# expanded tables. How such a launch is made safely is decided here,
+# once. It is deliberately NOT BatchVerifier._verify_group's ed25519
+# branch: the planes' policy differs (known-answer sentinel lane,
+# host_recheck on a suspect verdict, no _FORCE_HOST pin, no use_device
+# override), but the crypto/tpu device-health counters are the shared
+# ones, so dashboards and the docs/CHAOS.md triage flow see the
+# planes' launches next to consensus ones. Invalid lanes stay OUT of
+# crypto_invalid_sigs on purpose: a garbage flood at admission is
+# expected bulk (admission_shed_total{bad_signature}) and must not
+# fire consensus invalid-signature alarms.
+
+
+def note_plane_launch(launches, backend: str) -> None:
+    """What a plane records of a launch that answered `backend`: its
+    own `launches{backend}` counter — a device launch whose sentinel
+    failed LANDED before its host re-check, so it counts as both — and
+    the answer on the batch's flush span (the current one: the
+    collector hands it to the worker thread)."""
+    if backend == "host_recheck":
+        launches.inc(backend="device")
+    launches.inc(backend=backend)
+    (tracing.TRACER.current() or tracing.NOOP_SPAN).set_attr(
+        "backend", backend)
+
+
+def _host_verify_ed25519(pubs, msgs, sigs) -> np.ndarray:
+    """Per-key host verify of raw triples. A lane never raises: a
+    wrong-length key (Ed25519PubKey refuses it), a wrong-length
+    signature (tested here, and again by verify_signature) or any
+    other exception reads False."""
+    from .ed25519 import Ed25519PubKey
+
+    out = np.zeros(len(pubs), bool)
+    for i, (p, m, s) in enumerate(zip(pubs, msgs, sigs)):
+        try:
+            out[i] = len(s) == 64 and \
+                Ed25519PubKey(p).verify_signature(m, s)
+        except Exception:
+            out[i] = False
+    return out
+
+
+def host_ed25519_launch(pubs, msgs, sigs) -> tuple[np.ndarray, str]:
+    """ONE host launch of raw ed25519 triples, counted as such — the
+    foot of the guarded ladder, and what a plane's own injected launch
+    failure degrades to. Returns (verdicts, "host")."""
+    from ..libs.metrics import crypto_metrics
+
+    crypto_metrics().batch_lanes.inc(len(pubs), backend="host")
+    return _host_verify_ed25519(pubs, msgs, sigs), "host"
+
+
+def guarded_ed25519_launch(pubs, msgs, sigs, workload: str,
+                           device_threshold: int
+                           ) -> tuple[np.ndarray, str]:
+    """Verify raw ed25519 triples in one launch: on the device when
+    the batch reaches `device_threshold` lanes and the breaker admits
+    it, on the host oracle otherwise. `workload` tags the launch in
+    the ledger (crypto/tpu/ledger.py). Returns (per-lane verdicts,
+    backend), backend one of "device", "host", "host_recheck"."""
+    from ..libs import failpoints
+    from ..libs.metrics import crypto_metrics, tpu_metrics
+
+    n = len(pubs)
+    want_dev = n >= device_threshold
+    if want_dev and breaker("ed25519").acquire():
+        try:
+            from .tpu import ledger as tpu_ledger
+            from .tpu import verify as tpu_verify
+
+            failpoints.hit("device.verify")
+            # device_launches counts ATTEMPTS (the BatchVerifier
+            # convention: a raising launch still burned a launch
+            # slot); the lane count, and the caller's own launch
+            # counter, land only after the launch returns, so a
+            # raising launch falls through as ONE host launch, never
+            # device+host for the same flush
+            crypto_metrics().device_launches.inc()
+            # one extra known-answer sentinel lane rides every batch,
+            # last (the breaker probe's triple): a NaN-ing kernel
+            # fails the sentinel, so a suspect verdict is detected
+            # POSITIVELY — an honest all-garbage batch (sentinel
+            # verifies, every real lane invalid) is trusted and dies
+            # at the device, never paying a per-signature host
+            # re-check
+            spub, smsg, ssig = _ed_probe_triple()
+            with tpu_ledger.workload(workload):
+                out = np.asarray(tpu_verify.verify_batch(
+                    [*pubs, spub], [*msgs, smsg], [*sigs, ssig]), bool)
+            crypto_metrics().batch_lanes.inc(
+                n, backend=_tpu_backend.platform())
+            if out[-1]:
+                return out[:n], "device"
+            # sentinel mismatch: wrong-verdict device (the shape the
+            # breaker's half-open probe exists for) — open the breaker
+            # and re-verify on host rather than answering from
+            # verdicts that may reject what is valid
+            mark_device_failed("ed25519")
+            logger.error(
+                "%s device batch (%d lanes) failed its known-answer "
+                "sentinel; breaker open %.1fs, re-verifying on host",
+                workload, n, breaker("ed25519").cooldown_remaining())
+            tpu_metrics().host_fallbacks.inc()
+            return _host_verify_ed25519(pubs, msgs, sigs), "host_recheck"
+        except Exception:
+            mark_device_failed("ed25519")
+            logger.exception(
+                "%s device batch failed (%d lanes); breaker open "
+                "%.1fs, degrading to host", workload, n,
+                breaker("ed25519").cooldown_remaining())
+    if want_dev:
+        # device wanted (threshold met) but breaker-refused or raised:
+        # same fallback signal as BatchVerifier._verify_group — never
+        # for a batch under the threshold
+        tpu_metrics().host_fallbacks.inc()
+    return host_ed25519_launch(pubs, msgs, sigs)

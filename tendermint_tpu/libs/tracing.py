@@ -39,6 +39,8 @@ links over the hot paths:
   admission.queue_wait                 first pending envelope -> the cut
   admission.flush                      the cut -> last verdict delivered
     crypto.verify ...                  (executor thread, via wrap)
+  light.queue_wait / light.flush       the same pair for the light
+                                       plane's commit-check batches
   p2p.send_flush / p2p.recv_msg        wire-side attribution
 
 Design constraints (this stays ON in production):
@@ -164,9 +166,12 @@ VERIFY_SIGN_BATCH = register_kind("verify.sign_batch")
 VERIFY_TABLES = register_kind("verify.tables")
 VERIFY_WINDOW = register_kind("verify.window")
 
-# Admission micro-batcher (mempool/admission.py), one pair per batch.
+# The verify planes' micro-batcher (crypto/collector.py), one pair per
+# batch: mempool/admission.py's and light/serving.py's.
 ADMISSION_QUEUE_WAIT = register_kind("admission.queue_wait")
 ADMISSION_FLUSH = register_kind("admission.flush")
+LIGHT_QUEUE_WAIT = register_kind("light.queue_wait")
+LIGHT_FLUSH = register_kind("light.flush")
 
 # State machine + durability + wire. The state.* children follow
 # BlockExecutor._apply_block in order.

@@ -15,15 +15,17 @@ RPC surface (one or many workers — ServingPool) and the light
     over the trusted ``LightStore`` makes the second client hitting a
     verified height cost a dict lookup, not a device launch;
 
-  * **batched skipping verify** — a micro-batching collector (the
-    ``mempool/admission.py`` flush-on-size-or-deadline shape) takes
-    ``types/validator_set.py`` CommitVerifyPlans from independent
-    requests AND from both checks of one bisection step (the trusted
-    -overlap check and the new set's own +2/3 check run concurrently)
-    and executes them as single wide ed25519 launches — breaker-aware
-    with host fallback, one known-answer sentinel lane per device
-    batch (a NaN-ing kernel fails the sentinel and the batch re-runs
-    on host instead of failing requests on wrong verdicts);
+  * **batched skipping verify** — a micro-batching collector
+    (crypto/collector.py's size-or-deadline flusher, weighing a plan
+    by its lanes) takes ``types/validator_set.py`` CommitVerifyPlans
+    from independent requests AND from both checks of one bisection
+    step (the trusted-overlap check and the new set's own +2/3 check
+    run concurrently) and executes them as single wide ed25519
+    launches through crypto/batch.py's one guarded launch —
+    breaker-aware with host fallback, one known-answer sentinel lane
+    per device batch (a NaN-ing kernel fails the sentinel and the
+    batch re-runs on host instead of failing requests on wrong
+    verdicts);
 
   * **bounded pending-verify backlog** — the collector's parked +
     in-verify commit checks are the ``light.pending_verify`` entry in
@@ -47,7 +49,8 @@ import time
 
 import numpy as np
 
-from ..libs.overload import CONTROLLER
+from ..crypto.collector import BacklogFull, BatchCollector
+from ..libs import tracing
 from ..types.validator_set import CommitVerifyPlan, VerificationError
 from .errors import (
     DivergenceError,
@@ -132,82 +135,40 @@ class VerifiedHeaderCache:
         self._d.clear()
 
 
-class _VerifyJob:
-    __slots__ = ("plan", "future")
-
-    def __init__(self, plan: CommitVerifyPlan, future: asyncio.Future):
-        self.plan = plan
-        self.future = future
-
-
-class LightVerifyCollector:
-    """Micro-batching commit-check collector (the admission-collector
-    shape, but the unit of work is a CommitVerifyPlan of several
-    signature lanes, not one tx).
-
-    ``check(plan)`` parks the plan and awaits its verdict; a single
-    flusher cuts batches once ``batch_max`` LANES have accumulated (or
-    ``flush_ms`` after the first pending plan) and runs every plan's
-    triples through ONE wide verify launch in an executor thread,
-    scattering per-lane verdicts back per plan. A plan with any
-    invalid lane gets the same VerificationError its inline execute()
-    would raise — one request's lying provider never poisons the
-    verdicts of the batchmates."""
+class LightVerifyCollector(BatchCollector):
+    """Micro-batching commit-check collector: the light face of
+    crypto/collector.py's BatchCollector. An item is a
+    CommitVerifyPlan, weighing its signature lanes — batches cut once
+    ``batch_max`` LANES have accumulated or ``flush_ms`` after the
+    first pending plan, and a plan wider than ``batch_max`` goes
+    alone. Every plan's triples run through ONE guarded general-kernel
+    launch (crypto/batch.py: cross-plan batches mix validator sets, so
+    per-lane keys are the right tool, not any one set's expanded
+    tables) and the per-lane verdicts scatter back per plan. A plan
+    with any invalid lane gets the same VerificationError its inline
+    execute() would raise — one request's lying provider never poisons
+    the verdicts of the batchmates."""
 
     def __init__(self, batch_max: int = 1024, flush_ms: float = 2.0,
                  pending_max: int = 1024,
                  device_threshold: int | None = None, controller=None):
         from ..crypto import batch as cbatch
 
-        self.batch_max = max(1, batch_max)
-        self.flush_ms = flush_ms
-        self.pending_max = max(1, pending_max)
         self.device_threshold = cbatch._DEVICE_THRESHOLD \
             if device_threshold is None else device_threshold
-        self._controller = controller or CONTROLLER
-        self._pending: collections.deque[_VerifyJob] = collections.deque()
-        self._pending_lane_count = 0
-        self._in_flight = 0
-        self._item_evt = asyncio.Event()
-        self._full_evt = asyncio.Event()
-        self._flusher: asyncio.Task | None = None
-        self._controller.register("light.pending_verify", self.depth,
-                                  lambda: self.pending_max, owner=self)
+        super().__init__(
+            queue="light.pending_verify", limit=pending_max,
+            batch_max=batch_max, flush_ms=flush_ms,
+            run_batch=lambda plans: self._verify_jobs(plans),
+            span_kinds=(tracing.LIGHT_QUEUE_WAIT, tracing.LIGHT_FLUSH),
+            controller=controller)
 
-    # -- sizes ---------------------------------------------------------
-
-    def depth(self) -> int:
-        """Backlog the bound applies to: parked + in-verify checks."""
-        return len(self._pending) + self._in_flight
+    @property
+    def pending_max(self) -> int:
+        return self.limit
 
     def pending_lanes(self) -> int:
-        # maintained incrementally: check() and the flusher consult
-        # this per enqueue/wakeup, and a scan of a deep backlog here
-        # would make admission quadratic exactly under load
-        return self._pending_lane_count
-
-    def saturated(self) -> bool:
-        return self.depth() >= self.pending_max
-
-    # -- lifecycle -----------------------------------------------------
-
-    def close(self) -> None:
-        if self._flusher is not None:
-            self._flusher.cancel()
-            self._flusher = None
-        for job in self._pending:
-            if not job.future.done():
-                job.future.cancel()
-        self._pending.clear()
-        self._pending_lane_count = 0
-        self._controller.unregister("light.pending_verify", owner=self)
-
-    def _ensure_flusher(self) -> None:
-        if self._flusher is None or self._flusher.done():
-            self._flusher = asyncio.get_running_loop().create_task(
-                self._flush_loop(), name="light-verify-flusher")
-
-    # -- the await-a-verdict entry point -------------------------------
+        return self._pending_weight
 
     async def check(self, plan: CommitVerifyPlan) -> None:
         """Queue `plan` for the next coalesced launch; returns when
@@ -217,64 +178,11 @@ class LightVerifyCollector:
         UNcounted: one shed REQUEST may park two plans (the gathered
         checks of a non-adjacent step), so the plane counts sheds once
         per request, not here per plan."""
-        if self.depth() >= self.pending_max:
-            raise LightServingShedError(self.depth(), self.pending_max)
-        self._ensure_flusher()
-        fut = asyncio.get_running_loop().create_future()
-        self._pending.append(_VerifyJob(plan, fut))
-        self._pending_lane_count += len(plan)
-        self._item_evt.set()
-        if self.pending_lanes() >= self.batch_max:
-            self._full_evt.set()
-        verdicts = await fut
+        try:
+            verdicts = await self.submit(plan, len(plan))
+        except BacklogFull as e:
+            raise LightServingShedError(e.depth, e.limit) from None
         plan.raise_invalid(verdicts)
-
-    # -- flusher -------------------------------------------------------
-
-    async def _flush_loop(self) -> None:
-        loop = asyncio.get_running_loop()
-        while True:
-            while not self._pending:
-                self._item_evt.clear()
-                await self._item_evt.wait()
-            deadline = loop.time() + self.flush_ms / 1000.0
-            while self.pending_lanes() < self.batch_max:
-                remaining = deadline - loop.time()
-                if remaining <= 0:
-                    break
-                self._full_evt.clear()
-                try:
-                    await asyncio.wait_for(self._full_evt.wait(),
-                                           remaining)
-                except asyncio.TimeoutError:
-                    break
-            batch: list[_VerifyJob] = []
-            lanes = 0
-            while self._pending and (not batch
-                                     or lanes < self.batch_max):
-                job = self._pending.popleft()
-                self._pending_lane_count -= len(job.plan)
-                batch.append(job)
-                lanes += len(job.plan)
-            self._in_flight = len(batch)
-            try:
-                verdicts = await loop.run_in_executor(
-                    None, self._verify_jobs, [j.plan for j in batch])
-                for job, v in zip(batch, verdicts):
-                    if not job.future.done():
-                        job.future.set_result(v)
-            except asyncio.CancelledError:
-                for job in batch:
-                    if not job.future.done():
-                        job.future.cancel()
-                raise
-            except Exception as e:  # defensive: a verdict must land
-                logger.exception("light verify batch died")
-                for job in batch:
-                    if not job.future.done():
-                        job.future.set_exception(e)
-            finally:
-                self._in_flight = 0
 
     # -- the coalesced verify launch (executor thread) -----------------
 
@@ -292,17 +200,9 @@ class LightVerifyCollector:
         return [verdicts[off:off + n] for off, n in spans]
 
     def _verify_triples(self, triples: list[tuple]) -> np.ndarray:
-        # Same dispatch stance as the admission plane: one wide
-        # general-kernel launch with a known-answer sentinel lane,
-        # breaker-aware, host fallback — and the shared crypto/tpu
-        # device-health counters move so dashboards see light-plane
-        # launches next to consensus ones. Cross-plan batches mix
-        # validator sets, so the general kernel (per-lane keys) is
-        # the right tool, not any one set's expanded tables.
         from ..crypto import batch as cbatch
         from ..libs import failpoints
-        from ..libs.metrics import (crypto_metrics, light_metrics,
-                                    tpu_metrics)
+        from ..libs.metrics import light_metrics
 
         met = light_metrics()
         n = len(triples)
@@ -311,103 +211,40 @@ class LightVerifyCollector:
         try:
             try:
                 failpoints.hit("light.verify")
+                injected = False
             except failpoints.FailpointError:
                 # injected launch failure: degrade to the host oracle,
                 # exactly like a raising device launch
-                met.verify_launches.inc(backend="host")
-                crypto_metrics().batch_lanes.inc(n, backend="host")
-                return self._host_verify(triples)
-            ed = [i for i, (pk, _, _) in enumerate(triples)
-                  if pk.type_name == "ed25519"]
-            ed_set = set(ed)
+                injected = True
             out = np.zeros(n, bool)
-            # non-ed25519 lanes (sr25519/secp256k1 validators) verify
-            # on host per key — rare in practice, never worth a
-            # second kernel here
-            for i in range(n):
-                if i not in ed_set:
-                    pk, m, s = triples[i]
-                    try:
-                        out[i] = pk.verify_signature(m, s)
-                    except Exception:
-                        out[i] = False
-            if not ed:
-                met.verify_launches.inc(backend="host")
-                return out
-            want_dev = len(ed) >= self.device_threshold
-            use_dev = want_dev and cbatch.breaker("ed25519").acquire()
-            if use_dev:
+            ed: list[int] = []
+            for i, (pk, m, s) in enumerate(triples):
+                if pk.type_name == "ed25519":
+                    ed.append(i)
+                    continue
+                # non-ed25519 lanes (sr25519/secp256k1 validators)
+                # verify on host per key — rare in practice, never
+                # worth a second kernel here
                 try:
-                    from ..crypto.tpu import verify as tpu_verify
-
-                    failpoints.hit("device.verify")
-                    # device_launches counts ATTEMPTS (the core
-                    # BatchVerifier convention — a raising launch
-                    # still burned a launch slot)
-                    crypto_metrics().device_launches.inc()
-                    # one known-answer sentinel lane rides every
-                    # device batch (the breaker probe's triple): a
-                    # NaN-ing kernel fails the sentinel, so wrong
-                    # verdicts are detected POSITIVELY and the batch
-                    # re-verifies on host instead of failing client
-                    # requests on headers that are actually valid
-                    spub, smsg, ssig = cbatch._ed_probe_triple()
-                    from ..crypto.tpu import backend as tpu_backend
-                    from ..crypto.tpu import ledger as tpu_ledger
-
-                    with tpu_ledger.workload("light"):
-                        dv = np.asarray(tpu_verify.verify_batch(
-                            [triples[i][0].bytes() for i in ed]
-                            + [spub],
-                            [triples[i][1] for i in ed] + [smsg],
-                            [triples[i][2] for i in ed] + [ssig]),
-                            bool)
-                    # the launch LANDED: only now does it count as a
-                    # device verify — a raising launch falls through
-                    # to the host path as ONE host launch, never
-                    # device+host for the same flush
-                    met.verify_launches.inc(backend="device")
-                    crypto_metrics().batch_lanes.inc(
-                        len(ed), backend=tpu_backend.platform())
-                    if dv[-1]:
-                        out[np.asarray(ed)] = dv[:-1]
-                        return out
-                    cbatch.mark_device_failed("ed25519")
-                    logger.error(
-                        "light verify batch (%d lanes) failed its "
-                        "known-answer sentinel; breaker open %.1fs, "
-                        "re-verifying on host", len(ed),
-                        cbatch.breaker("ed25519").cooldown_remaining())
-                    met.verify_launches.inc(backend="host_recheck")
-                    tpu_metrics().host_fallbacks.inc()
-                    return self._host_verify(triples, into=out, only=ed)
+                    out[i] = pk.verify_signature(m, s)
                 except Exception:
-                    cbatch.mark_device_failed("ed25519")
-                    logger.exception(
-                        "light device batch failed (%d lanes); "
-                        "breaker open %.1fs, degrading to host",
-                        len(ed),
-                        cbatch.breaker("ed25519").cooldown_remaining())
-            if want_dev:
-                tpu_metrics().host_fallbacks.inc()
-            met.verify_launches.inc(backend="host")
-            crypto_metrics().batch_lanes.inc(len(ed), backend="host")
-            return self._host_verify(triples, into=out, only=ed)
+                    out[i] = False
+            backend = "host"
+            if ed:
+                lanes = ([triples[i][0].bytes() for i in ed],
+                         [triples[i][1] for i in ed],
+                         [triples[i][2] for i in ed])
+                if injected:
+                    dv, backend = cbatch.host_ed25519_launch(*lanes)
+                else:
+                    dv, backend = cbatch.guarded_ed25519_launch(
+                        *lanes, workload="light",
+                        device_threshold=self.device_threshold)
+                out[np.asarray(ed)] = dv
+            cbatch.note_plane_launch(met.verify_launches, backend)
+            return out
         finally:
             met.verify_seconds.observe(time.perf_counter() - t0)
-
-    @staticmethod
-    def _host_verify(triples: list[tuple], into: np.ndarray | None = None,
-                     only: list[int] | None = None) -> np.ndarray:
-        out = np.zeros(len(triples), bool) if into is None else into
-        idxs = range(len(triples)) if only is None else only
-        for i in idxs:
-            pk, m, s = triples[i]
-            try:
-                out[i] = len(s) == 64 and pk.verify_signature(m, s)
-            except Exception:
-                out[i] = False
-        return out
 
 
 class ServingPlane:

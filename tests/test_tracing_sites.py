@@ -513,6 +513,68 @@ def test_admission_flush_names_the_host_backend():
     assert flush[6] == {"lanes": 1, "backend": "host"}
 
 
+def test_light_spans_cut_reasons_and_lineage(monkeypatch):
+    """The light plane's batches leave the pair the admission plane's
+    do (one collector emits both): light.queue_wait with the cut
+    reason, light.flush with the backend, crypto.verify beneath it."""
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.crypto.tpu import verify as tv
+    from tendermint_tpu.light.serving import LightVerifyCollector
+
+    from helpers import CHAIN_ID
+    from test_light import LightChain
+
+    cbatch.reset_breakers()
+    monkeypatch.setattr(tv, "_mesh", lambda: None)
+    monkeypatch.setattr(
+        tv, "_kernel",
+        lambda: lambda btab, **packed: np.ones(
+            packed["s_ok"].shape[0], bool))
+    chain = LightChain(3)
+
+    def plan(h):
+        lb = chain.blocks[h]
+        sh = lb.signed_header
+        return lb.validator_set.plan_commit_light(
+            CHAIN_ID, sh.commit.block_id, sh.header.height, sh.commit)
+
+    plans = [plan(h) for h in (1, 2, 3)]
+    assert [len(p) for p in plans] == [3, 3, 3]
+
+    async def go():
+        col = LightVerifyCollector(batch_max=6, flush_ms=30.0,
+                                   device_threshold=1)
+        try:
+            with TRACER.span(tracing.P2P_RECV_MSG):
+                await asyncio.gather(col.check(plans[0]),
+                                     col.check(plans[1]))
+            await col.check(plans[2])
+        finally:
+            col.close()
+
+    TRACER.clear()
+    run(go())
+    recs = TRACER.snapshot()
+    waits = [r for r in recs if r[0] == tracing.LIGHT_QUEUE_WAIT]
+    assert [(w[6]["lanes"], w[6]["cut"]) for w in waits] == [
+        (6, "full"), (3, "deadline")]
+    assert all(w[2] == 0 for w in waits)
+    assert waits[1][5] >= 25_000_000          # it waited its deadline out
+    assert waits[1][6]["wait_sum_ms"] >= 25.0
+    flushes = [r for r in recs if r[0] == tracing.LIGHT_FLUSH]
+    assert [f[6] for f in flushes] == [
+        {"lanes": 6, "backend": "device"},
+        {"lanes": 3, "backend": "device"}]
+    assert all(f[2] == 0 for f in flushes)
+    for w, f in zip(waits, flushes):
+        assert w[4] + w[5] <= f[4]
+    verifies = [r for r in recs if r[0] == tracing.CRYPTO_VERIFY]
+    assert len(verifies) == 2
+    for v in verifies:
+        assert tracing.LIGHT_FLUSH in ancestors(recs, v)
+        assert v[3] != flushes[0][3]           # it ran in a worker thread
+
+
 # ------------------------------------------------------------ device names
 
 

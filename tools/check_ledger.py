@@ -13,7 +13,9 @@ record" is only true while something enforces it. Four checks:
    any `ledger.launch/begin` call site under crypto/tpu/ NOT in the
    catalog is flagged, so the catalog and reality can't drift apart.
 2. Workload tags are a closed set. Every `workload("tag")` literal in
-   the product tree (and bench.py) names an entry in ledger.WORKLOADS,
+   the product tree (and bench.py), and every `workload="tag"` a verify
+   plane hands to crypto/batch.py's guarded launch, names an entry in
+   ledger.WORKLOADS,
    and every non-default tag has at least one call site — a plane
    whose tag nothing sets would silently report as `consensus`.
 3. Docs stay honest: docs/OBSERVABILITY.md has the "Launch ledger &
@@ -126,8 +128,9 @@ def check_dispatch_sites() -> list[str]:
 
 def workload_call_sites() -> dict[str, list[str]]:
     """{tag: ["relpath:line", ...]} over every `workload("tag")` call
-    with a string-literal argument, across tendermint_tpu/ and the
-    repo-root bench entry point."""
+    with a string-literal argument, and every call handing on a
+    `workload="tag"` literal, across tendermint_tpu/ and the repo-root
+    bench entry point."""
     roots = [PKG, os.path.join(REPO, "bench.py")]
     out: dict[str, list[str]] = {}
     paths = []
@@ -148,18 +151,20 @@ def workload_call_sites() -> dict[str, list[str]]:
             except SyntaxError:  # pragma: no cover
                 continue
         for node in ast.walk(tree):
-            if not isinstance(node, ast.Call) or not node.args:
+            if not isinstance(node, ast.Call):
                 continue
             f = node.func
             name = f.attr if isinstance(f, ast.Attribute) else \
                 getattr(f, "id", None)
-            if name != "workload":
-                continue
-            first = node.args[0]
-            if isinstance(first, ast.Constant) and \
-                    isinstance(first.value, str):
-                out.setdefault(first.value, []).append(
-                    f"{rel}:{node.lineno}")
+            tags = [kw.value for kw in node.keywords
+                    if kw.arg == "workload"]
+            if name == "workload" and node.args:
+                tags.append(node.args[0])
+            for tag in tags:
+                if isinstance(tag, ast.Constant) and \
+                        isinstance(tag.value, str):
+                    out.setdefault(tag.value, []).append(
+                        f"{rel}:{node.lineno}")
     return out
 
 
