@@ -27,6 +27,8 @@ import logging
 
 from ..libs import tracing
 from ..types.block import BlockID
+from ..types.sign_batch import (
+    CommitColumns, CommitSignBatch, MergedSignBatch)
 from ..types.validator_set import VerificationError
 
 logger = logging.getLogger("blockchain")
@@ -52,35 +54,26 @@ def _batch_verify_window(vals, chain_id: str, items):
     results: list = [None] * len(items)
     lanes_all: list[int] = []
     sigs_all: list[bytes] = []
-    per_commit: list[tuple] = []  # (commit, slots) per verifiable block
+    # (commit, slots, columns) per verifiable block
+    per_commit: list[tuple] = []
     with tracing.TRACER.span(tracing.VERIFY_COLLECT, blocks=len(items)):
         for i, (bid, height, commit) in enumerate(items):
-            start = len(lanes_all)
             try:
                 vals._check_commit_basics(bid, height, commit)
                 need = 2 * vals.total_voting_power()
-                tallied = 0
-                slots: list[int] = []
-                for idx, cs in enumerate(commit.signatures):
-                    if not cs.for_block():
-                        continue
-                    val = vals.validators[idx]
-                    lanes_all.append(idx)
-                    slots.append(idx)
-                    sigs_all.append(cs.signature)
-                    tallied += val.voting_power
-                    if 3 * tallied > need:
-                        break
+                cols = CommitColumns(commit)
+                slots, sigs, tallied = vals.light_selection(cols, need)
                 if 3 * tallied <= need:
                     raise VerificationError(
                         f"insufficient voting power at height {height}")
-                spans.append((i, start, len(lanes_all)))
-                per_commit.append((commit, slots))
             except Exception as e:
                 results[i] = e
-                # roll back this block's lanes
-                del lanes_all[start:]
-                del sigs_all[start:]
+                continue
+            start = len(lanes_all)
+            lanes_all.extend(slots.tolist())
+            sigs_all.extend(sigs)
+            spans.append((i, start, len(lanes_all)))
+            per_commit.append((commit, slots, cols))
     window = tracing.TRACER.current()
     if window is not None and window.kind == tracing.VERIFY_WINDOW:
         # the pipeline's job span learns its lane count here
@@ -109,18 +102,16 @@ def _window_lane_verdicts(vals, chain_id, lanes_all, sigs_all, per_commit):
     ValidatorSet._commit_msgs. The verify ladder itself (structured →
     bytes → host, device-failure degradation, logging) is owned by
     ValidatorSet._batch_verify_lanes — one copy for every call site."""
-    from ..types.sign_batch import CommitSignBatch, MergedSignBatch
-
     with tracing.TRACER.span(tracing.VERIFY_SIGN_BATCH,
                              lanes=len(lanes_all)):
         msgs = vals.structured_or_bytes(
             lanes_all,
             lambda: MergedSignBatch([
-                CommitSignBatch(chain_id, c, slots)
-                for c, slots in per_commit
+                CommitSignBatch(chain_id, c, slots, cols)
+                for c, slots, cols in per_commit
             ]),
             lambda: [c.vote_sign_bytes(chain_id, s)
-                     for c, slots in per_commit for s in slots],
+                     for c, slots, _ in per_commit for s in slots],
         )
     from ..crypto.tpu import ledger as tpu_ledger
 

@@ -1263,11 +1263,20 @@ def max_keys() -> int:
     return base * mesh.devices.size if mesh is not None else base
 
 
-def get_expanded(pubkeys: list[bytes]) -> ExpandedKeys:
+def key_digest(pubkeys: list[bytes]) -> bytes:
+    """_CACHE's key for a set's keys, in validator order."""
+    return hashlib.sha256(b"".join(pubkeys)).digest()
+
+
+def get_expanded(pubkeys: list[bytes],
+                 digest: bytes | None = None) -> ExpandedKeys:
+    """The set's tables, from _CACHE or built now. `digest` is
+    key_digest(pubkeys) where the caller holds it (a ValidatorSet
+    hashes its keys once, not once a commit); hashed here otherwise."""
     from ...libs.metrics import tpu_metrics
 
     tmet = tpu_metrics()
-    key = hashlib.sha256(b"".join(pubkeys)).digest()
+    key = digest if digest is not None else key_digest(pubkeys)
     while True:
         with _CACHE_LOCK:
             exp = _CACHE.get(key)

@@ -16,9 +16,12 @@ links over the hot paths:
       state.apply_block                ApplyBlock wall time
         state.validate                 validate_block (worker thread)
           verify.commit                one commit check, any form
-            verify.collect             basics + the loop over CommitSigs
+            verify.collect             basics + the commit's columns:
+                                       address check, lanes, tally
             verify.sign_batch          sign bytes (structured or full)
-            verify.tables              key-bytes list + table lookup
+            verify.tables              table lookup by the set's held
+                                       key digest (attr digest =
+                                       held | hashed: once a set)
             crypto.batch               a BatchVerifier.verify call
             crypto.verify              one device verify
               crypto.pack              host byte packing (numpy)

@@ -19,6 +19,12 @@ front-end) reassembles the exact bytes on device; `materialize()`
 yields the identical full bytes for host/fallback paths, and tests
 enforce byte equality between the two.
 
+A commit's slots reach all of this as columns: CommitColumns reads the
+CommitSig objects ONCE, by C-level iteration, and the verify sites
+(types/validator_set.py, blockchain/verify_ahead.py) and
+CommitSignBatch compute lanes, signatures, tally, address check,
+template groups and timestamps from the columns with array operations.
+
 Shapes:
   CommitSignBatch — one commit's slots (groups: for-block vs nil).
   MergedSignBatch — a fast-sync window: several commits, one group
@@ -30,11 +36,14 @@ Shapes:
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import InitVar, dataclass, field
+from itertools import compress
+from operator import attrgetter, ne
 
 import numpy as np
 
 from . import canonical
+from .block import BlockIDFlag
 
 PATCH_W = 24  # outer varint (<=2) + ts field (<=18), zero-padded
 
@@ -46,25 +55,27 @@ PATCH_W = 24  # outer varint (<=2) + ts field (<=18), zero-padded
 MAX_GROUPS = 32
 
 
+# v >= _VARINT_EDGES[k] needs more than k varint bytes (int64 >= 0:
+# at most nine).
+_VARINT_EDGES = np.array([1 << (7 * k) for k in range(9)], np.int64)
+
+
 def _vlen(v: np.ndarray) -> np.ndarray:
-    """Minimal varint byte length per element (v > 0)."""
-    bits = np.zeros(v.shape, np.int64)
-    x = v.astype(np.int64)
-    for shift in (32, 16, 8, 4, 2, 1):
-        hi = x >= (1 << shift)
-        bits += np.where(hi, shift, 0)
-        x = np.where(hi, x >> shift, x)
-    return (bits // 7 + 1).astype(np.int64)
+    """Minimal varint byte length per element of v >= 0; 0 where the
+    value is 0 (the field is then absent from the encoding)."""
+    return np.searchsorted(_VARINT_EDGES, v, side="right")
 
 
-def _varint_digits(out: np.ndarray, col: int, v: np.ndarray, ln: int):
-    """Write the ln-byte minimal varint of each v into out[:, col:]."""
-    for j in range(ln):
-        b = (v >> (7 * j)) & 0x7F
-        if j < ln - 1:
-            b = b | 0x80
-        out[:, col + j] = b
-    return col + ln
+def _varint_digits(out: np.ndarray, rows, col: int, v: np.ndarray,
+                   width: int) -> int:
+    """Write the minimal varint of each v into out[rows, col:]:
+    `width` is the byte length of the longest, and a shorter one
+    leaves zeros after its last byte. Returns the column after."""
+    for j in range(width):
+        rest = v >> 7
+        out[rows, col + j] = (v & 0x7F) | (rest > 0) * 0x80
+        v = rest
+    return col + width
 
 
 def _pack_templates(parts: list[tuple[bytes, bytes]]):
@@ -87,53 +98,55 @@ def _pack_templates(parts: list[tuple[bytes, bytes]]):
 
 
 def _build_patches(pre_len, suf_len, group, ts):
-    """Vectorized outer-varint + ts-field assembly, grouped by byte
-    layout (within one batch there are only a handful: seconds share
-    a varint width, nanos vary 1-5 bytes).
+    """Vectorized outer-varint + ts-field assembly.
+
+    A lane's patch is outer ‖ 0x2A pay ‖ 0x08 secs ‖ 0x10 nanos, each
+    piece absent when its value is 0. The nanos come last, so lanes
+    whose nanos differ in width still share their columns
+    (_varint_digits leaves zeros after a shorter one); only the outer
+    varint's length and the seconds' width move a byte's column, and
+    within one batch those take one value or two (the seconds share a
+    varint width), so each column is written over all rows at once.
 
     Returns (patch, split, patch_len); raises ValueError when a blob
     would exceed the two-byte outer-varint range."""
     n = ts.shape[0]
-    secs = ts // 1_000_000_000
-    nanos = ts % 1_000_000_000
-    ls = np.where(secs > 0, _vlen(np.maximum(secs, 1)), 0)
-    ln = np.where(nanos > 0, _vlen(np.maximum(nanos, 1)), 0)
-    pay = np.where(secs > 0, 1 + ls, 0) + np.where(nanos > 0, 1 + ln, 0)
-    tsf_total = np.where(ts > 0, 2 + pay, 0)
-    body = (pre_len[group].astype(np.int64) + tsf_total
-            + suf_len[group])
+    secs, nanos = np.divmod(ts, 1_000_000_000)
+    ls = _vlen(secs)
+    ln = _vlen(nanos)
+    pay = ls + (ls > 0) + ln + (ln > 0)
+    tsf_total = pay + 2 * (pay > 0)
+    body = (pre_len.astype(np.int64) + suf_len)[group] + tsf_total
     if body.size and body.max() >= 1 << 14:
         raise ValueError("sign bytes too long for structured batch")
-    outer_len = np.where(body >= 128, 2, 1)
+    outer_len = 1 + (body >= 128)
 
     patch = np.zeros((n, PATCH_W), np.uint8)
     split = outer_len.astype(np.int32)
     patch_len = (outer_len + tsf_total).astype(np.int32)
-    # layout key: everything that fixes byte positions/constants
-    key = (group.astype(np.int64) * 4 + (secs > 0) * 2
-           + (nanos > 0)) * 1024 + ls * 64 + ln * 8 + outer_len
-    for kv in np.unique(key):
-        m = key == kv
-        ol = int(outer_len[m][0])
-        bd = int(body[m][0])
+    # layout key: what fixes the columns of every piece but the last
+    key = outer_len * 16 + ls
+    keys = np.flatnonzero(np.bincount(key, minlength=48))
+    for kv in keys.tolist():
+        rows = slice(None) if len(keys) == 1 else np.flatnonzero(key == kv)
+        ol, width_s = divmod(kv, 16)
+        bd = body[rows]
         if ol == 1:
-            patch[m, 0] = bd
+            patch[rows, 0] = bd
         else:
-            patch[m, 0] = (bd & 0x7F) | 0x80
-            patch[m, 1] = bd >> 7
-        if int(tsf_total[m][0]) == 0:
-            continue
-        sub = np.zeros((int(m.sum()), PATCH_W - ol), np.uint8)
-        sub[:, 0] = 0x2A  # field 5, wire type 2
-        sub[:, 1] = pay[m]
-        col = 2
-        if int((secs > 0)[m][0]):
-            sub[:, col] = 0x08
-            col = _varint_digits(sub, col + 1, secs[m], int(ls[m][0]))
-        if int((nanos > 0)[m][0]):
-            sub[:, col] = 0x10
-            col = _varint_digits(sub, col + 1, nanos[m], int(ln[m][0]))
-        patch[m, ol:] = sub
+            patch[rows, 0] = (bd & 0x7F) | 0x80
+            patch[rows, 1] = bd >> 7
+        py = pay[rows]
+        patch[rows, ol] = (py > 0) * 0x2A  # field 5, wire type 2
+        patch[rows, ol + 1] = py
+        col = ol + 2
+        if width_s:
+            patch[rows, col] = 0x08
+            col = _varint_digits(patch, rows, col + 1, secs[rows], width_s)
+        lnr = ln[rows]
+        patch[rows, col] = (lnr > 0) * 0x10
+        _varint_digits(patch, rows, col + 1, nanos[rows],
+                       int(lnr.max(initial=0)))
     return patch, split, patch_len
 
 
@@ -143,6 +156,73 @@ def _check_ts(ts: int) -> int:
         # 2262 falls back to the full-bytes path instead.
         raise ValueError("timestamp out of int64 range")
     return ts
+
+
+_FLAG = attrgetter("block_id_flag")
+_ADDRESS = attrgetter("validator_address")
+_TIMESTAMP = attrgetter("timestamp")
+_SIGNATURE = attrgetter("signature")
+
+
+class CommitColumns:
+    """One Commit's CommitSig slots as columns, each read by ONE
+    C-level pass over the slots (map + attrgetter), for the verify
+    sites and CommitSignBatch to compute on with array operations.
+    Where a value fits no column (a flag past a byte, a timestamp past
+    int64, an address of another length) that slot is looked at by
+    itself, here. Nothing of it is kept on the Commit: CommitSig is
+    mutable, so a Commit is read again each time it is verified."""
+
+    __slots__ = ("commit", "present", "for_block")
+
+    def __init__(self, commit):
+        self.commit = commit
+        try:
+            flags = bytes(map(_FLAG, commit.signatures))
+        except ValueError:
+            # no BlockIDFlag is past a byte: such a slot is neither
+            # absent nor for the block
+            flags = bytes(f if 0 <= f < 256 else 0
+                          for f in map(_FLAG, commit.signatures))
+        flags = np.frombuffer(flags, np.uint8)
+        # (n,) bool each: not is_absent(), for_block()
+        self.present = flags != BlockIDFlag.ABSENT
+        self.for_block = flags == BlockIDFlag.COMMIT
+
+    def signatures(self, mask: np.ndarray) -> list[bytes]:
+        """The signatures of the slots a bool mask selects, in slot
+        order; a mask shorter than the commit selects among its first
+        slots only."""
+        return list(compress(map(_SIGNATURE, self.commit.signatures),
+                             mask.tobytes()))
+
+    def wrong_address(self, addresses: list[bytes]) -> int | None:
+        """The lowest present slot whose non-empty address differs
+        from its validator's (addresses[slot]), or None."""
+        sigs = self.commit.signatures
+        # every slot that differs, absent ones with their empty
+        # address among them: few, and looked at one by one
+        for idx in compress(range(len(sigs)),
+                            map(ne, map(_ADDRESS, sigs), addresses)):
+            if self.present[idx] and sigs[idx].validator_address:
+                return idx
+        return None
+
+    def timestamps(self, slots: np.ndarray) -> np.ndarray:
+        """(len(slots),) int64 timestamps of the given slots;
+        ValueError when one of THOSE is outside [0, 2^63) — the
+        vectorized layout is int64, and structured_or_bytes turns that
+        into the full-bytes path."""
+        sigs = self.commit.signatures
+        try:
+            ts = np.fromiter(map(_TIMESTAMP, sigs), np.int64,
+                             len(sigs))[slots]
+        except OverflowError:
+            ts = None  # some slot's value fits no int64: maybe not ours
+        if ts is None or (ts.size and ts.min() < 0):
+            ts = np.array([_check_ts(sigs[s].timestamp) for s in slots],
+                          np.int64)
+        return ts
 
 
 class StructuredSignBytes:
@@ -192,7 +272,10 @@ class CommitSignBatch(StructuredSignBytes):
 
     chain_id: str
     commit: object
-    slots: list[int]
+    slots: "list[int] | np.ndarray"
+    # the commit's columns where the caller has read them already;
+    # used here and not kept
+    columns: InitVar[CommitColumns | None] = None
     # templates, one row per group
     pre: np.ndarray = field(init=False)       # (K, PW) uint8
     pre_len: np.ndarray = field(init=False)   # (K,) int32
@@ -204,27 +287,26 @@ class CommitSignBatch(StructuredSignBytes):
     split: np.ndarray = field(init=False)     # (N,) int32 outer-varint len
     patch_len: np.ndarray = field(init=False)  # (N,) int32
 
-    def __post_init__(self):
+    def __post_init__(self, columns):
         from .vote import VoteType
 
-        commit, chain_id = self.commit, self.chain_id
-        n = len(self.slots)
-        parts: list[tuple[bytes, bytes]] = []   # group id -> (pre, suf)
-        group_of: dict[bool, int] = {}          # keyed by for_block()
-        group = np.zeros(n, np.int32)
-        ts = np.zeros(n, np.int64)
-        for i, slot in enumerate(self.slots):
-            cs = commit.signatures[slot]
-            ts[i] = _check_ts(cs.timestamp)
-            fb = cs.for_block()
-            g = group_of.get(fb)
-            if g is None:
-                g = len(parts)
-                group_of[fb] = g
-                parts.append(canonical.vote_sign_parts(
-                    chain_id, int(VoteType.PRECOMMIT), commit.height,
-                    commit.round, cs.block_id_for(commit.block_id)))
-            group[i] = g
+        commit = self.commit
+        if columns is None:
+            columns = CommitColumns(commit)
+        slots = np.asarray(self.slots, np.intp)
+        ts = columns.timestamps(slots)
+        # group ids in order of first appearance, keyed by for_block()
+        fb = columns.for_block[slots]
+        group = (fb != fb[:1]).astype(np.int32)
+        firsts = [0][:len(slots)]
+        if group.any():
+            firsts.append(int(group.argmax()))
+        parts = [
+            canonical.vote_sign_parts(
+                self.chain_id, int(VoteType.PRECOMMIT), commit.height,
+                commit.round,
+                commit.signatures[slots[i]].block_id_for(commit.block_id))
+            for i in firsts]
         self._finish(parts, group, ts)
 
     def __len__(self) -> int:

@@ -13,11 +13,17 @@ per-commit critical path."""
 
 from __future__ import annotations
 
+import dataclasses
+from operator import attrgetter
+
+import numpy as np
+
 from ..crypto import merkle
 from ..crypto.batch import BatchVerifier
 from ..libs import tracing
 from ..libs.tracing import TRACER
 from .block import BlockID
+from .sign_batch import CommitColumns, CommitSignBatch, StructuredSignBytes
 from .validator import Validator
 
 MAX_TOTAL_VOTING_POWER = (1 << 62) // 8
@@ -64,10 +70,8 @@ class CommitVerifyPlan:
         plans may come from different validator sets, so the shared
         launch uses the general per-lane-key kernel, not this set's
         expanded tables)."""
-        from .sign_batch import StructuredSignBytes
-
         msgs = self.msgs.materialize() \
-            if isinstance(self.msgs, StructuredSignBytes) else self.msgs
+            if _is_structured(self.msgs) else self.msgs
         return [(self.valset.validators[i].pub_key, m, s)
                 for i, m, s in zip(self.lanes, msgs, self.sigs)]
 
@@ -93,9 +97,37 @@ class CommitVerifyPlan:
 
 
 def _is_structured(msgs) -> bool:
-    from .sign_batch import StructuredSignBytes
-
     return isinstance(msgs, StructuredSignBytes)
+
+
+@dataclasses.dataclass
+class _SetColumns:
+    """What the verify sites need of a validator set, as columns:
+    built once per set (ValidatorSet._columns), never per commit.
+    `src` is the validators list they were read from — the validity
+    key, as for _addr_index. The digest is the key of
+    crypto/tpu/expanded.py's table cache and is hashed on first use;
+    the tables themselves stay in that cache, whose LRU alone decides
+    when they leave the chip."""
+
+    src: list
+    addresses: list[bytes]   # per validator, for the address check
+    power: np.ndarray        # (n,) voting powers; see _power_column
+    all_ed25519: bool
+    pubkeys: list[bytes]
+    digest: bytes | None = None
+
+
+def _power_column(validators: list) -> np.ndarray:
+    """Voting powers as int64 wherever every tally of a subset is
+    exact in it (no negative power, total within the cap of 2^59);
+    Python ints in an object array for a set that breaks either, so
+    that its tallies grow and do not wrap, until
+    total_voting_power() raises."""
+    powers = list(map(attrgetter("voting_power"), validators))
+    exact = not powers or (
+        min(powers) >= 0 and sum(powers) <= MAX_TOTAL_VOTING_POWER)
+    return np.array(powers, np.int64 if exact else object)
 
 
 class ValidatorSet:
@@ -103,6 +135,7 @@ class ValidatorSet:
         self._total: int | None = None
         self._addr_cache: dict = {}
         self._addr_cache_src: list | None = None
+        self._cols: _SetColumns | None = None
         if validators:
             vals = [v.copy() for v in validators]
             vals.sort(key=lambda v: (-v.voting_power, v.address))
@@ -141,6 +174,28 @@ class ValidatorSet:
             self._addr_cache_src = vals
         return self._addr_cache
 
+    def _held_columns(self) -> _SetColumns | None:
+        """The columns if they still describe this set — _addr_index's
+        rule: the validators list they were read from, at its length."""
+        cols = self._cols
+        if cols is not None and cols.src is self.validators and \
+                len(cols.addresses) == len(cols.src):
+            return cols
+        return None
+
+    def _columns(self) -> _SetColumns:
+        cols = self._held_columns()
+        if cols is None:
+            vals = self.validators
+            cols = self._cols = _SetColumns(
+                src=vals,
+                addresses=[v.address for v in vals],
+                power=_power_column(vals),
+                all_ed25519=all(v.pub_key.type_name == "ed25519"
+                                for v in vals),
+                pubkeys=[v.pub_key.bytes() for v in vals])
+        return cols
+
     def get_by_address(self, addr: bytes) -> tuple[int, Validator | None]:
         i = self._addr_index().get(addr, -1)
         return (i, self.validators[i]) if i >= 0 else (-1, None)
@@ -165,6 +220,10 @@ class ValidatorSet:
             i, _ = self.get_by_address(self.proposer.address)
             vs.proposer = vs.validators[i] if i >= 0 else self.proposer.copy()
         vs._total = self._total
+        cols = self._held_columns()
+        if cols is not None:
+            # same keys, addresses and powers: the columns are values
+            vs._cols = dataclasses.replace(cols, src=vs.validators)
         return vs
 
     def validate_basic(self) -> None:
@@ -285,8 +344,10 @@ class ValidatorSet:
 
     # -- commit verification (batched; the hot path) --
 
-    def _use_expanded(self, lanes: list[int]) -> bool:
-        """Will _batch_verify_lanes take the expanded device path?"""
+    def _use_expanded(self, lanes) -> bool:
+        """Will _batch_verify_lanes take the expanded device path for
+        this many lanes? (Its tables hold every key of the set, so the
+        set, not the lanes, has to be all ed25519.)"""
         from ..crypto import batch as _batch
         from ..crypto.tpu import verify as tv
 
@@ -310,9 +371,7 @@ class ValidatorSet:
             _batch.mark_device_failed("ed25519")
             _batch.logger.exception("backend probe failed; host path")
             return False
-        return (len(self.validators) <= cap
-                and all(self.validators[i].pub_key.type_name == "ed25519"
-                        for i in lanes))
+        return len(self.validators) <= cap and self._columns().all_ed25519
 
     def warm_device_tables(self):
         """Kick a background build of this set's expanded device
@@ -320,12 +379,11 @@ class ValidatorSet:
         for it would use them. Called when a validator-set change is
         adopted so the first commit under the new set doesn't pay the
         table build inline. Returns the thread or None."""
-        if not self._use_expanded(list(range(len(self.validators)))):
+        if not self._use_expanded(range(len(self.validators))):
             return None
         from ..crypto.tpu import expanded
 
-        return expanded.warm_async(
-            [v.pub_key.bytes() for v in self.validators])
+        return expanded.warm_async(self._columns().pubkeys)
 
     def structured_or_bytes(self, lanes: list[int], build, materialize):
         """THE structured-vs-full-bytes policy, one copy for every
@@ -343,18 +401,18 @@ class ValidatorSet:
                 pass
         return materialize()
 
-    def _commit_msgs(self, chain_id: str, commit, slots: list[int],
-                     lanes: list[int]):
-        """Sign bytes for the given commit slots: structured when the
-        device path will consume it, materialized otherwise."""
-        if not slots:
+    def _commit_msgs(self, chain_id: str, commit, slots, lanes,
+                     columns: CommitColumns | None = None):
+        """Sign bytes for the given commit slots (a list or an index
+        array): structured when the device path will consume it,
+        materialized otherwise. `columns`: the commit's, where the
+        caller has read them."""
+        if not len(slots):
             return []
-        from .sign_batch import CommitSignBatch
-
         with TRACER.span(tracing.VERIFY_SIGN_BATCH, lanes=len(slots)):
             return self.structured_or_bytes(
                 lanes,
-                lambda: CommitSignBatch(chain_id, commit, slots),
+                lambda: CommitSignBatch(chain_id, commit, slots, columns),
                 lambda: [commit.vote_sign_bytes(chain_id, s)
                          for s in slots],
             )
@@ -378,7 +436,7 @@ class ValidatorSet:
 
         structured = _is_structured(msgs)
         # structured implies _use_expanded held when the batch was
-        # built (_commit_msgs) — don't repeat the O(n) key-type scan.
+        # built (_commit_msgs)
         if structured or self._use_expanded(lanes):
             from ..crypto.tpu import expanded
             from ..libs import failpoints
@@ -386,9 +444,13 @@ class ValidatorSet:
             try:
                 failpoints.hit("device.verify")
                 with TRACER.span(tracing.VERIFY_TABLES,
-                                 keys=len(self.validators)):
-                    exp = expanded.get_expanded(
-                        [v.pub_key.bytes() for v in self.validators])
+                                 keys=len(self.validators)) as tspan:
+                    cols = self._columns()
+                    held = cols.digest is not None
+                    tspan.set_attr("digest", "held" if held else "hashed")
+                    if not held:
+                        cols.digest = expanded.key_digest(cols.pubkeys)
+                    exp = expanded.get_expanded(cols.pubkeys, cols.digest)
                 if structured:
                     try:
                         verdicts = exp.verify_structured(
@@ -425,6 +487,23 @@ class ValidatorSet:
             bv.add(self.validators[i].pub_key, m, s)
         return bv.verify()
 
+    def light_selection(self, cols: CommitColumns, need: int):
+        """VerifyCommitLight's selection over a commit's columns: the
+        for-block slots (an index array) up to and including the first
+        that carries the tally past 2/3 (3 * tally > need), their
+        signatures and that tally; every for-block slot when none
+        does. Shared with the fast-sync window builder
+        (blockchain/verify_ahead.py)."""
+        slots = np.flatnonzero(cols.for_block)
+        if not slots.size:
+            return slots, [], 0
+        tally = np.cumsum(self._columns().power[slots])
+        over = np.flatnonzero(3 * tally > need)
+        if over.size:
+            slots = slots[:over[0] + 1]
+        return (slots, cols.signatures(cols.for_block[:slots[-1] + 1]),
+                int(tally[len(slots) - 1]))
+
     def verify_commit(self, chain_id: str, block_id: BlockID, height: int,
                       commit) -> None:
         """Verify ALL non-absent signatures; tally for-block power must
@@ -434,25 +513,19 @@ class ValidatorSet:
 
     def _verify_commit(self, chain_id: str, block_id: BlockID,
                        height: int, commit, span) -> None:
-        lanes: list[int] = []
-        sigs: list[bytes] = []
-        tallied = 0
         with TRACER.span(tracing.VERIFY_COLLECT):
             self._check_commit_basics(block_id, height, commit)
-            for idx, cs in enumerate(commit.signatures):
-                if cs.is_absent():
-                    continue
-                val = self.validators[idx]
-                if cs.validator_address and \
-                        cs.validator_address != val.address:
-                    raise VerificationError(
-                        f"wrong validator address in slot {idx}"
-                    )
-                lanes.append(idx)
-                sigs.append(cs.signature)
-                if cs.for_block():
-                    tallied += val.voting_power
-        msgs = self._commit_msgs(chain_id, commit, lanes, lanes)
+            cols = CommitColumns(commit)
+            mine = self._columns()
+            bad = cols.wrong_address(mine.addresses)
+            if bad is not None:
+                raise VerificationError(
+                    f"wrong validator address in slot {bad}")
+            slots = np.flatnonzero(cols.present)
+            lanes = slots.tolist()
+            sigs = cols.signatures(cols.present)
+            tallied = int(mine.power[cols.for_block].sum())
+        msgs = self._commit_msgs(chain_id, commit, slots, lanes, cols)
         span.set_attr("lanes", len(lanes))
         span.set_attr("structured", _is_structured(msgs))
         ok, verdicts = self._batch_verify_lanes(lanes, msgs, sigs)
@@ -470,26 +543,17 @@ class ValidatorSet:
         cheapest 2/3 of for-block power, NO signature work. Raises
         VerificationError before planning any cryptography when the
         power cannot reach the threshold."""
-        lanes: list[int] = []
-        sigs: list[bytes] = []
-        tallied = 0
         need = 2 * self.total_voting_power()
         with TRACER.span(tracing.VERIFY_COLLECT):
             self._check_commit_basics(block_id, height, commit)
-            for idx, cs in enumerate(commit.signatures):
-                if not cs.for_block():
-                    continue
-                val = self.validators[idx]
-                lanes.append(idx)
-                sigs.append(cs.signature)
-                tallied += val.voting_power
-                if 3 * tallied > need:
-                    break
+            cols = CommitColumns(commit)
+            slots, sigs, tallied = self.light_selection(cols, need)
         if 3 * tallied <= need:
             raise VerificationError(
                 f"insufficient voting power: {tallied} of {self.total_voting_power()}"
             )
-        msgs = self._commit_msgs(chain_id, commit, lanes, lanes)
+        lanes = slots.tolist()
+        msgs = self._commit_msgs(chain_id, commit, slots, lanes, cols)
         return CommitVerifyPlan(self, lanes, lanes, sigs, msgs, "light")
 
     def verify_commit_light(self, chain_id: str, block_id: BlockID,

@@ -227,7 +227,8 @@ def _fake_expanded(monkeypatch, vals):
     keys.tables = np.zeros((n, 4), np.int32)
     keys._maybe_reshard = lambda: None
     monkeypatch.setattr(vs_mod, "_EXPAND_MIN", 2)
-    monkeypatch.setattr(ex, "get_expanded", lambda pubkeys: keys)
+    monkeypatch.setattr(ex, "get_expanded",
+                        lambda pubkeys, digest=None: keys)
     monkeypatch.setattr(ex, "max_keys", lambda: 1 << 20)
     monkeypatch.setattr(
         ex, "_skernel",
