@@ -36,7 +36,7 @@ from .msgs import (
     encode_bc_msg,
 )
 from .pool import BlockPool
-from .verify_ahead import BATCH_WINDOW, WindowPipeline
+from .verify_ahead import BATCH_WINDOW, WindowPipeline, sync_window
 
 logger = logging.getLogger("blockchain")
 
@@ -207,70 +207,49 @@ class BlockchainReactor(Reactor):
             logger.exception("fast-sync pool routine died")
 
     async def _try_sync(self) -> bool:
-        """Verify+apply a window of contiguous fetched blocks. Block i
-        is verified with block i+1's LastCommit, so with W+1 buffered
-        blocks, W are verifiable — in one signature batch when the
-        validator set is stable (the overwhelmingly common case).
-        While this window's blocks execute, the NEXT window's batch
-        verifies concurrently (verify-ahead), so steady-state catch-up
-        pays max(verify, apply) per window instead of their sum."""
+        """Verify+apply a window of contiguous fetched blocks through
+        the p2p-free engine's loop (verify_ahead.sync_window): one
+        signature batch a window, the next window verified ahead, a
+        stop at a refused block or a moved validator set. The pool,
+        the bans and the metrics are this reactor's."""
         blocks = self.pool.peek_blocks(BATCH_WINDOW + 1)
         if len(blocks) < 2:
             return False
-        vals = self.state.validators
-        chain_id = self.state.chain_id
-        items, parts_list, results = await self.pipeline.verdicts(
-            vals, chain_id, blocks)
-        self.pipeline.start_ahead(vals, chain_id,
-                                  self.pool.peek_blocks, len(blocks))
 
-        applied = 0
-        now = time.monotonic()
-        assumed_vals_hash = vals.hash()
-        for i, err in enumerate(results):
-            if err is not None:
-                # The failure implicates BOTH peers: the one that served
-                # block H (possibly forged) and the one that served
-                # block H+1 carrying the LastCommit used to verify H
-                # (possibly forged commit). Redo + ban both, mirroring
-                # reference blockchain/v0/reactor.go:409 — otherwise a
-                # byzantine peer serving H+1 with a bad commit keeps its
-                # block buffered while honest H-servers get banned one
-                # by one, stalling the sync.
-                bad_heights = (items[i][1], blocks[i + 1].header.height)
-                sw = self.switch
-                for h in bad_heights:
-                    peer_id = self.pool.redo_request(h)
-                    logger.warning(
-                        "block %d failed verification (%s); banning "
-                        "peer %s", h, err, peer_id,
-                    )
-                    if sw is not None and peer_id in sw.peers:
-                        rep = getattr(sw, "reporter", None)
-                        if rep is not None:
-                            # feed the trust metric before the hard stop
-                            rep.observe(peer_id, bad=1)
-                        sw._on_peer_error(sw.peers[peer_id],
-                                          RuntimeError(f"bad block: {err}"))
-                break
-            first = blocks[i]
-            bid = items[i][0]
-            # the part set built (off-loop) by the verify job — never
-            # re-serialize a full block on the event loop
-            parts = parts_list[i]
-            self.pool.pop_request(now)
-            self.block_store.save_block(first, parts, blocks[i + 1].last_commit)
-            self.state, _ = await self.block_exec.apply_block(
-                self.state, bid, first)
+        def applied_one(state, block) -> None:
+            self.state = state
             self.blocks_synced += 1
-            applied += 1
             blockchain_metrics().blocks_synced.inc()
-            if self.state.validators.hash() != assumed_vals_hash:
-                # validator set changed mid-window: the remaining
-                # verdicts were computed against the wrong set — leave
-                # those blocks buffered for re-verification next pass
-                # (any in-flight verify-ahead window is stale too: its
-                # key carries the old valset hash, so the next pass
-                # discards it and re-verifies under the new set)
-                break
+
+        _, applied, refused = await sync_window(
+            self.pipeline, self.state, blocks, self.pool.peek_blocks,
+            self.block_store, self.block_exec,
+            lambda block: self.pool.pop_request(time.monotonic()),
+            applied_one)
+        if refused is not None:
+            # The failure implicates BOTH peers: the one that served
+            # block H (possibly forged) and the one that served
+            # block H+1 carrying the LastCommit used to verify H
+            # (possibly forged commit). Redo + ban both, mirroring
+            # reference blockchain/v0/reactor.go:409 — otherwise a
+            # byzantine peer serving H+1 with a bad commit keeps its
+            # block buffered while honest H-servers get banned one
+            # by one, stalling the sync.
+            bad_heights = (refused.height,
+                           blocks[refused.index + 1].header.height)
+            sw = self.switch
+            for h in bad_heights:
+                peer_id = self.pool.redo_request(h)
+                logger.warning(
+                    "block %d failed verification (%s); banning "
+                    "peer %s", h, refused.error, peer_id,
+                )
+                if sw is not None and peer_id in sw.peers:
+                    rep = getattr(sw, "reporter", None)
+                    if rep is not None:
+                        # feed the trust metric before the hard stop
+                        rep.observe(peer_id, bad=1)
+                    sw._on_peer_error(
+                        sw.peers[peer_id],
+                        RuntimeError(f"bad block: {refused.error}"))
         return applied > 0

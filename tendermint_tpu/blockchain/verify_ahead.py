@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+from typing import NamedTuple
 
 from ..libs import tracing
 from ..types.block import BlockID
@@ -229,3 +230,65 @@ class WindowPipeline:
             None, tracing.TRACER.wrap(self._verify_window_job),
             vals, chain_id, nxt)
         self._prefetch = (key, fut, nxt)
+
+
+class Refusal(NamedTuple):
+    """The block a window's verification refused: its index in the
+    window, its height and the error. Block index+1 carried the
+    LastCommit it was checked with."""
+
+    index: int
+    height: int
+    error: Exception
+
+
+async def sync_window(pipeline: WindowPipeline, state, blocks, peek,
+                      block_store, block_exec, before_block, after_block):
+    """One pass of fast sync's verify-apply loop (reference
+    blockchain/v0/reactor.go poolRoutine's trySync, batched): block i
+    of `blocks` is verified with block i+1's LastCommit, so with W+1
+    blocks W are verifiable, in one signature batch while the set
+    holds still. While this window's blocks go through save_block and
+    apply_block, the NEXT window's batch verifies concurrently
+    (verify-ahead; `peek(n)` returns up to n contiguous buffered
+    blocks), so steady-state catch-up pays max(verify, apply) a window
+    instead of their sum.
+
+    Stops at the first refused block, and after the first block that
+    moved the validator set: the remaining verdicts were computed
+    against the wrong set, so those blocks stay with the caller for
+    the next pass (any window verified ahead is stale too: its key
+    carries the old set's hash, so the next pass discards it and
+    verifies under the new set).
+
+    `before_block(block)` and `after_block(state, block)` bracket each
+    block's save and apply (the reactor's pool and metrics; a bench's
+    clock). Returns (state, blocks applied, Refusal or None)."""
+    vals = state.validators
+    chain_id = state.chain_id
+    items, parts_list, results = await pipeline.verdicts(
+        vals, chain_id, blocks)
+    pipeline.start_ahead(vals, chain_id, peek, len(blocks))
+
+    applied = 0
+    assumed_vals_hash = vals.hash()
+    for i, err in enumerate(results):
+        if err is not None:
+            return state, applied, Refusal(i, items[i][1], err)
+        first = blocks[i]
+        before_block(first)
+        # the part set built (off-loop) by the verify job: never
+        # re-serialize a full block on the event loop
+        block_store.save_block(first, parts_list[i],
+                               blocks[i + 1].last_commit)
+        state, _ = await block_exec.apply_block(state, items[i][0], first)
+        applied += 1
+        after_block(state, first)
+        if state.validators.hash() != assumed_vals_hash:
+            # what the cut throws away: verified - applied blocks of
+            # this launch, and the window verified ahead
+            tracing.TRACER.begin(
+                tracing.SYNC_WINDOW_CUT, applied=applied,
+                verified=len(results), height=first.header.height).end()
+            break
+    return state, applied, None

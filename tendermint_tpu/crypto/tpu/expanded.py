@@ -1239,6 +1239,7 @@ _CACHE_MAX = 2
 # behind another key's multi-second build.
 _CACHE_LOCK = threading.Lock()
 _BUILDS: dict[bytes, threading.Event] = {}
+_WARM_THREAD = "expanded-warm"   # warm_async's thread, by name
 
 
 def max_keys() -> int:
@@ -1292,20 +1293,37 @@ def get_expanded(pubkeys: list[bytes],
         # Another thread is building this exact key: wait, then loop —
         # either the table is cached now, or the builder failed and
         # this thread claims the build itself.
-        ev.wait()
-    try:
-        tmet.expanded_cache.inc(event="miss")
-        with tmet.expanded_build_seconds.time():
-            exp = ExpandedKeys(pubkeys)
-        with _CACHE_LOCK:
-            _CACHE[key] = exp
-            while len(_CACHE) > _CACHE_MAX:
-                _CACHE.popitem(last=False)
-        return exp
-    finally:
-        with _CACHE_LOCK:
-            _BUILDS.pop(key, None)
-        ev.set()
+        with tracing.TRACER.span(tracing.CRYPTO_TABLE_WAIT,
+                                 keys=len(pubkeys)):
+            ev.wait()
+    warm = threading.current_thread().name == _WARM_THREAD
+    with tracing.TRACER.span(
+            tracing.CRYPTO_TABLE_BUILD, keys=len(pubkeys),
+            thread="warm" if warm else "inline") as span:
+        try:
+            tmet.expanded_cache.inc(event="miss")
+            with tmet.expanded_build_seconds.time():
+                exp = ExpandedKeys(pubkeys)
+            evicted = 0
+            with _CACHE_LOCK:
+                _CACHE[key] = exp
+                while len(_CACHE) > _CACHE_MAX:
+                    _CACHE.popitem(last=False)
+                    evicted += 1
+        finally:
+            with _CACHE_LOCK:
+                _BUILDS.pop(key, None)
+            ev.set()
+        span.set_attr("bytes", int(exp.tables.nbytes))
+        span.set_attr("evicted", evicted)
+        if tracing.TRACER.enabled:
+            # the span ends when the tables are on the device, not when
+            # their last builder launch is enqueued; the tables are in
+            # _CACHE and the waiters released by now, as without a span
+            import jax
+
+            jax.block_until_ready(exp.tables)
+    return exp
 
 
 def structured_phases() -> dict[str, str]:
@@ -1355,6 +1373,6 @@ def warm_async(pubkeys: list[bytes]) -> threading.Thread:
                 "background expanded-table warm failed (%d keys)",
                 len(pubkeys))
 
-    t = threading.Thread(target=build, name="expanded-warm", daemon=True)
+    t = threading.Thread(target=build, name=_WARM_THREAD, daemon=True)
     t.start()
     return t

@@ -39,6 +39,14 @@ links over the hot paths:
   verify.window                        a fast-sync window in its worker:
                                        part sets, then collect /
                                        sign_batch / tables / crypto.*
+  sync.window_cut                      the verify-apply loop stopped at
+                                       a block that moved the set
+                                       (applied of verified blocks)
+  crypto.table_build                   a set's comb tables built until
+                                       they are on the device (thread =
+                                       warm | inline)
+  crypto.table_wait                    a launch site waiting for another
+                                       thread's build of its set
   admission.queue_wait                 first pending envelope -> the cut
   admission.flush                      the cut -> last verdict delivered
     crypto.verify ...                  (executor thread, via wrap)
@@ -152,6 +160,11 @@ CRYPTO_DISPATCH = register_kind("crypto.dispatch")
 CRYPTO_DEVICE_EXEC = register_kind("crypto.device_exec")
 CRYPTO_READBACK = register_kind("crypto.readback")
 CRYPTO_HOST_VERIFY = register_kind("crypto.host_verify")
+# The comb tables of a validator set (crypto/tpu/expanded.py
+# get_expanded): the build until the tables are on the device, and a
+# launch site held up behind another thread's build of the same set.
+CRYPTO_TABLE_BUILD = register_kind("crypto.table_build")
+CRYPTO_TABLE_WAIT = register_kind("crypto.table_wait")
 
 # Verify-ahead pipeline (consensus/speculation.py + crypto/tpu/
 # resident.py): speculate = an ahead-of-commit verification launch,
@@ -168,6 +181,9 @@ VERIFY_COLLECT = register_kind("verify.collect")
 VERIFY_SIGN_BATCH = register_kind("verify.sign_batch")
 VERIFY_TABLES = register_kind("verify.tables")
 VERIFY_WINDOW = register_kind("verify.window")
+# Fast sync's verify-apply loop (blockchain/verify_ahead.py
+# sync_window) stopping inside a window because the set moved.
+SYNC_WINDOW_CUT = register_kind("sync.window_cut")
 
 # The verify planes' micro-batcher (crypto/collector.py), one pair per
 # batch: mempool/admission.py's and light/serving.py's.

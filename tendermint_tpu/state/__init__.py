@@ -144,11 +144,15 @@ def median_time(commit: Commit, validators: ValidatorSet) -> int:
             continue
         pairs.append((cs.timestamp, val.voting_power))
         total += val.voting_power
+    # reference types/time/time.go WeightedMedian, to the letter: half
+    # the power rounded DOWN, and the vote that reaches it. With an odd
+    # total and a run of votes summing to exactly that half, rounding up
+    # names the next vote's time: another block time than every
+    # reference node computes.
     pairs.sort()
-    half = (total + 1) // 2
-    acc = 0
+    median = total // 2
     for ts, power in pairs:
-        acc += power
-        if acc >= half:
+        if median <= power:
             return ts
+        median -= power
     return 0

@@ -30,6 +30,7 @@ NEW_KINDS = (
     "state.app_commit", "state.save", "state.events",
     "store.save_block", "db.write",
     "consensus.new_height", "admission.queue_wait", "admission.flush",
+    "crypto.table_build", "crypto.table_wait", "sync.window_cut",
 )
 APPLY_CHILDREN = [
     "state.validate", "state.exec", "state.save_responses",
@@ -345,6 +346,55 @@ def test_verify_window_span_has_its_building_apart_from_its_launch(
     assert kids[0][4] > window[4]
     assert tracing.VERIFY_WINDOW in ancestors(
         recs, next(r for r in recs if r[0] == tracing.CRYPTO_VERIFY))
+
+
+# ------------------------------------------------------------ table builds
+
+
+def test_table_build_and_wait_spans(monkeypatch):
+    """One thread builds a set's tables, a launch site that asks for
+    the same set meanwhile waits for it: one crypto.table_build (keys,
+    bytes, evicted, thread) and one crypto.table_wait that ends when
+    the build does; a third set pushes the first out of the cache."""
+    import threading
+    import time
+    from collections import OrderedDict
+
+    from tendermint_tpu.crypto.tpu import expanded as ex
+
+    started = threading.Event()
+
+    class SlowTables:
+        def __init__(self, pubkeys):
+            self.tables = np.zeros((len(pubkeys), 8), np.int32)
+            started.set()
+            time.sleep(0.15)
+
+    monkeypatch.setattr(ex, "ExpandedKeys", SlowTables)
+    monkeypatch.setattr(ex, "_CACHE", OrderedDict())
+    sets = [[bytes([i, j]) * 16 for j in range(3)] for i in range(3)]
+    TRACER.clear()
+    builder = ex.warm_async(sets[0])
+    assert started.wait(5)
+    got = ex.get_expanded(sets[0])            # waits for the builder
+    builder.join()
+    assert got is ex.get_expanded(sets[0])    # a hit: no span
+    ex.get_expanded(sets[1])
+    ex.get_expanded(sets[2])                  # evicts sets[0]'s
+    recs = TRACER.snapshot()
+    builds = [r for r in recs if r[0] == tracing.CRYPTO_TABLE_BUILD]
+    waits = [r for r in recs if r[0] == tracing.CRYPTO_TABLE_WAIT]
+    assert [b[6] for b in builds] == [
+        {"keys": 3, "thread": "warm", "bytes": 96, "evicted": 0},
+        {"keys": 3, "thread": "inline", "bytes": 96, "evicted": 0},
+        {"keys": 3, "thread": "inline", "bytes": 96, "evicted": 1}]
+    assert all(b[5] >= 150_000_000 for b in builds)
+    (wait,) = waits
+    assert wait[6] == {"keys": 3} and wait[5] > 50_000_000
+    # the wait ends when the build it waited for ends
+    assert abs((wait[4] + wait[5]) - (builds[0][4] + builds[0][5])) \
+        < 50_000_000
+    assert len(ex._CACHE) == ex._CACHE_MAX == 2
 
 
 # -------------------------------------------------------------- apply path

@@ -572,6 +572,7 @@ class FakeTables:
 
     def __init__(self, pubkeys):
         self.pubkeys = list(pubkeys)
+        self.tables = np.zeros((len(self.pubkeys), 4), np.int32)
 
     def verify_structured(self, lanes, msgs, sigs):
         return np.ones(len(lanes), bool)

@@ -62,6 +62,7 @@ REQUIRED_KINDS = frozenset({
     "state.app_commit", "state.save", "state.events",
     "store.save_block", "db.write",
     "admission.queue_wait", "admission.flush", "consensus.new_height",
+    "crypto.table_build", "crypto.table_wait", "sync.window_cut",
     # height forensics reads these two by name: recv spans carry the
     # rehydrated origin tags, send_flush is the wire-side counterpart
     "p2p.recv_msg", "p2p.send_flush",
