@@ -200,6 +200,9 @@ STATE_EXEC = register_kind("state.exec")
 STATE_SAVE_RESPONSES = register_kind("state.save_responses")
 STATE_APP_COMMIT = register_kind("state.app_commit")
 STATE_SAVE = register_kind("state.save")
+# a validator set's membership encoded in full by the state store
+# (attrs height, keys, bytes): none on a block whose set stands still
+STATE_VALSET_ROW = register_kind("state.valset_row")
 STATE_EVENTS = register_kind("state.events")
 STORE_SAVE_BLOCK = register_kind("store.save_block")
 DB_WRITE = register_kind("db.write")

@@ -65,12 +65,20 @@ def abci_header_from_block(block: Block) -> dict:
 
 
 def build_last_commit_info(block: Block, state_store: Store,
-                           initial_height: int) -> abci_t.LastCommitInfo:
-    """Who signed the last block, with powers from the stored valset
-    (reference: state/execution.go getBeginBlockValidatorInfo)."""
+                           initial_height: int,
+                           last_validators: ValidatorSet | None = None
+                           ) -> abci_t.LastCommitInfo:
+    """Who signed the last block, with the powers of the set of the
+    height before it (reference: state/execution.go
+    getBeginBlockValidatorInfo). A caller that applies `block` to a
+    state holds that set as `state.last_validators`, the signers of
+    `block.last_commit`, and hands it in; only a caller with no state
+    (the handshake's app-only replay) has it read from the store."""
     if block.header.height <= initial_height or block.last_commit is None:
         return abci_t.LastCommitInfo()
-    vals = state_store.load_validators(block.header.height - 1)
+    vals = last_validators
+    if vals is None:
+        vals = state_store.load_validators(block.header.height - 1)
     if vals is None:
         raise ExecutionError(
             f"no validator set stored for height {block.header.height - 1}"
@@ -219,7 +227,8 @@ class BlockExecutor:
             hash=block.hash(),
             header=abci_header_from_block(block),
             last_commit_info=build_last_commit_info(
-                block, self.store, state.initial_height
+                block, self.store, state.initial_height,
+                state.last_validators
             ),
             byzantine_validators=byz,
         ))

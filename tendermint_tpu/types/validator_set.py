@@ -14,6 +14,7 @@ per-commit critical path."""
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from operator import attrgetter
 
 import numpy as np
@@ -116,6 +117,7 @@ class _SetColumns:
     all_ed25519: bool
     pubkeys: list[bytes]
     digest: bytes | None = None
+    membership: bytes | None = None  # see ValidatorSet.membership_digest
 
 
 def _power_column(validators: list) -> np.ndarray:
@@ -195,6 +197,21 @@ class ValidatorSet:
                                 for v in vals),
                 pubkeys=[v.pub_key.bytes() for v in vals])
         return cols
+
+    def membership_digest(self) -> bytes:
+        """32 bytes that stand for the set's key types, keys, powers
+        and order (what hash() commits to, without its Merkle tree),
+        hashed once per set and held with its columns. The state store
+        (state/store.py) names with it the membership a row holds."""
+        cols = self._columns()
+        if cols.membership is None:
+            kinds = "ed25519" if cols.all_ed25519 else ",".join(
+                v.pub_key.type_name for v in cols.src)
+            cols.membership = hashlib.sha256(b"|".join((
+                kinds.encode(), b"".join(cols.pubkeys),
+                ",".join(map(str, cols.power.tolist())).encode(),
+            ))).digest()
+        return cols.membership
 
     def get_by_address(self, addr: bytes) -> tuple[int, Validator | None]:
         i = self._addr_index().get(addr, -1)

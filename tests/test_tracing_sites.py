@@ -31,6 +31,7 @@ NEW_KINDS = (
     "store.save_block", "db.write",
     "consensus.new_height", "admission.queue_wait", "admission.flush",
     "crypto.table_build", "crypto.table_wait", "sync.window_cut",
+    "state.valset_row",
 )
 APPLY_CHILDREN = [
     "state.validate", "state.exec", "state.save_responses",
@@ -433,10 +434,12 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
         executor = BlockExecutor(store, client)
         last_commit = None
         per_block = []
-        for h in range(2):
+        for h in range(3):
+            # the third block re-weights a validator: the set moves
+            last = b"c%d=3" % h if h < 2 else b"val:%s!%d" % (
+                pvs[1].get_pub_key().bytes().hex().encode(), 15)
             block, bid = next_block(state, pvs, last_commit,
-                                    [b"a%d=1" % h, b"b%d=2" % h,
-                                     b"c%d=3" % h])
+                                    [b"a%d=1" % h, b"b%d=2" % h, last])
             seen = commit_for(state, pvs, block, bid)
             TRACER.clear()
             commits["n"] = 0
@@ -449,7 +452,7 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
             d.close()
         return per_block
 
-    for recs, durable in run(go()):
+    for height, (recs, durable) in enumerate(run(go()), 1):
         (apply_,) = [r for r in recs if r[0] == tracing.STATE_APPLY_BLOCK]
         kids = [k for k in children(recs, apply_)]
         assert [k[0] for k in kids] == APPLY_CHILDREN
@@ -473,11 +476,21 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
         assert by_parent[tracing.STORE_SAVE_BLOCK] == 1
         assert by_parent[tracing.STATE_SAVE_RESPONSES] == 1
         assert by_parent[tracing.STATE_APP_COMMIT] == 1
-        assert by_parent[tracing.STATE_SAVE] >= 1
-        # the app's commit carries the block: 3 keys + its state record
+        # the state's ONE batch; a set's membership is encoded into it
+        # only by the block that moves the set (in force two on)
+        assert by_parent[tracing.STATE_SAVE] == 1
+        (save,) = [k for k in kids if k[0] == tracing.STATE_SAVE]
+        rows = [r for r in recs if r[0] == tracing.STATE_VALSET_ROW]
+        assert all(r[2] == save[1] for r in rows)
+        assert [dict(r[6], bytes=0) for r in rows] == (
+            [] if height < 3
+            else [{"height": height + 2, "keys": 4, "bytes": 0}])
+        assert all(r[6]["bytes"] > 4 * 52 for r in rows)
+        # the app's commit carries the block: its keys (the third
+        # block's val: tx is none) + its state record
         (app_write,) = [w for w in writes
                         if by_id.get(w[2]) == tracing.STATE_APP_COMMIT]
-        assert app_write[6]["ops"] == 4
+        assert app_write[6]["ops"] == (4 if height < 3 else 3)
 
 
 # --------------------------------------------------------------- admission

@@ -60,6 +60,7 @@ REQUIRED_KINDS = frozenset({
     "verify.tables", "verify.window",
     "state.validate", "state.exec", "state.save_responses",
     "state.app_commit", "state.save", "state.events",
+    "state.valset_row",
     "store.save_block", "db.write",
     "admission.queue_wait", "admission.flush", "consensus.new_height",
     "crypto.table_build", "crypto.table_wait", "sync.window_cut",
