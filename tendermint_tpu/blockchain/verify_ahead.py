@@ -26,6 +26,8 @@ import asyncio
 import logging
 from typing import NamedTuple
 
+import numpy as np
+
 from ..libs import tracing
 from ..types.block import BlockID
 from ..types.sign_batch import (
@@ -44,13 +46,14 @@ def _batch_verify_window(vals, chain_id: str, items):
     or None, mirroring VerifyCommitLight's accept/reject per block
     (reference types/validator_set.go:720, batched across blocks).
 
-    Large all-ed25519 sets go through the expanded comb tables with
-    STRUCTURED sign bytes (one template group per block's commit,
-    types/sign_batch.py MergedSignBatch) — the same valset verifies
-    every block of the window AND every window of the catch-up, which
-    is exactly the workload the device-resident tables exist for.
-    Everything else (or any structural/device failure) falls back to
-    the general BatchVerifier with full bytes."""
+    The ed25519 lanes of a large set go through the expanded comb
+    tables with STRUCTURED sign bytes (one template group per block's
+    commit, types/sign_batch.py MergedSignBatch, over the ed25519
+    slots of each) — the same valset verifies every block of the
+    window AND every window of the catch-up, which is exactly the
+    workload the device-resident tables exist for. Everything else
+    (lanes of another key type; any structural/device failure) goes
+    through the general BatchVerifier with full bytes."""
     spans: list = []
     results: list = [None] * len(items)
     lanes_all: list[int] = []
@@ -107,12 +110,13 @@ def _window_lane_verdicts(vals, chain_id, lanes_all, sigs_all, per_commit):
                              lanes=len(lanes_all)):
         msgs = vals.structured_or_bytes(
             lanes_all,
-            lambda: MergedSignBatch([
+            lambda pick: MergedSignBatch([
                 CommitSignBatch(chain_id, c, slots, cols)
-                for c, slots, cols in per_commit
+                for c, slots, cols in _picked(per_commit, pick)
             ]),
-            lambda: [c.vote_sign_bytes(chain_id, s)
-                     for c, slots, _ in per_commit for s in slots],
+            lambda pick: [c.vote_sign_bytes(chain_id, s)
+                          for c, slots, _ in _picked(per_commit, pick)
+                          for s in slots],
         )
     from ..crypto.tpu import ledger as tpu_ledger
 
@@ -120,6 +124,20 @@ def _window_lane_verdicts(vals, chain_id, lanes_all, sigs_all, per_commit):
         _, verdicts = vals._batch_verify_lanes(lanes_all, msgs,
                                                sigs_all)
     return verdicts
+
+
+def _picked(per_commit, pick):
+    """per_commit with each commit's slots cut to those among the
+    window's lanes at positions `pick` (ascending; None: all of them):
+    the ed25519 slots, or the others, of a set of several key types. A
+    commit left with no slot drops out."""
+    if pick is None:
+        return per_commit
+    bounds = np.cumsum([0] + [len(slots) for _, slots, _ in per_commit])
+    cuts = np.searchsorted(pick, bounds)
+    return [(c, slots[pick[cuts[j]:cuts[j + 1]] - bounds[j]], cols)
+            for j, (c, slots, cols) in enumerate(per_commit)
+            if cuts[j + 1] > cuts[j]]
 
 
 def window_items(blocks) -> tuple[list[tuple], list]:

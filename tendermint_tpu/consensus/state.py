@@ -1110,10 +1110,14 @@ class ConsensusState(Service):
             votes = [batch[j][0] for j in idxs]
             lanes = [v.validator_index for v in votes]
             sigs = [v.signature for v in votes]
+            def picked(pick, votes=votes):
+                return votes if pick is None else [votes[i] for i in pick]
+
             msgs = vals.structured_or_bytes(
                 lanes,
-                lambda: VoteSignBatch(chain_id, votes),
-                lambda: [v.sign_bytes(chain_id) for v in votes],
+                lambda pick: VoteSignBatch(chain_id, picked(pick)),
+                lambda pick: [v.sign_bytes(chain_id)
+                              for v in picked(pick)],
             )
             _, group_verdicts = vals._batch_verify_lanes(
                 lanes, msgs, sigs)

@@ -451,6 +451,23 @@ def assemble_core():
     return assemble
 
 
+# Up to this many lanes the structured program takes the field
+# operations as calls (field.as_calls): a shape's first launch then
+# costs ~5 s of tracing and lowering on the chip's host where the
+# inlined form costs ~25-50, and these are the launches a node meets
+# in many shapes (a LastCommit, a light commit, a block's evidence, a
+# fast-sync window of a 1,000-validator chain). Measured on the v5e
+# (PERF.md §6, PR 35): the called form runs as fast at 1,024 and
+# 2,048 lanes (4.30 / 10.5 ms for 4.32 / 10.55) and SLOWER above:
+# 28.8 ms for 25.95 at 7,168 lanes, 39.8 for 36.1 at 10,240 (XLA
+# prefetches the main loop's operands otherwise). Under the limit a
+# launch is verified ahead or is a few ms of a block's ~80-180 ms of
+# host work, and a 35 s stall at a shape's first launch is the larger
+# cost; over it the launch is a 10,000-validator commit, whose latency
+# IS the kernel's, and the program stays the inlined one.
+_CALLS_MAX_LANES = 8192
+
+
 @functools.cache
 def _skernel(wpi: int = WINDOWS_PER_ITER):
     """Structured front-end: assemble each lane's padded message ON
@@ -460,6 +477,8 @@ def _skernel(wpi: int = WINDOWS_PER_ITER):
     ship once per launch."""
     import jax
 
+    from .fieldsel import F as fe
+
     core = _xcore(wpi)
     assemble = assemble_core()
 
@@ -467,10 +486,11 @@ def _skernel(wpi: int = WINDOWS_PER_ITER):
     def skernel(idx, akeys, sb, s_ok, key_ok, atab, btab,
                 pre, pre_len, suf, suf_len, patch, split, patch_len,
                 group, *, width):
-        msg, nblocks = assemble(pre, pre_len, suf, suf_len, patch,
-                                split, patch_len, group, width)
-        return core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab,
-                    btab)
+        with fe.as_calls(idx.shape[0] <= _CALLS_MAX_LANES):
+            msg, nblocks = assemble(pre, pre_len, suf, suf_len, patch,
+                                    split, patch_len, group, width)
+            return core(idx, akeys, sb, msg, nblocks, s_ok, key_ok,
+                        atab, btab)
 
     return skernel
 

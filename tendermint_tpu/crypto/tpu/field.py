@@ -24,9 +24,26 @@ after a single pass, and that is fine — the mul overflow bound has
 
 Everything here is pure-functional jnp on int32 — no Python control
 flow on data — so the whole verifier jits into one XLA program.
+
+A verify kernel calls `add`, `sub`, `neg`, `mul` and `sqr` a few
+hundred times: inlined, that is 54,000-81,000 equations to trace and
+to lower for EVERY lane bucket (~10 s of tracing anywhere, 15-42 s of
+`jaxpr_to_mlir_module` on the chip's host: PERF.md §6, PR 35). A kernel
+traced under `as_calls()` gets them as calls of jitted functions, each
+traced once a shape and lowered to one function a module (the sr25519
+kernel: 3,856 top-level equations, its first launch ~5 s of Python for
+~35). XLA inlines the calls before it optimises and the arithmetic is
+the same, but its schedule is not: the sr25519 kernel runs as fast or
+faster so (512 / 1,024 / 4,096 lanes), `jit_skernel` as fast at 1,024
+and 2,048 lanes and 8-11 % SLOWER at 7,168 and 10,240 (my chip runs,
+PR 35). So the form is the kernel's choice, and the default is inline.
 """
 
 from __future__ import annotations
+
+import contextlib
+import contextvars
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +56,31 @@ MASK = (1 << BITS) - 1
 # 2^(12*22) = 2^264 ≡ 19 * 2^9 (mod p)
 FOLD = 19 << 9
 SIGNED = False  # limbs are kept non-negative (see sub bias below)
+
+_AS_CALLS = contextvars.ContextVar("field_ops_as_calls", default=False)
+
+
+@contextlib.contextmanager
+def as_calls(on: bool = True):
+    """While a kernel's body is traced under this, the field operations
+    it calls are calls of jitted functions (module docstring)."""
+    token = _AS_CALLS.set(on)
+    try:
+        yield
+    finally:
+        _AS_CALLS.reset(token)
+
+
+def _op(fn):
+    """A field operation in both forms: inline, or under as_calls() a
+    call of its jitted self."""
+    call = jax.jit(fn)
+
+    @functools.wraps(fn)
+    def op(*args):
+        return call(*args) if _AS_CALLS.get() else fn(*args)
+
+    return op
 
 
 def to_limbs(x: int) -> np.ndarray:
@@ -135,20 +177,24 @@ def _pass22(x: jnp.ndarray) -> jnp.ndarray:
 REDUCED_BOUND = 7700
 
 
+@_op
 def add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """REDUCED + REDUCED -> REDUCED."""
     return _pass22(jnp.asarray(a) + jnp.asarray(b))
 
 
+@_op
 def sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """REDUCED - REDUCED -> REDUCED. Adds 1024p so limbs stay >= 0."""
     return _pass22(jnp.asarray(a) + jnp.asarray(_SUB_BIAS)[:, None] - jnp.asarray(b))
 
 
+@_op
 def neg(a: jnp.ndarray) -> jnp.ndarray:
     return _pass22(jnp.asarray(_SUB_BIAS)[:, None] - jnp.asarray(a))
 
 
+@_op
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Field multiply. Inputs REDUCED (limbs < 7700); output REDUCED.
 
@@ -180,6 +226,7 @@ def _balanced_sum(terms: list) -> jnp.ndarray:
     return terms[0]
 
 
+@_op
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
     """Dedicated squaring: ~half the limb products of a general mul.
 

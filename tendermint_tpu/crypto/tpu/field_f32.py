@@ -37,6 +37,9 @@ Bounds discipline (mirrors field.py; tests drive all-max patterns):
 The top-limb fold uses 2^256 ≡ 38 (mod p): a carry c out of limb 31
 re-enters as 38*c split across limbs 0 and 1 so no intermediate
 exceeds the exactness bound.
+
+`add`, `sub`, `neg`, `mul` and `sqr` come in field.py's two forms
+(`as_calls`): inline, or calls of jitted functions traced once a shape.
 """
 
 from __future__ import annotations
@@ -44,6 +47,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .field import _op, as_calls  # noqa: F401  (as_calls: fe.as_calls)
 
 P = 2**255 - 19
 NLIMB = 32
@@ -114,20 +119,24 @@ def _pass32(x: jnp.ndarray) -> jnp.ndarray:
     return _fold_top(r, c[-1])
 
 
+@_op
 def add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """REDUCED + REDUCED -> REDUCED."""
     return _pass32(jnp.asarray(a) + jnp.asarray(b))
 
 
+@_op
 def sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """REDUCED - REDUCED -> REDUCED (signed limbs; no bias needed)."""
     return _pass32(jnp.asarray(a) - jnp.asarray(b))
 
 
+@_op
 def neg(a: jnp.ndarray) -> jnp.ndarray:
     return _pass32(-jnp.asarray(a))
 
 
+@_op
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Field multiply. Inputs REDUCED (|limb| <= 680); output REDUCED.
 
@@ -156,6 +165,7 @@ def _balanced_sum(terms: list) -> jnp.ndarray:
     return terms[0]
 
 
+@_op
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
     """Dedicated squaring: ~half the limb products of a general mul.
 

@@ -60,7 +60,7 @@ from . import backend as _backend
 
 # Workload tags (closed set; the lint and docs table enumerate it).
 WORKLOADS = ("consensus", "speculation", "admission", "light",
-             "fastsync", "probe", "bench")
+             "fastsync", "evidence", "probe", "bench")
 
 DEFAULT_CAPACITY = 512
 

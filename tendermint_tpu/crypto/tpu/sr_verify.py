@@ -12,6 +12,12 @@ via per-lane 4-bit Straus windows, [s]B via the shared fixed-base comb
 identity rows and are simply not iterated here, k and s both < L <
 2^253 = 64 nibbles).
 
+The jitted program is `sr25519_kernel` (`jit_sr25519_kernel` to the
+profiler: a name the general ed25519 program does not share) and names
+its phases with `jax.named_scope`, as the ed25519 programs do (PHASES).
+The host's Merlin transcripts are the span `crypto.sr_merlin` inside
+the launch's pack stage.
+
 Semantics match sr25519_ref.verify bit-for-bit (tested on schnorrkel-
 anchored keys, torsioned/corrupted lanes, non-canonical encodings).
 Reference surface: crypto/sr25519/pubkey.go:34-61 (BASELINE config #4:
@@ -24,6 +30,7 @@ import functools
 
 import numpy as np
 
+from ...libs import tracing
 from .. import ed25519_ref as ref
 from . import ledger as _ledger
 from . import verify as tv
@@ -31,6 +38,12 @@ from . import verify as tv
 _L = ref.L
 _P = ref.P
 _WINDOWS = 64  # k, s < L < 2^253: 64 nibbles each
+
+# The kernel's phases, in order (jax.named_scope): both ristretto
+# decodes, the 16-entry window table of -A, the fused 64-window loop
+# with its last add, the ristretto equality with the verdict.
+PHASES = ("sr25519.decode", "sr25519.table", "sr25519.msm",
+          "sr25519.compare")
 
 _P_WORDS = np.frombuffer(_P.to_bytes(32, "little"), np.uint64)
 _L_WORDS = np.frombuffer(_L.to_bytes(32, "little"), np.uint64)
@@ -56,22 +69,31 @@ def _kernel():
     from . import ristretto as rs
     from .fieldsel import F as fe
 
+    # the field operations as calls: a shape's first launch is ~5 s of
+    # tracing and lowering for ~35, and the program runs as fast or
+    # faster at every bucket measured (fe.as_calls)
     @jax.jit
-    def kernel(ab, rb, kdig, sdig, a_pre, r_pre, s_ok, btab):
+    @fe.as_calls()
+    def sr25519_kernel(ab, rb, kdig, sdig, a_pre, r_pre, s_ok, btab):
         n = ab.shape[0]
-        a_limbs = fe.limbs_from_bytes(ab.astype(jnp.int32).T)
-        r_limbs = fe.limbs_from_bytes(rb.astype(jnp.int32).T)
-        # Fused 2N ristretto decode (one sqrt-ratio dispatch, like the
-        # ed25519 kernel's fused A/R decompression).
-        limbs2 = jnp.concatenate([a_limbs, r_limbs], axis=1)
-        pre2 = jnp.concatenate([jnp.asarray(a_pre), jnp.asarray(r_pre)])
-        p2, ok2 = rs.decode(limbs2, pre2)
-        A = ed.Point(p2.x[:, :n], p2.y[:, :n], p2.z[:, :n], p2.t[:, :n])
-        R = ed.Point(p2.x[:, n:], p2.y[:, n:], p2.z[:, n:], p2.t[:, n:])
-        a_ok, r_ok = ok2[:n], ok2[n:]
+        with jax.named_scope("sr25519.decode"):
+            a_limbs = fe.limbs_from_bytes(ab.astype(jnp.int32).T)
+            r_limbs = fe.limbs_from_bytes(rb.astype(jnp.int32).T)
+            # Fused 2N ristretto decode (one sqrt-ratio dispatch, like
+            # the ed25519 kernel's fused A/R decompression).
+            limbs2 = jnp.concatenate([a_limbs, r_limbs], axis=1)
+            pre2 = jnp.concatenate(
+                [jnp.asarray(a_pre), jnp.asarray(r_pre)])
+            p2, ok2 = rs.decode(limbs2, pre2)
+            A = ed.Point(p2.x[:, :n], p2.y[:, :n], p2.z[:, :n],
+                         p2.t[:, :n])
+            R = ed.Point(p2.x[:, n:], p2.y[:, n:], p2.z[:, n:],
+                         p2.t[:, n:])
+            a_ok, r_ok = ok2[:n], ok2[n:]
 
-        neg_a = ed.neg(A)
-        tbl = ed.build_window_table(neg_a, 16)
+        with jax.named_scope("sr25519.table"):
+            neg_a = ed.neg(A)
+            tbl = ed.build_window_table(neg_a, 16)
 
         def body(w, accs):
             acc_a, acc_b = accs
@@ -87,13 +109,15 @@ def _kernel():
             acc_b = ed.add_z1(acc_b, qx, qy, qt)
             return (acc_a, acc_b)
 
-        acc_a, acc_b = jax.lax.fori_loop(
-            0, _WINDOWS, body, (ed.identity(n), ed.identity(n))
-        )
-        v = ed.add(acc_a, acc_b)
-        return rs.equal(v, R) & a_ok & r_ok & jnp.asarray(s_ok)
+        with jax.named_scope("sr25519.msm"):
+            acc_a, acc_b = jax.lax.fori_loop(
+                0, _WINDOWS, body, (ed.identity(n), ed.identity(n))
+            )
+            v = ed.add(acc_a, acc_b)
+        with jax.named_scope("sr25519.compare"):
+            return rs.equal(v, R) & a_ok & r_ok & jnp.asarray(s_ok)
 
-    return kernel
+    return sr25519_kernel
 
 
 def _nibbles(ints, n: int) -> np.ndarray:
@@ -160,7 +184,10 @@ def verify_batch_sr(pubs, msgs, sigs, ctx: bytes = b"",
             # Merlin challenges (SIMD host; transcript sees the WIRE
             # bytes of pk and R, marker included on neither — R is
             # sig[:32] as-is).
-            ks = sr25519_challenges(a_raw, list(msgs), r_raw, ctx)
+            with tracing.TRACER.span(
+                    tracing.CRYPTO_SR_MERLIN, lanes=n,
+                    groups=len(set(map(len, msgs)))):
+                ks = sr25519_challenges(a_raw, list(msgs), r_raw, ctx)
             kdig = _nibbles(ks, n)
             s_ints = [int.from_bytes(s_raw[i].tobytes(), "little")
                       for i in range(n)]

@@ -1,12 +1,23 @@
 """Evidence verification (reference: evidence/verify.go).
 
-DuplicateVoteEvidence: both conflicting votes' signatures verify as
-one device batch (reference does two sequential verifies,
-verify.go:165-225)."""
+The reference verifies one evidence and one signature at a time
+(VerifyDuplicateVote's two checks after one another, verify.go:165-225;
+CheckEvidence's loop over a block's list, pool.go:181). Here the work
+is cut in two: `prepare` is every check of Verify BEFORE a signature
+(the block time and the validators of the evidence's height, expiry,
+and for a DuplicateVoteEvidence all of VerifyDuplicateVote but its two
+signatures), and `signature_errors` verifies the two lanes of any
+number of prepared DuplicateVoteEvidence in ONE call of their
+validator set's `_batch_verify_lanes` (ledger tag `evidence`): ed25519
+lanes of a large set ride its resident tables, sr25519 lanes one
+launch of their kernel, a couple of lanes stay on the host as
+BatchVerifier decides. `verify_evidence` / `verify_duplicate_vote`
+(gossip, RPC, `Pool.add_evidence`) are the one-evidence case;
+`Pool.check_evidence` hands in a block's whole list. The errors, and
+which evidence raises first, are those of the one-at-a-time loop."""
 
 from __future__ import annotations
 
-from ..crypto.batch import BatchVerifier
 from ..types.evidence import DuplicateVoteEvidence, Evidence
 
 
@@ -14,11 +25,47 @@ class EvidenceError(Exception):
     pass
 
 
-def verify_evidence(ev: Evidence, state, state_store, block_store) -> None:
-    """Full verification against committed chain state
-    (reference: evidence/verify.go:25 Verify + prepare checks)."""
+class ChainLoads:
+    """The committed chain as evidence verification reads it: a
+    height's block time and validator set, each loaded once however
+    many pieces of evidence name the height."""
+
+    def __init__(self, state_store, block_store):
+        self.state_store = state_store
+        self.block_store = block_store
+        self._times: dict[int, int] = {}
+        self._vals: dict[int, object] = {}
+
+    def block_time(self, height: int) -> int:
+        when = self._times.get(height)
+        if when is None:
+            meta = self.block_store.load_block_meta(height)
+            if meta is None:
+                raise EvidenceError(
+                    f"no committed block at evidence height {height}")
+            when = self._times[height] = meta.header.time
+        return when
+
+    def validators(self, height: int):
+        vals = self._vals.get(height)
+        if vals is None:
+            vals = self.state_store.load_validators(height)
+            if vals is None:
+                raise EvidenceError(
+                    f"no validator set at height {height}")
+            self._vals[height] = vals
+        return vals
+
+
+def prepare(ev: Evidence, state, loads: ChainLoads):
+    """Every check of Verify that comes before a signature
+    (reference: evidence/verify.go:25 Verify + prepare checks). A
+    LightClientAttackEvidence is verified whole, here. For a
+    DuplicateVoteEvidence returns (validator set of its height, the
+    validator's index in it): its two signatures are still to verify
+    (signature_errors); None for any other evidence."""
     height = ev.height()
-    header_time = _committed_block_time(block_store, height)
+    header_time = loads.block_time(height)
 
     # expiry relative to consensus params (reference verify.go:33-47:
     # expired only when BOTH height- and time-age are exceeded)
@@ -31,34 +78,31 @@ def verify_evidence(ev: Evidence, state, state_store, block_store) -> None:
             f"({age_blocks} blocks / {age_ns / 1e9:.0f}s)")
 
     if isinstance(ev, DuplicateVoteEvidence):
-        vals = state_store.load_validators(height)
-        if vals is None:
-            raise EvidenceError(f"no validator set at height {height}")
-        verify_duplicate_vote(ev, state.chain_id, vals, header_time)
-        return
+        vals = loads.validators(height)
+        return vals, check_duplicate_vote(ev, vals, header_time)
     from ..light.types import LightClientAttackEvidence
 
     if isinstance(ev, LightClientAttackEvidence):
-        common_vals = state_store.load_validators(height)
-        if common_vals is None:
-            raise EvidenceError(f"no validator set at height {height}")
         verify_light_client_attack(
-            ev, state.chain_id, common_vals, header_time, state_store,
-            block_store)
-        return
+            ev, state.chain_id, loads.validators(height), header_time,
+            loads.state_store, loads.block_store)
+        return None
     raise EvidenceError(f"unknown evidence type {type(ev).__name__}")
 
 
-def _committed_block_time(block_store, height: int) -> int:
-    meta = block_store.load_block_meta(height)
-    if meta is None:
-        raise EvidenceError(f"no committed block at evidence height {height}")
-    return meta.header.time
+def verify_evidence(ev: Evidence, state, state_store, block_store) -> None:
+    """Full verification of one evidence against committed chain
+    state."""
+    lanes = prepare(ev, state, ChainLoads(state_store, block_store))
+    if lanes is not None:
+        vals, index = lanes
+        _raise_first(signature_errors(state.chain_id, vals, [(ev, index)]))
 
 
-def verify_duplicate_vote(ev: DuplicateVoteEvidence, chain_id: str,
-                          vals, header_time: int) -> None:
-    """reference: evidence/verify.go:165 VerifyDuplicateVote."""
+def check_duplicate_vote(ev: DuplicateVoteEvidence, vals,
+                         header_time: int) -> int:
+    """VerifyDuplicateVote (reference: evidence/verify.go:165) up to
+    its signatures; returns the validator's index in `vals`."""
     a, b = ev.vote_a, ev.vote_b
 
     if a.height != b.height or a.round != b.round or a.type != b.type:
@@ -71,7 +115,7 @@ def verify_duplicate_vote(ev: DuplicateVoteEvidence, chain_id: str,
     if not _block_key(a.block_id) < _block_key(b.block_id):
         raise EvidenceError("votes not in canonical order")
 
-    _, val = vals.get_by_address(a.validator_address)
+    index, val = vals.get_by_address(a.validator_address)
     if val is None:
         raise EvidenceError(
             f"validator {a.validator_address.hex()} not in set at "
@@ -87,14 +131,51 @@ def verify_duplicate_vote(ev: DuplicateVoteEvidence, chain_id: str,
     if ev.timestamp != header_time:
         raise EvidenceError(
             f"evidence time {ev.timestamp} != block time {header_time}")
+    return index
 
-    bv = BatchVerifier()
-    bv.add(val.pub_key, a.sign_bytes(chain_id), a.signature)
-    bv.add(val.pub_key, b.sign_bytes(chain_id), b.signature)
-    ok, verdicts = bv.verify()
-    if not ok:
-        which = "A" if not verdicts[0] else "B"
-        raise EvidenceError(f"invalid signature on vote {which}")
+
+def signature_errors(chain_id: str, vals,
+                     items: list[tuple[DuplicateVoteEvidence, int]]
+                     ) -> list[EvidenceError | None]:
+    """The two signatures of each (evidence, its validator's index in
+    `vals`), all in one batch through the set's verify ladder; per
+    evidence the error VerifyDuplicateVote would raise (vote A is
+    looked at before vote B), or None."""
+    from ..crypto.tpu import ledger as tpu_ledger
+    from ..types.sign_batch import VoteSignBatch
+
+    votes = [v for ev, _ in items for v in (ev.vote_a, ev.vote_b)]
+    lanes = [i for _, i in items for _ in (0, 1)]
+    sigs = [v.signature for v in votes]
+
+    def picked(pick):
+        return votes if pick is None else [votes[i] for i in pick]
+
+    msgs = vals.structured_or_bytes(
+        lanes,
+        lambda pick: VoteSignBatch(chain_id, picked(pick)),
+        lambda pick: [v.sign_bytes(chain_id) for v in picked(pick)])
+    with tpu_ledger.workload("evidence"):
+        ok, verdicts = vals._batch_verify_lanes(lanes, msgs, sigs)
+    if ok:
+        return [None] * len(items)
+    return [None if verdicts[2 * k] and verdicts[2 * k + 1]
+            else EvidenceError("invalid signature on vote "
+                               + ("B" if verdicts[2 * k] else "A"))
+            for k in range(len(items))]
+
+
+def _raise_first(errors) -> None:
+    for err in errors:
+        if err is not None:
+            raise err
+
+
+def verify_duplicate_vote(ev: DuplicateVoteEvidence, chain_id: str,
+                          vals, header_time: int) -> None:
+    """reference: evidence/verify.go:165 VerifyDuplicateVote."""
+    index = check_duplicate_vote(ev, vals, header_time)
+    _raise_first(signature_errors(chain_id, vals, [(ev, index)]))
 
 
 def verify_light_client_attack(ev, chain_id: str, common_vals,

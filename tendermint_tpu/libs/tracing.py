@@ -165,6 +165,10 @@ CRYPTO_HOST_VERIFY = register_kind("crypto.host_verify")
 # launch site held up behind another thread's build of the same set.
 CRYPTO_TABLE_BUILD = register_kind("crypto.table_build")
 CRYPTO_TABLE_WAIT = register_kind("crypto.table_wait")
+# The host's Merlin transcripts of an sr25519 launch
+# (crypto/tpu/sr_verify.py, inside its pack stage): attrs `lanes` and
+# `groups` (distinct message lengths, one SIMD transcript run each).
+CRYPTO_SR_MERLIN = register_kind("crypto.sr_merlin")
 
 # Verify-ahead pipeline (consensus/speculation.py + crypto/tpu/
 # resident.py): speculate = an ahead-of-commit verification launch,
@@ -181,9 +185,25 @@ VERIFY_COLLECT = register_kind("verify.collect")
 VERIFY_SIGN_BATCH = register_kind("verify.sign_batch")
 VERIFY_TABLES = register_kind("verify.tables")
 VERIFY_WINDOW = register_kind("verify.window")
+# A batch of a set that is not all ed25519, partitioned by key type
+# (types/validator_set.py _lane_split): attrs `ed25519`, `sr25519`,
+# `other` = lanes of each. Never opened for an all-ed25519 set.
+VERIFY_LANE_SPLIT = register_kind("verify.lane_split")
 # Fast sync's verify-apply loop (blockchain/verify_ahead.py
 # sync_window) stopping inside a window because the set moved.
 SYNC_WINDOW_CUT = register_kind("sync.window_cut")
+
+# A block's evidence (evidence/__init__.py Pool): `evidence.check` is
+# one check_evidence call that verifies anything (attrs `evidence`,
+# `heights`, `sets`, `lanes`), `evidence.collect` its host part (the
+# lookups, one load of the validators and the block time a height, the
+# checks before the signatures; the sign bytes are built after it,
+# outside the span, in evidence/verify.py signature_errors),
+# `evidence.update` the marking of a block's evidence as committed
+# (attr `committed`).
+EVIDENCE_CHECK = register_kind("evidence.check")
+EVIDENCE_COLLECT = register_kind("evidence.collect")
+EVIDENCE_UPDATE = register_kind("evidence.update")
 
 # The verify planes' micro-batcher (crypto/collector.py), one pair per
 # batch: mempool/admission.py's and light/serving.py's.

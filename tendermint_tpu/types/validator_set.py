@@ -5,11 +5,14 @@ The verify_commit* family is the framework's north-star surface: where
 the reference loops `PubKey.VerifySignature` per signature
 (validator_set.go:683-705,720-762,776-824), every variant here collects
 its exact verification set first and executes it as ONE device batch
-with per-lane verdicts. Large all-ed25519 sets additionally route
-through crypto/tpu/expanded.py: per-validator comb tables cached on
-device across heights (the valset persists block to block), which
+with per-lane verdicts. The ed25519 keys of a large set additionally
+route through crypto/tpu/expanded.py: per-validator comb tables cached
+on device across heights (the valset persists block to block), which
 removes pubkey decompression and all scalar-mul doublings from the
-per-commit critical path."""
+per-commit critical path. A set that holds other key types beside them
+is split by key type at the verify site (_batch_verify_lanes): its
+ed25519 lanes ride the tables of its ed25519 keys, its sr25519 lanes
+one launch of their own kernel, verdicts back in the caller's order."""
 
 from __future__ import annotations
 
@@ -34,6 +37,9 @@ PRIORITY_WINDOW_SIZE_FACTOR = 2
 # batch kernel / host path wins because the table build + HBM
 # residency don't amortize.
 _EXPAND_MIN = 128
+# key types the lane split tells apart (_SetColumns.kind_code)
+_KIND_ED25519, _KIND_SR25519, _KIND_OTHER = 0, 1, 2
+_KIND_CODES = {"ed25519": _KIND_ED25519, "sr25519": _KIND_SR25519}
 
 
 class VerificationError(Exception):
@@ -71,8 +77,8 @@ class CommitVerifyPlan:
         plans may come from different validator sets, so the shared
         launch uses the general per-lane-key kernel, not this set's
         expanded tables)."""
-        msgs = self.msgs.materialize() \
-            if _is_structured(self.msgs) else self.msgs
+        msgs = self.msgs if isinstance(self.msgs, list) \
+            else self.msgs.materialize()
         return [(self.valset.validators[i].pub_key, m, s)
                 for i, m, s in zip(self.lanes, msgs, self.sigs)]
 
@@ -98,7 +104,39 @@ class CommitVerifyPlan:
 
 
 def _is_structured(msgs) -> bool:
+    """Do these sign bytes — of a SplitSignBytes, its ed25519 lanes' —
+    ride in structured form?"""
+    if isinstance(msgs, SplitSignBytes):
+        msgs = msgs.ed_msgs
     return isinstance(msgs, StructuredSignBytes)
+
+
+class SplitSignBytes:
+    """Sign bytes for lanes of more than one key type
+    (structured_or_bytes, for a set that is not all ed25519): the
+    ed25519 lanes' — structured where the tables will take them — and
+    the other lanes' in full, each beside its positions in the
+    caller's lane order."""
+
+    __slots__ = ("ed_pos", "ed_msgs", "rest_pos", "rest_msgs")
+
+    def __init__(self, ed_pos: np.ndarray, ed_msgs,
+                 rest_pos: np.ndarray, rest_msgs: list[bytes]):
+        self.ed_pos = ed_pos      # positions among the lanes, ascending
+        self.ed_msgs = ed_msgs    # list[bytes] | StructuredSignBytes
+        self.rest_pos = rest_pos
+        self.rest_msgs = rest_msgs
+
+    def materialize(self) -> list[bytes]:
+        """Full sign bytes of every lane, in the caller's order."""
+        ed = self.ed_msgs if isinstance(self.ed_msgs, list) \
+            else self.ed_msgs.materialize()
+        out = [b""] * (len(ed) + len(self.rest_msgs))
+        for i, m in zip(self.ed_pos.tolist(), ed):
+            out[i] = m
+        for i, m in zip(self.rest_pos.tolist(), self.rest_msgs):
+            out[i] = m
+        return out
 
 
 @dataclasses.dataclass
@@ -106,16 +144,22 @@ class _SetColumns:
     """What the verify sites need of a validator set, as columns:
     built once per set (ValidatorSet._columns), never per commit.
     `src` is the validators list they were read from — the validity
-    key, as for _addr_index. The digest is the key of
-    crypto/tpu/expanded.py's table cache and is hashed on first use;
-    the tables themselves stay in that cache, whose LRU alone decides
-    when they leave the chip."""
+    key, as for _addr_index. `ed_keys` are the set's ed25519 keys in
+    set order: what crypto/tpu/expanded.py's tables are built over and
+    what `digest`, the key of its table cache, names (hashed on first
+    use; the tables themselves stay in that cache, whose LRU alone
+    decides when they leave the chip). For a set that is all ed25519
+    they are `pubkeys` itself, a validator's row is its index, and
+    `ed_row` / `kind_code` are None: nothing is looked up per lane."""
 
     src: list
     addresses: list[bytes]   # per validator, for the address check
     power: np.ndarray        # (n,) voting powers; see _power_column
     all_ed25519: bool
     pubkeys: list[bytes]
+    ed_keys: list[bytes]
+    ed_row: np.ndarray | None = None     # (n,) row in ed_keys; -1: none
+    kind_code: np.ndarray | None = None  # (n,) _KIND_* of each key
     digest: bytes | None = None
     membership: bytes | None = None  # see ValidatorSet.membership_digest
 
@@ -189,13 +233,24 @@ class ValidatorSet:
         cols = self._held_columns()
         if cols is None:
             vals = self.validators
-            cols = self._cols = _SetColumns(
+            pubkeys = [v.pub_key.bytes() for v in vals]
+            code = np.fromiter(
+                (_KIND_CODES.get(v.pub_key.type_name, _KIND_OTHER)
+                 for v in vals), np.uint8, len(vals))
+            is_ed = code == _KIND_ED25519
+            cols = _SetColumns(
                 src=vals,
                 addresses=[v.address for v in vals],
                 power=_power_column(vals),
-                all_ed25519=all(v.pub_key.type_name == "ed25519"
-                                for v in vals),
-                pubkeys=[v.pub_key.bytes() for v in vals])
+                all_ed25519=bool(is_ed.all()),
+                pubkeys=pubkeys, ed_keys=pubkeys)
+            if not cols.all_ed25519:
+                cols.ed_keys = [pubkeys[i] for i in np.flatnonzero(is_ed)]
+                cols.ed_row = np.where(is_ed, np.cumsum(is_ed) - 1, -1)
+                cols.kind_code = code
+            # held only once whole: the window's thread and the apply
+            # loop read one set's columns side by side
+            self._cols = cols
         return cols
 
     def membership_digest(self) -> bytes:
@@ -363,8 +418,16 @@ class ValidatorSet:
 
     def _use_expanded(self, lanes) -> bool:
         """Will _batch_verify_lanes take the expanded device path for
-        this many lanes? (Its tables hold every key of the set, so the
-        set, not the lanes, has to be all ed25519.)"""
+        the ed25519 lanes among `lanes`? The question is asked of the
+        set's ed25519 part: enough such lanes in the batch, and the
+        ed25519 keys (what the tables hold) within the backend's cap."""
+        cols = self._columns()
+        if cols.all_ed25519:
+            return self._tables_take(cols, len(lanes))
+        rows = cols.ed_row[np.asarray(lanes, np.intp)]
+        return self._tables_take(cols, int(np.count_nonzero(rows >= 0)))
+
+    def _tables_take(self, cols: _SetColumns, ed_lanes: int) -> bool:
         from ..crypto import batch as _batch
         from ..crypto.tpu import verify as tv
 
@@ -373,7 +436,7 @@ class ValidatorSet:
         # window at 10k validators. The valset-size cap is
         # backend-dependent (expanded.max_keys: HBM budget on chips,
         # one build chunk on the CPU backend where tables buy nothing).
-        if not (_EXPAND_MIN <= len(lanes) <= tv._MAX_BATCH
+        if not (_EXPAND_MIN <= ed_lanes <= tv._MAX_BATCH
                 and not _batch.host_forced()
                 and _batch.device_available("ed25519")):
             return False
@@ -388,35 +451,66 @@ class ValidatorSet:
             _batch.mark_device_failed("ed25519")
             _batch.logger.exception("backend probe failed; host path")
             return False
-        return len(self.validators) <= cap and self._columns().all_ed25519
+        return len(cols.ed_keys) <= cap
 
     def warm_device_tables(self):
         """Kick a background build of this set's expanded device
-        tables (crypto/tpu/expanded.py warm_async) if commit verifies
-        for it would use them. Called when a validator-set change is
-        adopted so the first commit under the new set doesn't pay the
-        table build inline. Returns the thread or None."""
+        tables (crypto/tpu/expanded.py warm_async) — over its ed25519
+        keys — if commit verifies for it would use them. Called when a
+        validator-set change is adopted so the first commit under the
+        new set doesn't pay the table build inline. Returns the thread
+        or None."""
         if not self._use_expanded(range(len(self.validators))):
             return None
         from ..crypto.tpu import expanded
 
-        return expanded.warm_async(self._columns().pubkeys)
+        return expanded.warm_async(self._columns().ed_keys)
+
+    def _lane_split(self, cols: _SetColumns, lanes):
+        """Positions among `lanes` (validator indices) of the ed25519
+        lanes and of the others, each ascending. Only a set that is
+        not all ed25519 is ever split."""
+        with TRACER.span(tracing.VERIFY_LANE_SPLIT) as span:
+            code = cols.kind_code[np.asarray(lanes, np.intp)]
+            ed_pos = np.flatnonzero(code == _KIND_ED25519)
+            rest_pos = np.flatnonzero(code != _KIND_ED25519)
+            sr = int(np.count_nonzero(code == _KIND_SR25519))
+            span.set_attr("ed25519", len(ed_pos))
+            span.set_attr("sr25519", sr)
+            span.set_attr("other", len(rest_pos) - sr)
+        return ed_pos, rest_pos
 
     def structured_or_bytes(self, lanes: list[int], build, materialize):
         """THE structured-vs-full-bytes policy, one copy for every
-        call site (commit verify, fast-sync windows, vote scheduler):
-        build() -> a types.sign_batch.StructuredSignBytes when the
+        call site (commit verify, fast-sync windows, vote scheduler,
+        a block's evidence). build(pick) and materialize(pick) give
+        the sign bytes of the lanes at positions `pick` (an ascending
+        index array; None: every lane): build -> a
+        types.sign_batch.StructuredSignBytes, asked for when the
         expanded device path will consume it; ValueError from build
         (hostile timestamps, too many template groups, oversized sign
         bytes) means the input doesn't fit the vectorized layout —
         fall back to materialize()'s full bytes SILENTLY, because
-        that's an input property, not a bug."""
-        if self._use_expanded(lanes):
+        that's an input property, not a bug. For a set that is not all
+        ed25519 the policy is applied to its ed25519 lanes, the others
+        get full bytes, and both come back as a SplitSignBytes."""
+        cols = self._columns()
+        if cols.all_ed25519:
+            return self._ed25519_msgs(cols, len(lanes), None, build,
+                                      materialize)
+        ed_pos, rest_pos = self._lane_split(cols, lanes)
+        return SplitSignBytes(
+            ed_pos, self._ed25519_msgs(cols, len(ed_pos), ed_pos, build,
+                                       materialize),
+            rest_pos, materialize(rest_pos) if len(rest_pos) else [])
+
+    def _ed25519_msgs(self, cols, n: int, pick, build, materialize):
+        if self._tables_take(cols, n):
             try:
-                return build()
+                return build(pick)
             except ValueError:
                 pass
-        return materialize()
+        return materialize(pick) if n else []
 
     def _commit_msgs(self, chain_id: str, commit, slots, lanes,
                      columns: CommitColumns | None = None):
@@ -426,52 +520,71 @@ class ValidatorSet:
         caller has read them."""
         if not len(slots):
             return []
+
+        def of(pick):
+            return slots if pick is None else np.asarray(slots)[pick]
+
         with TRACER.span(tracing.VERIFY_SIGN_BATCH, lanes=len(slots)):
             return self.structured_or_bytes(
                 lanes,
-                lambda: CommitSignBatch(chain_id, commit, slots, columns),
-                lambda: [commit.vote_sign_bytes(chain_id, s)
-                         for s in slots],
+                lambda pick: CommitSignBatch(chain_id, commit, of(pick),
+                                             columns),
+                lambda pick: [commit.vote_sign_bytes(chain_id, s)
+                              for s in of(pick)],
             )
 
     def _batch_verify_lanes(self, lanes: list[int], msgs,
-                            sigs: list[bytes]):
+                            sigs: list[bytes], rows=None):
         """One device batch over (self.validators[lanes[i]], msgs[i],
-        sigs[i]). Large all-ed25519 sets go through the expanded
-        per-validator comb tables (cached on device across heights —
-        see crypto/tpu/expanded.py); everything else through the
-        general BatchVerifier.
+        sigs[i]): the ladder of every verify site. The ed25519 lanes
+        of a large set go through the expanded per-validator comb
+        tables (cached on device across heights — see
+        crypto/tpu/expanded.py); everything else through the general
+        BatchVerifier, which groups by key type (sr25519 lanes: one
+        launch of their kernel). A set that is all ed25519 has nothing
+        to partition: its lanes take the ed25519 rungs below as they
+        come. A set of several key types is split first (_verify_split),
+        and its ed25519 lanes come back here with `rows`, their rows in
+        the tables of the set's ed25519 keys.
 
-        msgs is either a list of sign-byte blobs or a
+        msgs is a list of sign-byte blobs, a
         types.sign_batch.StructuredSignBytes (single-commit batch or a
-        fast-sync window's merged batch): the structured form lets the
+        fast-sync window's merged batch) or, for a set of several key
+        types, a SplitSignBytes: the structured form lets the
         expanded path assemble the bytes ON DEVICE (template +
         per-lane timestamp patch) instead of shipping ~190 B of
         redundant sign bytes per lane; every fallback materializes the
-        identical full bytes."""
+        identical full bytes. Verdicts are in the caller's lane
+        order."""
+        cols = self._columns()
+        if rows is None:
+            if not cols.all_ed25519:
+                return self._verify_split(cols, lanes, msgs, sigs)
+            rows = lanes
+        # The ed25519 rungs: structured -> full bytes on the tables ->
+        # BatchVerifier (general kernel -> host).
         from ..crypto import batch as _batch
 
         structured = _is_structured(msgs)
-        # structured implies _use_expanded held when the batch was
-        # built (_commit_msgs)
-        if structured or self._use_expanded(lanes):
+        # structured implies the tables were to take the batch when it
+        # was built (structured_or_bytes)
+        if structured or self._tables_take(cols, len(lanes)):
             from ..crypto.tpu import expanded
             from ..libs import failpoints
 
             try:
                 failpoints.hit("device.verify")
                 with TRACER.span(tracing.VERIFY_TABLES,
-                                 keys=len(self.validators)) as tspan:
-                    cols = self._columns()
+                                 keys=len(cols.ed_keys)) as tspan:
                     held = cols.digest is not None
                     tspan.set_attr("digest", "held" if held else "hashed")
                     if not held:
-                        cols.digest = expanded.key_digest(cols.pubkeys)
-                    exp = expanded.get_expanded(cols.pubkeys, cols.digest)
+                        cols.digest = expanded.key_digest(cols.ed_keys)
+                    exp = expanded.get_expanded(cols.ed_keys, cols.digest)
                 if structured:
                     try:
                         verdicts = exp.verify_structured(
-                            lanes, msgs, sigs)
+                            rows, msgs, sigs)
                     except ValueError:
                         # structural limit (oversized templates /
                         # sign bytes), NOT a device failure: same
@@ -485,9 +598,9 @@ class ValidatorSet:
                             "batch (%d lanes); using full-bytes form",
                             len(lanes))
                         verdicts = exp.verify(
-                            lanes, msgs.materialize(), sigs)
+                            rows, msgs.materialize(), sigs)
                 else:
-                    verdicts = exp.verify(lanes, msgs, sigs)
+                    verdicts = exp.verify(rows, msgs, sigs)
                 return bool(verdicts.all()), verdicts
             except Exception:
                 # dead device mid-table-build or mid-launch: degrade
@@ -504,6 +617,30 @@ class ValidatorSet:
             bv.add(self.validators[i].pub_key, m, s)
         return bv.verify()
 
+    def _verify_split(self, cols: _SetColumns, lanes, msgs, sigs):
+        """_batch_verify_lanes for a set of several key types: the
+        ed25519 lanes back through the ladder at their rows of the
+        tables, the others in ONE BatchVerifier (sr25519: one launch),
+        verdicts in the caller's lane order."""
+        if isinstance(msgs, SplitSignBytes):
+            split = msgs
+        else:
+            ed_pos, rest_pos = self._lane_split(cols, lanes)
+            split = SplitSignBytes(ed_pos, [msgs[i] for i in ed_pos],
+                                   rest_pos, [msgs[i] for i in rest_pos])
+        verdicts = np.zeros(len(lanes), bool)
+        ed_pos, rest_pos = split.ed_pos.tolist(), split.rest_pos.tolist()
+        if ed_pos:
+            ed_lanes = [lanes[i] for i in ed_pos]
+            _, verdicts[split.ed_pos] = self._batch_verify_lanes(
+                ed_lanes, split.ed_msgs, [sigs[i] for i in ed_pos],
+                rows=cols.ed_row[ed_lanes])
+        if rest_pos:
+            bv = BatchVerifier()
+            for i, m in zip(rest_pos, split.rest_msgs):
+                bv.add(self.validators[lanes[i]].pub_key, m, sigs[i])
+            _, verdicts[split.rest_pos] = bv.verify()
+        return bool(verdicts.all()), verdicts
     def light_selection(self, cols: CommitColumns, need: int):
         """VerifyCommitLight's selection over a commit's columns: the
         for-block slots (an index array) up to and including the first

@@ -177,11 +177,13 @@ def test_table_builder_compiles_for_v5e(one_chip, no_compile_cache):
 
 
 def test_sr25519_kernel_compiles_for_v5e(one_chip, no_compile_cache):
-    """sr_verify._kernel at 1,024 lanes."""
+    """sr_verify._kernel at 4,096 lanes: the launch of a 16-commit
+    window's sr25519 lanes on a 1,000-validator set a third of whose
+    keys are sr25519 (BASELINE.json configs[3] at 1,000 validators)."""
     from tendermint_tpu.crypto.tpu import sr_verify
     from tendermint_tpu.crypto.tpu import verify as tv
 
-    n = 1024
+    n = 4096
     btab = tv.b_comb_tables()[:sr_verify._WINDOWS]
     digits = np.zeros((sr_verify._WINDOWS, n), np.int32)
     compiled = sr_verify._kernel().lower(

@@ -251,3 +251,112 @@ def test_f32_matches_i32_differential():
     mf = field_f32.from_limbs(np.asarray(field_f32.canonical(
         field_f32.mul(af, bf))))
     assert m32 == mf
+
+
+def _calls(jaxpr) -> list[str]:
+    """Names of the jitted functions a jaxpr calls at its top level."""
+    return [e.params["name"] for e in jaxpr.eqns
+            if e.primitive.name in ("pjit", "jit")]
+
+
+def test_ops_are_inline_unless_traced_under_as_calls(fe):
+    """The default is the inlined form every kernel had; under
+    as_calls() each operation is ONE call of its jitted self, traced
+    once a shape, with the same result (PR 35)."""
+    import jax
+
+    a, _ = rand_elems(fe, 4)
+    b, _ = rand_elems(fe, 4)
+
+    def f(x, y):
+        return fe.sub(fe.add(fe.mul(x, y), fe.sqr(x)), fe.neg(y))
+
+    def jaxpr_of(fn, *args):
+        # a function of its own each time: jax keeps a function's
+        # trace by its shapes, whatever form it was traced in
+        return jax.make_jaxpr(lambda *xs: fn(*xs))(*args).jaxpr
+
+    inline = jaxpr_of(f, a, b)
+    assert not {"mul", "sqr", "add", "sub", "neg"} & set(_calls(inline))
+    with fe.as_calls():
+        called = jaxpr_of(f, a, b)
+        got = np.asarray(f(a, b))
+    assert sorted(_calls(called)) == ["add", "mul", "neg", "sqr", "sub"]
+    assert len(called.eqns) == 5 < len(inline.eqns)
+    assert (got == np.asarray(f(a, b))).all()
+    # and off again once the block is left, or when asked with False
+    assert "neg" not in _calls(jaxpr_of(fe.neg, a))
+    with fe.as_calls(False):
+        assert "neg" not in _calls(jaxpr_of(fe.neg, a))
+
+
+def test_sr25519_kernel_takes_the_field_as_calls():
+    """54,034 top-level equations inlined, under 5,000 as calls: what
+    a launch shape's first launch costs in tracing and lowering."""
+    from tendermint_tpu.crypto.tpu import sr_verify
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    n = 128
+    digits = np.zeros((sr_verify._WINDOWS, n), np.int32)
+    jaxpr = sr_verify._kernel().trace(
+        ab=np.zeros((n, 32), np.uint8), rb=np.zeros((n, 32), np.uint8),
+        kdig=digits, sdig=digits, a_pre=np.zeros(n, bool),
+        r_pre=np.zeros(n, bool), s_ok=np.zeros(n, bool),
+        btab=np.asarray(tv.b_comb_tables()[:sr_verify._WINDOWS])
+    ).jaxpr.jaxpr
+    assert len(jaxpr.eqns) < 5000
+    assert {"mul", "sqr"} <= set(_calls(jaxpr))
+
+
+@pytest.mark.parametrize("max_lanes,called", [(None, True), (0, False)],
+                         ids=["small-bucket", "over-the-limit"])
+def test_structured_kernel_takes_calls_up_to_its_lane_limit(
+        monkeypatch, max_lanes, called):
+    """A small structured launch is traced with the field as calls; one
+    over `_CALLS_MAX_LANES` keeps the inlined program the commit of
+    10,000 validators was measured on (its lowered module is the
+    parent's, byte for byte)."""
+    import jax
+
+    from tendermint_tpu.crypto.tpu import expanded as ex
+    from tendermint_tpu.crypto.tpu import verify as tv
+    from tendermint_tpu.types.block import (
+        BlockID, BlockIDFlag, Commit, CommitSig, PartSetHeader)
+    from tendermint_tpu.types.sign_batch import CommitSignBatch
+
+    if max_lanes is not None:
+        monkeypatch.setattr(ex, "_CALLS_MAX_LANES", max_lanes)
+    n, n_keys = 128, 8
+    commit = Commit(
+        height=7, round=0,
+        block_id=BlockID(hash=b"\xab" * 32,
+                         part_set_header=PartSetHeader(4, b"\xcd" * 32)),
+        signatures=[CommitSig(BlockIDFlag.COMMIT, bytes(20),
+                              1_753_928_000_000_000_000 + i, bytes(64))
+                    for i in range(n)])
+    keys = object.__new__(ex.ExpandedKeys)
+    keys.pubkeys = tuple(bytes([i]) * 32 for i in range(n_keys))
+    keys.sharded = False
+    lanes = [i % n_keys for i in range(n)]
+    idx, fields, _, width = keys._prepare_structured(
+        lanes, CommitSignBatch("form", commit, list(range(n))),
+        [bytes(64)] * n)
+
+    def spec(a, rows=None):
+        a = np.asarray(a)
+        return jax.ShapeDtypeStruct(
+            a.shape if rows is None else (rows,) + a.shape[1:],
+            jax.dtypes.canonicalize_dtype(a.dtype))
+
+    btab = tv.b_comb_tables()
+    # a jit of its own: the cached one keeps a shape's first trace
+    jaxpr = ex._skernel.__wrapped__().trace(
+        idx=spec(idx), width=width,
+        akeys=spec(np.zeros((1, 32), np.uint8), n_keys),
+        key_ok=spec(np.zeros(1, bool), n_keys),
+        atab=spec(np.zeros((1, ex._ROW), btab.dtype),
+                  n_keys * ex._WINDOWS * ex._ENTRIES),
+        btab=spec(btab), **{k: spec(v) for k, v in fields.items()}
+    ).jaxpr.jaxpr
+    assert ("mul" in _calls(jaxpr)) is called
+    assert (len(jaxpr.eqns) < 10_000) is called

@@ -32,6 +32,8 @@ NEW_KINDS = (
     "consensus.new_height", "admission.queue_wait", "admission.flush",
     "crypto.table_build", "crypto.table_wait", "sync.window_cut",
     "state.valset_row",
+    "verify.lane_split", "crypto.sr_merlin",
+    "evidence.check", "evidence.collect", "evidence.update",
 )
 APPLY_CHILDREN = [
     "state.validate", "state.exec", "state.save_responses",
@@ -289,6 +291,152 @@ def test_verify_commit_span_tree_and_compile_cache(monkeypatch):
     # the structured launch's record says what count_compile answered
     launches = [r for r in ledger.snapshot() if r["kernel"] == "structured"]
     assert [r["compile_cache"] for r in launches] == ["miss", "hit"]
+
+
+def _mixed_commit(monkeypatch, n_ed=4, n_sr=4, height=3):
+    """A set of both key types, its commit, and fake device programs
+    for both: tables over the ed25519 keys and an sr25519 kernel that
+    accept every lane."""
+    import hashlib
+
+    from tendermint_tpu.crypto.ed25519 import Ed25519PrivKey
+    from tendermint_tpu.crypto.sr25519 import Sr25519PrivKey
+    from tendermint_tpu.crypto.tpu import sr_verify
+    from tendermint_tpu.state import make_genesis_state
+    from tendermint_tpu.types.block import BlockID, PartSetHeader
+    from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
+    from tendermint_tpu.types.priv_validator import MockPV
+    from tendermint_tpu.types.validator_set import ValidatorSet
+
+    pvs = [MockPV((Sr25519PrivKey if i >= n_ed else Ed25519PrivKey)(
+        hashlib.sha256(b"site%d" % i).digest()))
+        for i in range(n_ed + n_sr)]
+    gdoc = GenesisDoc(chain_id="mixed-sites", genesis_time=1,
+                      validators=[GenesisValidator(pv.get_pub_key(), 10)
+                                  for pv in pvs])
+    gdoc.validate_and_complete()
+    state = make_genesis_state(gdoc)
+    vals = state.validators
+    ed_only = ValidatorSet([v for v in vals.validators
+                            if v.pub_key.type_name == "ed25519"])
+    ed_only.validators = [v for v in vals.validators
+                          if v.pub_key.type_name == "ed25519"]
+    _fake_expanded(monkeypatch, ed_only)
+    monkeypatch.setattr(
+        sr_verify, "_kernel",
+        lambda: lambda *, ab, **kw: np.ones(ab.shape[0], bool))
+    bid = BlockID(bytes([height]) * 32,
+                  PartSetHeader(1, bytes([height]) * 32))
+    commit = sign_commit(vals, pvs, state.chain_id, height, 0, bid,
+                         1_700_000_000 * 10**9 + height)
+    return state, pvs, bid, commit
+
+
+def test_lane_split_and_sr_merlin_at_their_sites(monkeypatch):
+    from tendermint_tpu.crypto import batch as cbatch
+
+    cbatch.reset_breakers()
+    state, _, bid, commit = _mixed_commit(monkeypatch)
+    TRACER.clear()
+    state.validators.verify_commit(state.chain_id, bid, 3, commit)
+    recs = TRACER.snapshot()
+    (root,) = [r for r in recs if r[0] == tracing.VERIFY_COMMIT]
+    assert root[6] == {"form": "full", "lanes": 8, "structured": True}
+    assert [k[0] for k in children(recs, root)] == [
+        tracing.VERIFY_COLLECT, tracing.VERIFY_SIGN_BATCH,
+        tracing.VERIFY_TABLES, tracing.CRYPTO_VERIFY, tracing.CRYPTO_BATCH]
+    # the partition is the sign-bytes step's: one a batch, not two
+    (split,) = [r for r in recs if r[0] == tracing.VERIFY_LANE_SPLIT]
+    assert ancestors(recs, split)[0] == tracing.VERIFY_SIGN_BATCH
+    assert split[6] == {"ed25519": 4, "sr25519": 4, "other": 0}
+    (tables,) = [r for r in recs if r[0] == tracing.VERIFY_TABLES]
+    assert tables[6]["keys"] == 4
+    (merlin,) = [r for r in recs if r[0] == tracing.CRYPTO_SR_MERLIN]
+    assert merlin[6] == {"lanes": 4, "groups": 1}
+    assert ancestors(recs, merlin)[:2] == [tracing.CRYPTO_BATCH,
+                                           tracing.VERIFY_COMMIT]
+    assert not [r for r in recs if r[0] == tracing.CRYPTO_HOST_VERIFY]
+
+    # full bytes handed straight to the ladder: split there, once
+    vals = state.validators
+    lanes = list(range(8))
+    TRACER.clear()
+    vals._batch_verify_lanes(
+        lanes, [commit.vote_sign_bytes(state.chain_id, s) for s in lanes],
+        [cs.signature for cs in commit.signatures])
+    assert len([r for r in TRACER.snapshot()
+                if r[0] == tracing.VERIFY_LANE_SPLIT]) == 1
+
+
+def test_all_ed25519_set_opens_no_lane_split(monkeypatch):
+    from tendermint_tpu.crypto import batch as cbatch
+
+    cbatch.reset_breakers()
+    state, bid, commit = _commit()
+    _fake_expanded(monkeypatch, state.validators)
+    TRACER.clear()
+    state.validators.verify_commit(state.chain_id, bid, 3, commit)
+    state.validators.verify_commit_light(state.chain_id, bid, 3, commit)
+    kinds = {r[0] for r in TRACER.snapshot()}
+    assert tracing.VERIFY_LANE_SPLIT not in kinds
+    assert tracing.CRYPTO_SR_MERLIN not in kinds
+
+
+def test_evidence_spans_at_their_sites(monkeypatch):
+    from types import SimpleNamespace
+
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.evidence import Pool
+    from tendermint_tpu.libs.db import MemDB
+    from tendermint_tpu.state.store import Store
+    from tendermint_tpu.types.block import BlockID, PartSetHeader
+    from tendermint_tpu.types.evidence import DuplicateVoteEvidence
+    from tendermint_tpu.types.vote import Vote, VoteType
+
+    cbatch.reset_breakers()
+    state, pvs, _, _ = _mixed_commit(monkeypatch)
+    vals = state.validators
+    store = Store(MemDB())
+    store.save_validator_set(1, vals)
+    state.last_block_height, state.last_block_time = 1, 50
+    store.save(state)
+    blocks = SimpleNamespace(load_block_meta=lambda h: SimpleNamespace(
+        header=SimpleNamespace(time=50)))
+    pool = Pool(MemDB(), store, blocks)
+
+    def vote(pv, tag):
+        idx, val = vals.get_by_address(pv.get_pub_key().address())
+        v = Vote(type=VoteType.PREVOTE, height=1, round=0,
+                 block_id=BlockID(bytes([tag]) * 32,
+                                  PartSetHeader(1, bytes([tag]) * 32)),
+                 timestamp=40, validator_address=val.address,
+                 validator_index=idx)
+        pv.sign_vote(state.chain_id, v)
+        return v
+
+    evs = [DuplicateVoteEvidence.from_votes(vote(pv, 1), vote(pv, 2), 50,
+                                            vals) for pv in pvs]
+    TRACER.clear()
+    pool.check_evidence(evs)
+    recs = TRACER.snapshot()
+    (check,) = [r for r in recs if r[0] == tracing.EVIDENCE_CHECK]
+    assert check[6] == {"evidence": 8, "heights": 1, "sets": 1,
+                        "lanes": 16}
+    kids = [k[0] for k in children(recs, check)]
+    assert kids[0] == tracing.EVIDENCE_COLLECT
+    # after the collecting: the sign bytes' split, then the launches
+    assert kids[1] == tracing.VERIFY_LANE_SPLIT
+    assert tracing.CRYPTO_BATCH in kids[2:]
+    (merlin,) = [r for r in recs if r[0] == tracing.CRYPTO_SR_MERLIN]
+    assert tracing.EVIDENCE_CHECK in ancestors(recs, merlin)
+    assert merlin[6]["lanes"] == 8
+
+    TRACER.clear()
+    pool.update(state, evs)
+    pool.update(state, [])            # a block without evidence: none
+    (upd,) = [r for r in TRACER.snapshot()
+              if r[0] == tracing.EVIDENCE_UPDATE]
+    assert upd[6] == {"committed": 8}
 
 
 def test_verify_commit_light_and_trusting_forms(monkeypatch):
@@ -652,6 +800,13 @@ def test_jitted_program_names_are_pinned():
     assert ex._skernel_sharded().__name__ == "skernel"
     assert ex._xkernel().__name__ == "kernel"
     assert tv._kernel().__name__ == "kernel"
+    # the sr25519 program has a name of its own: `jit_kernel` is the
+    # general ed25519 program's, and kernel_ms.* reads that one
+    from tendermint_tpu.crypto.tpu import sr_verify
+
+    assert sr_verify._kernel().__name__ == "sr25519_kernel"
+    assert sr_verify.PHASES == ("sr25519.decode", "sr25519.table",
+                                "sr25519.msm", "sr25519.compare")
 
 
 def test_assemble_is_traced_under_its_phase():
@@ -672,6 +827,25 @@ def test_assemble_is_traced_under_its_phase():
     assert set(tv.PHASES) == {
         "ed25519.assemble", "ed25519.gather", "ed25519.sha512",
         "ed25519.decompress", "ed25519.msm", "ed25519.compare"}
+
+
+def test_sr25519_kernel_is_traced_under_its_phases():
+    import jax
+
+    from tendermint_tpu.crypto.tpu import sr_verify
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    n = 8
+    jaxpr = jax.make_jaxpr(sr_verify._kernel())(
+        np.zeros((n, 32), np.uint8), np.zeros((n, 32), np.uint8),
+        np.zeros((64, n), np.int32), np.zeros((64, n), np.int32),
+        np.ones(n, bool), np.ones(n, bool), np.ones(n, bool),
+        tv.b_comb_tables()[:sr_verify._WINDOWS])
+    (call,) = jaxpr.jaxpr.eqns
+    assert call.params["name"] == "sr25519_kernel"
+    scopes = {str(e.source_info.name_stack).split("/")[0]
+              for e in call.params["jaxpr"].jaxpr.eqns}
+    assert scopes == set(sr_verify.PHASES)   # nothing outside a phase
 
 
 def test_phase_of_instructions_reads_optimized_hlo():

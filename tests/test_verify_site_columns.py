@@ -662,15 +662,50 @@ def test_set_columns_follow_the_set(tables, change, held_after):
     assert tables[-1][1] == _fresh_digest(changed)
 
 
-def test_a_set_with_another_key_type_stays_off_the_tables(tables):
+def test_a_set_with_another_key_type_keeps_its_ed25519_lanes_on_the_tables(
+        tables):
+    """One key of another type no longer takes the set off the tables:
+    they are built over its ed25519 keys, and only that lane leaves."""
     from tendermint_tpu.crypto import secp256k1
 
     vals = _valset([5] * 6, b"mixed")
     other = secp256k1.Secp256k1PrivKey.from_secret(b"k1").pub_key()
     vals.validators = vals.validators + [Validator.new(other, 5)]
     vals._total = None
-    assert not vals._use_expanded(range(3))
-    assert not vals._columns().all_ed25519
+    cols = vals._columns()
+    assert not cols.all_ed25519
+    assert cols.ed_keys == cols.pubkeys[:6] and len(cols.pubkeys) == 7
+    assert cols.ed_row.tolist() == [0, 1, 2, 3, 4, 5, -1]
+    assert vals._use_expanded(range(3))
+    assert not vals._use_expanded([6])      # the secp256k1 lane alone
+    ok, verdicts = vals._batch_verify_lanes(
+        [0, 6, 3], [b"a", b"b", b"c"], [b"\0" * 64] * 3)
+    # the fake tables accept every lane; the host refuses the other
+    assert verdicts.tolist() == [True, False, True] and not ok
+    assert tables[-1] == (cols.ed_keys, ex.key_digest(cols.ed_keys))
+
+
+def test_the_launch_is_called_from_the_ladders_own_frame(tables, monkeypatch):
+    """Every verify site of an all-ed25519 set reaches the tables'
+    launch straight from _batch_verify_lanes, as at PR 34. With a
+    helper of their own between the ladder and the launch the first
+    lowering of every structured shape took 40-42 s on the chip's host
+    where it takes 15 (PERF.md section 6, PR 35: three runs against
+    three), so the ed25519 rungs stay in the ladder's frame."""
+    import sys
+
+    callers = []
+
+    def launch(self, lanes, msgs, sigs):
+        callers.append(sys._getframe(1).f_code.co_name)
+        return np.ones(len(lanes), bool)
+
+    monkeypatch.setattr(FakeTables, "verify_structured", launch)
+    monkeypatch.setattr(FakeTables, "verify", launch)
+    vals = _valset([5] * 12, b"frames")
+    _verify_once(vals)
+    vals._batch_verify_lanes([0, 3], [b"a", b"b"], [b"\0" * 64] * 2)
+    assert callers == ["_batch_verify_lanes"] * 2
 
 
 def test_third_set_evicts_the_first_sets_tables(tables):

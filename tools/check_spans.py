@@ -64,6 +64,8 @@ REQUIRED_KINDS = frozenset({
     "store.save_block", "db.write",
     "admission.queue_wait", "admission.flush", "consensus.new_height",
     "crypto.table_build", "crypto.table_wait", "sync.window_cut",
+    "verify.lane_split", "crypto.sr_merlin",
+    "evidence.check", "evidence.collect", "evidence.update",
     # height forensics reads these two by name: recv spans carry the
     # rehydrated origin tags, send_flush is the wire-side counterpart
     "p2p.recv_msg", "p2p.send_flush",
