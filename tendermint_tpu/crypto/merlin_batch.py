@@ -1,4 +1,12 @@
-"""Lane-vectorized Merlin transcripts (numpy) for sr25519 batches.
+"""Lane-vectorized Merlin transcripts (numpy): the host's way to N
+sr25519 challenges. Since PR 36 no verification launch calls it: the
+device derives the challenge from the transcript's blocks
+(crypto/tpu/sr_verify.py, phase `sr25519.merlin`). What it is for now:
+the benchmark's generator signs its chains with `sr25519_challenges`
+(benchmark/traffic/mixed_evidence_replay.py), and the tests hold the
+device's permutation and challenges to `keccak_f1600_batch` and to it
+(tests/test_sr_merlin_device.py). sr_verify.py takes the permutation's
+constants (`_RC`, `_ROTC_FLAT`, `_PI_SRC`) from here.
 
 The STROBE op schedule (which state bytes are touched, when the
 permutation runs) depends only on byte LENGTHS, never on values — so
@@ -8,7 +16,8 @@ Keccak-f[1600] over (N, 25) uint64 lanes. The sr25519 verify challenge
 appends fixed-length labels, the (variable) message, pk (32) and
 R (32): callers group lanes by message length and get one SIMD
 transcript run per group — ~3 ms/sig of pure-Python Keccak
-(crypto/merlin.py) becomes ~10 µs/sig amortized.
+(crypto/merlin.py) becomes ~10 µs/sig amortized, at a fixed ~25-30 ms a
+length group (~160 small numpy calls a round).
 
 Semantics are pinned against the scalar implementation (which is
 itself pinned against the upstream merlin test vector) in

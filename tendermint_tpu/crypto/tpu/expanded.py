@@ -49,7 +49,7 @@ import numpy as np
 
 from . import ledger as _ledger
 from . import verify as tv
-from ...libs import tracing
+from ...libs import jaxcache, tracing
 
 _WINDOWS = 69  # scalar.DIGITS_K: folded challenge < 2^271
 _ENTRIES = 9   # signed digits: |d| in 0..8
@@ -468,7 +468,7 @@ def assemble_core():
 _CALLS_MAX_LANES = 8192
 
 
-@functools.cache
+@jaxcache.one_program
 def _skernel(wpi: int = WINDOWS_PER_ITER):
     """Structured front-end: assemble each lane's padded message ON
     DEVICE, as the words SHA-512 reads (assemble_core), then verify

@@ -12,8 +12,10 @@ compiling and a second one should not be."""
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
+import threading
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -36,3 +38,25 @@ def configure() -> str:
             "jax_persistent_cache_min_compile_time_secs",
             float(os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"]))
     return path
+
+
+def one_program(factory):
+    """`functools.cache` for a function that builds a jitted program,
+    safe for threads. `functools.cache` alone runs the function again
+    for every thread that asks before the first call has returned, and
+    each gets a jit object of its own: launch sites warmed side by
+    side then compile (or load) their shapes on objects nobody meets
+    again, and the object that stayed in the cache traces, lowers and
+    loads those shapes anew at their next launch, in series (~22 s a
+    shape in a fast-sync warm replay: PERF.md §6, PR 36). Keeps
+    `cache_clear` and `__wrapped__`."""
+    cached = functools.cache(factory)
+    lock = threading.Lock()
+
+    @functools.wraps(factory)
+    def build(*args):
+        with lock:
+            return cached(*args)
+
+    build.cache_clear = cached.cache_clear
+    return build

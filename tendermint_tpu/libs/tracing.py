@@ -165,9 +165,12 @@ CRYPTO_HOST_VERIFY = register_kind("crypto.host_verify")
 # launch site held up behind another thread's build of the same set.
 CRYPTO_TABLE_BUILD = register_kind("crypto.table_build")
 CRYPTO_TABLE_WAIT = register_kind("crypto.table_wait")
-# The host's Merlin transcripts of an sr25519 launch
-# (crypto/tpu/sr_verify.py, inside its pack stage): attrs `lanes` and
-# `groups` (distinct message lengths, one SIMD transcript run each).
+# The host's share of an sr25519 launch's Merlin transcripts
+# (crypto/tpu/sr_verify.py, inside its pack stage): since PR 36 the
+# layout of each lane's blocks, no permutation (the device derives the
+# challenge). Attrs `lanes`, `groups` (distinct message lengths, one
+# template each) and `blocks` (the launch's block dimension: 4 unless
+# a message is over 460 bytes).
 CRYPTO_SR_MERLIN = register_kind("crypto.sr_merlin")
 
 # Verify-ahead pipeline (consensus/speculation.py + crypto/tpu/

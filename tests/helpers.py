@@ -105,3 +105,21 @@ def commit_for(state: State, pvs: list[MockPV], block: Block,
         state.validators, pvs, state.chain_id, block.header.height, 0,
         block_id, block.header.time + 1_000_000_000,
     )
+
+
+def sr_kernel_args(n: int, nblocks: int = 4) -> dict:
+    """Zero arguments of `sr_verify._kernel()` at `n` lanes, by name:
+    what a test that only traces, lowers or compiles the program hands
+    it."""
+    import numpy as np
+
+    from tendermint_tpu.crypto.tpu import sr_verify
+    from tendermint_tpu.crypto.tpu import verify as tv
+
+    return dict(
+        ab=np.zeros((n, 32), np.uint8), rb=np.zeros((n, 32), np.uint8),
+        blocks=np.zeros((n, nblocks * sr_verify._BLOCK_WORDS), np.uint32),
+        counts=np.zeros(n, np.int32), s0=np.zeros((25, 2), np.uint32),
+        sb=np.zeros((n, 32), np.uint8), a_pre=np.zeros(n, bool),
+        r_pre=np.zeros(n, bool), s_ok=np.zeros(n, bool),
+        btab=np.asarray(tv.b_comb_tables()))

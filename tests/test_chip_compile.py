@@ -180,18 +180,11 @@ def test_sr25519_kernel_compiles_for_v5e(one_chip, no_compile_cache):
     """sr_verify._kernel at 4,096 lanes: the launch of a 16-commit
     window's sr25519 lanes on a 1,000-validator set a third of whose
     keys are sr25519 (BASELINE.json configs[3] at 1,000 validators)."""
-    from tendermint_tpu.crypto.tpu import sr_verify
-    from tendermint_tpu.crypto.tpu import verify as tv
+    from helpers import sr_kernel_args
 
-    n = 4096
-    btab = tv.b_comb_tables()[:sr_verify._WINDOWS]
-    digits = np.zeros((sr_verify._WINDOWS, n), np.int32)
-    compiled = sr_verify._kernel().lower(
-        ab=_spec(np.zeros((n, 32), np.uint8), one_chip),
-        rb=_spec(np.zeros((n, 32), np.uint8), one_chip),
-        kdig=_spec(digits, one_chip), sdig=_spec(digits, one_chip),
-        a_pre=_spec(np.zeros(n, bool), one_chip),
-        r_pre=_spec(np.zeros(n, bool), one_chip),
-        s_ok=_spec(np.zeros(n, bool), one_chip),
-        btab=_spec(btab, one_chip)).compile()
+    from tendermint_tpu.crypto.tpu import sr_verify
+
+    compiled = sr_verify._kernel().lower(**{
+        k: _spec(v, one_chip)
+        for k, v in sr_kernel_args(4096).items()}).compile()
     _fits(compiled, "sr25519 kernel")

@@ -463,10 +463,12 @@ def test_sr25519_padded_dispatch_shape(monkeypatch):
     seen = {}
 
     def fake_kernel():
-        def k(*, btab, ab, rb, kdig, sdig, a_pre, r_pre, s_ok):
+        def k(*, btab, s0, ab, rb, blocks, counts, sb, a_pre, r_pre,
+              s_ok):
             seen["bucket"] = ab.shape[0]
-            seen["sharded"] = hasattr(ab, "sharding") and \
-                getattr(ab.sharding, "mesh", None) is not None
+            seen["sharded"] = all(
+                getattr(getattr(v, "sharding", None), "mesh", None)
+                is not None for v in (ab, blocks, counts, sb))
             return np.ones(ab.shape[0], bool)
         return k
 

@@ -293,17 +293,12 @@ def test_ops_are_inline_unless_traced_under_as_calls(fe):
 def test_sr25519_kernel_takes_the_field_as_calls():
     """54,034 top-level equations inlined, under 5,000 as calls: what
     a launch shape's first launch costs in tracing and lowering."""
-    from tendermint_tpu.crypto.tpu import sr_verify
-    from tendermint_tpu.crypto.tpu import verify as tv
+    from helpers import sr_kernel_args
 
-    n = 128
-    digits = np.zeros((sr_verify._WINDOWS, n), np.int32)
+    from tendermint_tpu.crypto.tpu import sr_verify
+
     jaxpr = sr_verify._kernel().trace(
-        ab=np.zeros((n, 32), np.uint8), rb=np.zeros((n, 32), np.uint8),
-        kdig=digits, sdig=digits, a_pre=np.zeros(n, bool),
-        r_pre=np.zeros(n, bool), s_ok=np.zeros(n, bool),
-        btab=np.asarray(tv.b_comb_tables()[:sr_verify._WINDOWS])
-    ).jaxpr.jaxpr
+        **sr_kernel_args(128)).jaxpr.jaxpr
     assert len(jaxpr.eqns) < 5000
     assert {"mul", "sqr"} <= set(_calls(jaxpr))
 

@@ -352,7 +352,7 @@ def test_lane_split_and_sr_merlin_at_their_sites(monkeypatch):
     (tables,) = [r for r in recs if r[0] == tracing.VERIFY_TABLES]
     assert tables[6]["keys"] == 4
     (merlin,) = [r for r in recs if r[0] == tracing.CRYPTO_SR_MERLIN]
-    assert merlin[6] == {"lanes": 4, "groups": 1}
+    assert merlin[6] == {"lanes": 4, "groups": 1, "blocks": 4}
     assert ancestors(recs, merlin)[:2] == [tracing.CRYPTO_BATCH,
                                            tracing.VERIFY_COMMIT]
     assert not [r for r in recs if r[0] == tracing.CRYPTO_HOST_VERIFY]
@@ -805,8 +805,9 @@ def test_jitted_program_names_are_pinned():
     from tendermint_tpu.crypto.tpu import sr_verify
 
     assert sr_verify._kernel().__name__ == "sr25519_kernel"
-    assert sr_verify.PHASES == ("sr25519.decode", "sr25519.table",
-                                "sr25519.msm", "sr25519.compare")
+    assert sr_verify.PHASES == ("sr25519.merlin", "sr25519.decode",
+                                "sr25519.table", "sr25519.msm",
+                                "sr25519.compare")
 
 
 def test_assemble_is_traced_under_its_phase():
@@ -832,15 +833,11 @@ def test_assemble_is_traced_under_its_phase():
 def test_sr25519_kernel_is_traced_under_its_phases():
     import jax
 
-    from tendermint_tpu.crypto.tpu import sr_verify
-    from tendermint_tpu.crypto.tpu import verify as tv
+    from helpers import sr_kernel_args
 
-    n = 8
-    jaxpr = jax.make_jaxpr(sr_verify._kernel())(
-        np.zeros((n, 32), np.uint8), np.zeros((n, 32), np.uint8),
-        np.zeros((64, n), np.int32), np.zeros((64, n), np.int32),
-        np.ones(n, bool), np.ones(n, bool), np.ones(n, bool),
-        tv.b_comb_tables()[:sr_verify._WINDOWS])
+    from tendermint_tpu.crypto.tpu import sr_verify
+
+    jaxpr = jax.make_jaxpr(sr_verify._kernel())(**sr_kernel_args(8))
     (call,) = jaxpr.jaxpr.eqns
     assert call.params["name"] == "sr25519_kernel"
     scopes = {str(e.source_info.name_stack).split("/")[0]
