@@ -20,10 +20,8 @@ may lag the buckets by in-flight observes — relaxed, like counters.
 
 The tracing→metrics bridge at the bottom of this module makes every
 registered span kind (libs/tracing.py) populate a histogram on span
-close: one instrumentation point, two exports. The device-pipeline
-kinds (crypto.pack/dispatch/device_exec/readback) feed the dedicated
-`tpu_*_seconds` histograms; every other kind feeds
-`tracing_span_seconds{kind=...}`.
+close, `tracing_span_seconds{kind=...}`: one instrumentation point,
+two exports.
 """
 
 from __future__ import annotations
@@ -661,21 +659,6 @@ class TPUMetrics:
             "Real lanes / padded bucket size per device batch.", "tpu",
             buckets=(0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8,
                      0.9, 1.0)))
-    pack_seconds: Histogram = field(default_factory=lambda: DEFAULT.histogram(
-        "pack_seconds", "Host byte-packing time per launch "
-        "(bridge-fed from crypto.pack spans).", "tpu"))
-    dispatch_seconds: Histogram = field(
-        default_factory=lambda: DEFAULT.histogram(
-            "dispatch_seconds", "Kernel-launch enqueue time "
-            "(bridge-fed from crypto.dispatch spans).", "tpu"))
-    device_exec_seconds: Histogram = field(
-        default_factory=lambda: DEFAULT.histogram(
-            "device_exec_seconds", "Wait-until-verdicts-ready time "
-            "(bridge-fed from crypto.device_exec spans).", "tpu"))
-    readback_seconds: Histogram = field(
-        default_factory=lambda: DEFAULT.histogram(
-            "readback_seconds", "Device-to-host verdict copy time "
-            "(bridge-fed from crypto.readback spans).", "tpu"))
     host_fallbacks: Counter = field(default_factory=lambda: DEFAULT.counter(
         "host_fallbacks_total",
         "Batches that wanted the device but verified on host.", "tpu"))
@@ -825,8 +808,8 @@ class OverloadMetrics:
 
 @dataclass
 class TracingMetrics:
-    """The generic half of the tracing→metrics bridge: span kinds with
-    no dedicated histogram land here, labelled by kind."""
+    """The tracing→metrics bridge: every span close lands here,
+    labelled by kind."""
     span_seconds: Histogram = field(default_factory=lambda: DEFAULT.histogram(
         "span_seconds", "Span duration by registered kind "
         "(bridge-fed from every span close).", "tracing"))
@@ -1078,33 +1061,18 @@ def delta(before: dict, after: dict) -> dict:
 
 # ------------------------------------------------ tracing→metrics bridge
 
-# Span kinds with a dedicated histogram; resolved lazily so importing
-# this module does not force-construct the tpu family.
-_BRIDGE_DEDICATED = {
-    _tracing.CRYPTO_PACK: lambda: tpu_metrics().pack_seconds,
-    _tracing.CRYPTO_DISPATCH: lambda: tpu_metrics().dispatch_seconds,
-    _tracing.CRYPTO_DEVICE_EXEC: lambda: tpu_metrics().device_exec_seconds,
-    _tracing.CRYPTO_READBACK: lambda: tpu_metrics().readback_seconds,
-}
 _BRIDGE_CACHE: dict[str, object] = {}
 
 
 def span_metrics_sink(kind: str, seconds: float) -> None:
-    """Installed into the global TRACER: every span close observes one
-    histogram — the dedicated tpu stage histogram for the device
-    pipeline kinds, tracing_span_seconds{kind=...} for the rest. The
-    per-close cost is one dict lookup + one bucket scan (the bound
-    handle is cached per kind), inside the tools/check_spans.py
-    per-span overhead budget."""
+    """Installed into the global TRACER: every span close observes
+    tracing_span_seconds{kind=...}. The per-close cost is one dict
+    lookup + one bucket scan (the bound handle is cached per kind),
+    inside the tools/check_spans.py per-span overhead budget."""
     ob = _BRIDGE_CACHE.get(kind)
     if ob is None:
-        mk = _BRIDGE_DEDICATED.get(kind)
-        if mk is not None:
-            h = mk()
-            ob = _BoundHistogram(h.buckets, h._series_for(()))
-        else:
-            ob = tracing_metrics().span_seconds.labels(kind=kind)
-        _BRIDGE_CACHE[kind] = ob
+        ob = _BRIDGE_CACHE[kind] = \
+            tracing_metrics().span_seconds.labels(kind=kind)
     ob.observe(seconds)
 
 

@@ -13,23 +13,43 @@ links over the hot paths:
     consensus.propose / .prevote / .precommit / .commit ...
       wal.fsync                        every write_sync
       store.save_block                 the block store's one batch
+        store.encode_commits           the seen and the last commit as
+                                       protos
+        store.encode_parts             hash, meta, part rows, the
+                                       store's state row (attr parts)
+        store.write                    the batch's INSERTs and, as its
+                                       db.write child, the COMMIT
+                                       (attr rows)
       state.apply_block                ApplyBlock wall time
-        state.validate                 validate_block (worker thread)
-          verify.commit                one commit check, any form
-            verify.collect             basics + the commit's columns:
+        state.validate                 opened on the loop around the
+                                       await of the worker thread
+          validate.block               the whole validate_block call,
+                                       INSIDE the worker: the parent
+                                       less this is the hop, both ways
+            validate.basic             the block's own checks: header
+                                       fields, LastCommit / Data /
+                                       Evidence hashes against it
+            validate.set_hashes        the header against the state:
+                                       two Merkle trees over the sets
+            verify.commit              one commit check, any form
+              verify.collect           basics + the commit's columns:
                                        address check, lanes, tally
-            verify.sign_batch          sign bytes (structured or full)
-            verify.tables              table lookup by the set's held
+              verify.sign_batch        sign bytes (structured or full)
+              verify.tables            table lookup by the set's held
                                        key digest (attr digest =
                                        held | hashed: once a set)
-            crypto.batch               a BatchVerifier.verify call
-            crypto.verify              one device verify
-              crypto.pack              host byte packing (numpy)
-              crypto.dispatch          kernel-launch enqueue
-              crypto.device_exec       wait-until-verdicts-ready
-              crypto.readback          device->host verdict copy
+              crypto.batch             a BatchVerifier.verify call
+              crypto.verify            one device verify
+                crypto.pack            host byte packing (numpy)
+                crypto.dispatch        kernel-launch enqueue
+                crypto.device_exec     wait-until-verdicts-ready
+                crypto.readback        device->host verdict copy
+            validate.median_time       the BFT-time check
         state.exec                     BeginBlock, DeliverTx xN, EndBlock
         state.save_responses           ABCI responses to the state store
+        state.update                   update_state (three set copies)
+                                       and the start of a changed set's
+                                       table warm (attr updates)
         state.app_commit               mempool lock, flush, app Commit,
                                        mempool update
         state.save                     the new State to the state store
@@ -59,10 +79,30 @@ Design constraints (this stays ON in production):
   * Fixed-size ring buffer (collections.deque(maxlen=N), default 16k
     spans): ending a span is one tuple append under the ring's lock;
     overflow evicts the oldest — memory is bounded no matter the load.
-    One ring is one node's: a block costs it ~17 entries more since
-    its verify.* / state.* / store.* children exist (docs/
-    OBSERVABILITY.md gives the horizon in heights).
+    One ring is one node's: a block costs it ~25 entries more since
+    its verify.* / state.* / store.* / validate.* children exist
+    (docs/OBSERVABILITY.md gives the horizon in heights).
   * time.perf_counter_ns() start/stop; no datetime, no wall clock.
+  * A span's duration is its thread's work PLUS its wait for the
+    interpreter lock. A kind registered with cpu=True (its body is one
+    thread's synchronous work) also stamps time.thread_time_ns() at
+    both ends and records attr `cpu_ns`; dur - cpu_ns is the time the
+    thread held the span and did not run (the lock's queue; under a
+    span that writes or launches, the disk or the device too). Marked
+    are the twelve kinds a reader sums and no other: store.save_block,
+    validate.block, verify.window, and the pure-host store.encode_*,
+    validate.basic / .set_hashes / .median_time, state.update,
+    verify.collect / .sign_batch, crypto.pack. A kind that wraps an
+    `await` (state.validate, state.apply_block, state.exec,
+    state.app_commit, admission.*, consensus.*) must NOT be marked:
+    the loop thread's CPU inside it is other tasks'. A span ended on
+    another thread than it began on records none. The thread clock is
+    the kernel's: where it advances in ticks (10 ms on the benchmark's
+    sandboxed host) ONE span's cpu_ns is 0 or whole ticks, can exceed
+    its duration and means nothing; only a kind's sum over many spans
+    is a reading. A read is then a system call too (~5 us, not ~0.3:
+    a marked span ~15 us), so mark a per-block kind that a reader
+    sums, and nothing else.
   * Task-local context via contextvars: asyncio tasks inherit the
     active span automatically. Executor threads do NOT (run_in_executor
     ignores the caller's Context), so cross-thread parenting is an
@@ -85,9 +125,9 @@ Design constraints (this stays ON in production):
 
 Export: chrome_trace() renders the ring as Chrome trace-event JSON
 ("X" complete events) loadable in Perfetto / chrome://tracing; served
-at GET /debug/trace?seconds=N (libs/debugsrv.py), captured by
-`tendermint-tpu debug trace` (cmd/debug.py), and rolled up per-kind
-(p50/p95/p99) into bench.py's BENCH_*.json stage_breakdown field.
+at GET /debug/trace?seconds=N (libs/debugsrv.py) and captured by
+`tendermint-tpu debug trace` (cmd/debug.py); stage_rollup() gives the
+per-kind p50/p95/p99 that GET /debug/trace/rollup serves.
 """
 
 from __future__ import annotations
@@ -104,12 +144,22 @@ from typing import NamedTuple
 # ---------------------------------------------------------------- registry
 
 _KINDS: set[str] = set()
+# kinds whose spans also carry the thread's CPU time (attr `cpu_ns`)
+_CPU_KINDS: set[str] = set()
 
 
-def register_kind(name: str) -> str:
+def register_kind(name: str, cpu: bool = False) -> str:
     """Register a span kind. Instrumented modules use the constants
-    below; tests may register their own (namespaced `test.*`)."""
+    below; tests may register their own (namespaced `test.*`).
+    `cpu=True` marks a kind whose body is ONE thread's synchronous
+    work: its spans stamp time.thread_time_ns() at both ends and record
+    attr `cpu_ns`, so `dur - cpu_ns` is the time the thread held the
+    span and was not running (the GIL's queue, a disk, the device).
+    Never mark a kind that wraps an `await`: the loop thread's CPU
+    inside it is other tasks'."""
     _KINDS.add(name)
+    if cpu:
+        _CPU_KINDS.add(name)
     return name
 
 
@@ -155,7 +205,7 @@ def consensus_step_kind(step_name: str) -> str:
 # Device pipeline (crypto/batch.py, crypto/tpu/verify.py + expanded.py).
 CRYPTO_BATCH = register_kind("crypto.batch")
 CRYPTO_VERIFY = register_kind("crypto.verify")
-CRYPTO_PACK = register_kind("crypto.pack")
+CRYPTO_PACK = register_kind("crypto.pack", cpu=True)
 CRYPTO_DISPATCH = register_kind("crypto.dispatch")
 CRYPTO_DEVICE_EXEC = register_kind("crypto.device_exec")
 CRYPTO_READBACK = register_kind("crypto.readback")
@@ -184,10 +234,10 @@ SPECULATION_RECONCILE = register_kind("speculation.reconcile")
 # Verify sites (types/validator_set.py, blockchain/verify_ahead.py):
 # what a commit check costs the host around its crypto.* launch.
 VERIFY_COMMIT = register_kind("verify.commit")
-VERIFY_COLLECT = register_kind("verify.collect")
-VERIFY_SIGN_BATCH = register_kind("verify.sign_batch")
+VERIFY_COLLECT = register_kind("verify.collect", cpu=True)
+VERIFY_SIGN_BATCH = register_kind("verify.sign_batch", cpu=True)
 VERIFY_TABLES = register_kind("verify.tables")
-VERIFY_WINDOW = register_kind("verify.window")
+VERIFY_WINDOW = register_kind("verify.window", cpu=True)
 # A batch of a set that is not all ed25519, partitioned by key type
 # (types/validator_set.py _lane_split): attrs `ed25519`, `sr25519`,
 # `other` = lanes of each. Never opened for an all-ed25519 set.
@@ -219,15 +269,36 @@ LIGHT_FLUSH = register_kind("light.flush")
 # BlockExecutor._apply_block in order.
 STATE_APPLY_BLOCK = register_kind("state.apply_block")
 STATE_VALIDATE = register_kind("state.validate")
+# What state.validate holds (state/validation.py). validate.block is
+# the whole validate_block call INSIDE the worker thread (attr height):
+# state.validate less it is the executor hop, both ways. Its children
+# beside verify.commit and evidence.check: the block's own checks
+# (header fields, the LastCommit / Data / Evidence hashes against the
+# header), the header's hashes against the state (two Merkle trees over
+# the validator sets; attr validators) and the BFT-time check.
+VALIDATE_BLOCK = register_kind("validate.block", cpu=True)
+VALIDATE_BASIC = register_kind("validate.basic", cpu=True)
+VALIDATE_SET_HASHES = register_kind("validate.set_hashes", cpu=True)
+VALIDATE_MEDIAN_TIME = register_kind("validate.median_time", cpu=True)
 STATE_EXEC = register_kind("state.exec")
 STATE_SAVE_RESPONSES = register_kind("state.save_responses")
 STATE_APP_COMMIT = register_kind("state.app_commit")
+# update_state and the start of the changed set's table warm, between
+# state.save_responses and state.app_commit (attr updates)
+STATE_UPDATE = register_kind("state.update", cpu=True)
 STATE_SAVE = register_kind("state.save")
 # a validator set's membership encoded in full by the state store
 # (attrs height, keys, bytes): none on a block whose set stands still
 STATE_VALSET_ROW = register_kind("state.valset_row")
 STATE_EVENTS = register_kind("state.events")
-STORE_SAVE_BLOCK = register_kind("store.save_block")
+STORE_SAVE_BLOCK = register_kind("store.save_block", cpu=True)
+# What store.save_block holds (store/__init__.py _save_block), in
+# order: the seen commit and the last commit as protos, the meta, hash,
+# part and store-state rows (attr parts), the batch's INSERTs and its
+# COMMIT (attr rows; db.write, the COMMIT, is its child).
+STORE_ENCODE_COMMITS = register_kind("store.encode_commits", cpu=True)
+STORE_ENCODE_PARTS = register_kind("store.encode_parts", cpu=True)
+STORE_WRITE = register_kind("store.write")
 DB_WRITE = register_kind("db.write")
 WAL_FSYNC = register_kind("wal.fsync")
 P2P_SEND_FLUSH = register_kind("p2p.send_flush")
@@ -248,7 +319,7 @@ class Span:
     plain tuple; no reference is kept after that beyond the ring."""
 
     __slots__ = ("kind", "span_id", "parent_id", "tid", "t0", "attrs",
-                 "_tracer", "_done")
+                 "_tracer", "_done", "_cpu0")
 
     def __init__(self, tracer: "Tracer", kind: str, parent_id: int,
                  attrs: dict | None, start_ns: int | None = None):
@@ -260,6 +331,11 @@ class Span:
         self._tracer = tracer
         self._done = False
         self.t0 = time.perf_counter_ns() if start_ns is None else start_ns
+        # the CPU interval lies inside the wall interval: cpu_ns <= dur
+        # wherever the thread clock is as fine as the wall clock; where
+        # it ticks (10 ms on a sandboxed host) only sums of many spans
+        # mean anything
+        self._cpu0 = time.thread_time_ns() if kind in _CPU_KINDS else None
 
     def set_attr(self, key: str, value) -> None:
         if self.attrs is None:
@@ -270,6 +346,9 @@ class Span:
         if self._done:  # idempotent: height/step spans end via two paths
             return
         self._done = True
+        if self._cpu0 is not None and threading.get_ident() == self.tid:
+            # a span ended on another thread has no CPU time to give
+            self.set_attr("cpu_ns", time.thread_time_ns() - self._cpu0)
         t1 = time.perf_counter_ns()
         tracer = self._tracer
         tracer._append((
@@ -535,7 +614,7 @@ class Tracer:
                      prefix: str | None = None) -> dict[str, dict]:
         """Per-kind latency rollup {kind: {count, p50_ms, p95_ms,
         p99_ms, total_ms}} over the ring (optionally windowed /
-        prefix-filtered) — the BENCH stage-breakdown payload."""
+        prefix-filtered) — what GET /debug/trace/rollup serves."""
         by_kind: dict[str, list[int]] = {}
         for r in self.snapshot(seconds):
             if prefix is not None and not r[0].startswith(prefix):

@@ -135,8 +135,10 @@ class BlockExecutor:
     # -- the apply path --
 
     def validate_block(self, state: State, block: Block) -> None:
-        validate_block(state, block, self.evpool,
-                       speculation=self.speculation)
+        with TRACER.span(tracing.VALIDATE_BLOCK,
+                         height=block.header.height):
+            validate_block(state, block, self.evpool,
+                           speculation=self.speculation)
 
     async def validate_block_async(self, state: State, block: Block) -> None:
         """validate_block in a worker thread: the LastCommit signature
@@ -162,8 +164,8 @@ class BlockExecutor:
 
     async def _apply_block(self, state: State, block_id: BlockID,
                            block: Block) -> tuple[State, int]:
-        # the six state.* spans below are what a block costs the host,
-        # in order; update_state between them is apply_block's own time
+        # the seven state.* spans below are what a block costs the
+        # host, in order
         with TRACER.span(tracing.STATE_VALIDATE):
             await self.validate_block_async(state, block)
 
@@ -187,13 +189,15 @@ class BlockExecutor:
             state_metrics().validator_set_updates.inc(len(val_updates))
         if end_block.consensus_param_updates:
             state_metrics().consensus_param_updates.inc()
-        new_state = update_state(state, block_id, block, abci_responses,
-                                 val_updates)
-        if val_updates:
-            # The changed set takes effect at H+2: warm its expanded
-            # device tables in the background now so the first commit
-            # verify under it doesn't pay the table build inline.
-            new_state.next_validators.warm_device_tables()
+        with TRACER.span(tracing.STATE_UPDATE, updates=len(val_updates)):
+            new_state = update_state(state, block_id, block,
+                                     abci_responses, val_updates)
+            if val_updates:
+                # The changed set takes effect at H+2: warm its
+                # expanded device tables in the background now so the
+                # first commit verify under it doesn't pay the table
+                # build inline.
+                new_state.next_validators.warm_device_tables()
 
         # Commit app + update mempool (reference: execution.go:210-254)
         with TRACER.span(tracing.STATE_APP_COMMIT):

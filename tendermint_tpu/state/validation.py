@@ -7,6 +7,8 @@ reference's sequential loop."""
 
 from __future__ import annotations
 
+from ..libs import tracing
+from ..libs.tracing import TRACER
 from ..types.block import Block
 from ..types.validator_set import VerificationError
 from . import State, median_time
@@ -18,7 +20,8 @@ class BlockValidationError(Exception):
 
 def validate_block(state: State, block: Block, evidence_pool=None,
                    speculation=None) -> None:
-    block.validate_basic()
+    with TRACER.span(tracing.VALIDATE_BASIC):
+        block.validate_basic()
     h = block.header
 
     from . import BLOCK_PROTOCOL_VERSION
@@ -48,16 +51,18 @@ def validate_block(state: State, block: Block, evidence_pool=None,
         raise BlockValidationError("wrong LastBlockID")
 
     # hashes against current state
-    if h.app_hash != state.app_hash:
-        raise BlockValidationError("wrong AppHash")
-    if h.consensus_hash != state.consensus_params.hash():
-        raise BlockValidationError("wrong ConsensusHash")
-    if h.validators_hash != state.validators.hash():
-        raise BlockValidationError("wrong ValidatorsHash")
-    if h.next_validators_hash != state.next_validators.hash():
-        raise BlockValidationError("wrong NextValidatorsHash")
-    if h.last_results_hash != state.last_results_hash:
-        raise BlockValidationError("wrong LastResultsHash")
+    with TRACER.span(tracing.VALIDATE_SET_HASHES,
+                     validators=len(state.validators)):
+        if h.app_hash != state.app_hash:
+            raise BlockValidationError("wrong AppHash")
+        if h.consensus_hash != state.consensus_params.hash():
+            raise BlockValidationError("wrong ConsensusHash")
+        if h.validators_hash != state.validators.hash():
+            raise BlockValidationError("wrong ValidatorsHash")
+        if h.next_validators_hash != state.next_validators.hash():
+            raise BlockValidationError("wrong NextValidatorsHash")
+        if h.last_results_hash != state.last_results_hash:
+            raise BlockValidationError("wrong LastResultsHash")
 
     # LastCommit: genesis block carries an empty one; later blocks carry
     # +2/3 of the previous validator set — ALL sigs verified, batched.
@@ -104,7 +109,8 @@ def validate_block(state: State, block: Block, evidence_pool=None,
     else:
         if h.time <= state.last_block_time:
             raise BlockValidationError("block time not after last block")
-        expected = median_time(block.last_commit, state.last_validators)
+        with TRACER.span(tracing.VALIDATE_MEDIAN_TIME):
+            expected = median_time(block.last_commit, state.last_validators)
         if h.time != expected:
             raise BlockValidationError(
                 f"block time {h.time} != median commit time {expected}"

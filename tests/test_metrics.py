@@ -205,36 +205,34 @@ def test_histogram_concurrent_observe_render_consistent():
 
 
 def test_tracing_metrics_bridge():
-    """A span close on the global TRACER must populate the kind's
-    histogram: dedicated tpu_* stage histograms for the device
-    pipeline, tracing_span_seconds{kind=...} for everything else —
-    with no extra instrumentation call site."""
+    """A span close on the global TRACER must populate
+    tracing_span_seconds{kind=...} — the device pipeline's stage kinds
+    like every other — with no extra instrumentation call site."""
     from tendermint_tpu.libs import tracing
-    from tendermint_tpu.libs.metrics import tpu_metrics, tracing_metrics
+    from tendermint_tpu.libs.metrics import tracing_metrics
 
-    tm = tpu_metrics()
-    before_pack = tm.pack_seconds.count
-    with tracing.TRACER.span(tracing.CRYPTO_PACK, lanes=4):
-        pass
-    assert tm.pack_seconds.count == before_pack + 1
-
-    trm = tracing_metrics()
-    sink_hist = trm.span_seconds
-    before = sink_hist.count
-    with tracing.TRACER.span(tracing.WAL_FSYNC):
-        pass
-    assert sink_hist.count == before + 1
+    sink_hist = tracing_metrics().span_seconds
+    for kind, attrs in ((tracing.CRYPTO_PACK, {"lanes": 4}),
+                        (tracing.WAL_FSYNC, {})):
+        before = sink_hist.count
+        with tracing.TRACER.span(kind, **attrs):
+            pass
+        assert sink_hist.count == before + 1
     text = DEFAULT.render_text()
     assert 'tracing_span_seconds_bucket{kind="wal.fsync",le="+Inf"}' \
         in text
+    assert 'tracing_span_seconds_bucket{kind="crypto.pack",le="+Inf"}' \
+        in text
+    # the stage kinds have no series of their own any more
+    assert "tpu_pack_" not in text and "tpu_readback_" not in text
 
     # private tracers have no sink: a test Tracer must not feed the
     # process registry
     t = tracing.Tracer(capacity=8)
-    before = tm.pack_seconds.count
+    before = sink_hist.count
     with t.span(tracing.CRYPTO_PACK, lanes=1):
         pass
-    assert tm.pack_seconds.count == before
+    assert sink_hist.count == before
 
 
 def test_metrics_and_status_endpoints_end_to_end():

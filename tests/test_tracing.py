@@ -10,6 +10,7 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 import zlib
 
 import pytest
@@ -202,6 +203,74 @@ def test_stage_rollup_windows_and_prefix():
     assert t.stage_rollup(seconds=3600)[tracing.WAL_FSYNC]["count"] == 1
 
 
+# ------------------------------------------------- thread-CPU time (cpu_ns)
+
+CPU_KIND = tracing.register_kind("test.cpu_marked", cpu=True)
+PLAIN_KIND = tracing.register_kind("test.cpu_unmarked")
+
+
+def _busy(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+# loose on purpose: the suite runs six workers wide
+@pytest.mark.parametrize("body,lo,hi", [(_busy, 0.5, 1.0),
+                                        (time.sleep, 0.0, 0.2)],
+                         ids=["busy", "sleep"])
+def test_marked_kind_records_its_threads_cpu_time(body, lo, hi):
+    t = Tracer(capacity=8)
+    with t.span(CPU_KIND, lanes=3):
+        body(0.2)
+    (rec,) = t.snapshot()
+    dur, attrs = rec[5], rec[6]
+    assert attrs["lanes"] == 3
+    # no upper bound at dur: a thread clock that ticks (10 ms on the
+    # benchmark's host) can read more CPU than the span lasted
+    assert attrs["cpu_ns"] >= 0
+    assert lo * dur <= attrs["cpu_ns"]
+    if hi < 1.0:
+        assert attrs["cpu_ns"] < hi * dur
+    # the export carries it as it carries any other attribute
+    (event,) = chrome_trace(t.snapshot())["traceEvents"]
+    assert event["args"]["cpu_ns"] == attrs["cpu_ns"]
+
+
+def test_unmarked_kind_and_noop_span_record_no_cpu_time():
+    t = Tracer(capacity=8)
+    with t.span(PLAIN_KIND):
+        _busy(0.01)
+    with t.span(PLAIN_KIND, lanes=1):
+        pass
+    assert [r[6] for r in t.snapshot()] == [None, {"lanes": 1}]
+    off = Tracer(capacity=8, enabled=False)
+    with off.span(CPU_KIND) as span:
+        assert span is tracing.NOOP_SPAN
+    assert off.snapshot() == []
+    # marked are the twelve kinds a reader sums (a read of the thread
+    # clock can be a system call): never an `await`-wrapping kind, whose
+    # loop-thread CPU is other tasks', and no kind that feeds no metric
+    assert {k for k in tracing._CPU_KINDS if not k.startswith("test.")} == {
+        tracing.STORE_SAVE_BLOCK, tracing.VALIDATE_BLOCK,
+        tracing.VERIFY_WINDOW, tracing.STORE_ENCODE_COMMITS,
+        tracing.STORE_ENCODE_PARTS, tracing.VALIDATE_BASIC,
+        tracing.VALIDATE_SET_HASHES, tracing.VALIDATE_MEDIAN_TIME,
+        tracing.STATE_UPDATE, tracing.VERIFY_COLLECT,
+        tracing.VERIFY_SIGN_BATCH, tracing.CRYPTO_PACK}
+
+
+def test_span_ended_on_another_thread_records_no_cpu_time():
+    t = Tracer(capacity=8)
+    span = t.begin(CPU_KIND, lanes=2)
+    th = threading.Thread(target=span.end)
+    th.start()
+    th.join(timeout=10)
+    assert not th.is_alive()
+    (rec,) = t.snapshot()
+    assert rec[6] == {"lanes": 2}
+
+
 # ------------------------------------------- lint + overhead budget (CI gate)
 
 
@@ -212,6 +281,8 @@ def test_check_spans_lint_and_overhead_budget():
     )
 
     assert find_ad_hoc_spans() == []
+    # the budget is held by a MARKED kind: both thread_time_ns() stamps
+    assert tracing.CRYPTO_PACK in tracing._CPU_KINDS
     enabled, disabled = measure_overhead(n=5000)
     assert enabled < ENABLED_BUDGET_S, \
         f"enabled tracer overhead {enabled * 1e6:.1f}us over budget"
@@ -301,8 +372,9 @@ def test_consensus_height_timeline_and_trace_endpoint(tmp_path):
     applied = [e for e in evs if e["name"] == tracing.STATE_APPLY_BLOCK]
     assert any(children_of(a) >= {
         tracing.STATE_VALIDATE, tracing.STATE_EXEC,
-        tracing.STATE_SAVE_RESPONSES, tracing.STATE_APP_COMMIT,
-        tracing.STATE_SAVE, tracing.STATE_EVENTS} for a in applied)
+        tracing.STATE_SAVE_RESPONSES, tracing.STATE_UPDATE,
+        tracing.STATE_APP_COMMIT, tracing.STATE_SAVE,
+        tracing.STATE_EVENTS} for a in applied)
     assert any(e["name"] == tracing.STORE_SAVE_BLOCK for e in evs)
     assert any(e["name"] == tracing.STATE_APPLY_BLOCK for e in evs)
     assert any(e["name"] == tracing.WAL_FSYNC for e in evs)

@@ -34,10 +34,13 @@ NEW_KINDS = (
     "state.valset_row",
     "verify.lane_split", "crypto.sr_merlin",
     "evidence.check", "evidence.collect", "evidence.update",
+    "validate.block", "validate.basic", "validate.set_hashes",
+    "validate.median_time", "state.update",
+    "store.encode_commits", "store.encode_parts", "store.write",
 )
 APPLY_CHILDREN = [
     "state.validate", "state.exec", "state.save_responses",
-    "state.app_commit", "state.save", "state.events",
+    "state.update", "state.app_commit", "state.save", "state.events",
 ]
 
 
@@ -621,7 +624,7 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
             by_parent[kind] = by_parent.get(kind, 0) + w[6].get("n", 1)
         # DeliverTx stages: nothing is written inside state.exec
         assert tracing.STATE_EXEC not in by_parent
-        assert by_parent[tracing.STORE_SAVE_BLOCK] == 1
+        assert by_parent[tracing.STORE_WRITE] == 1
         assert by_parent[tracing.STATE_SAVE_RESPONSES] == 1
         assert by_parent[tracing.STATE_APP_COMMIT] == 1
         # the state's ONE batch; a set's membership is encoded into it
@@ -639,6 +642,153 @@ def test_apply_block_children_in_order_and_db_writes(tmp_path,
         (app_write,) = [w for w in writes
                         if by_id.get(w[2]) == tracing.STATE_APP_COMMIT]
         assert app_write[6]["ops"] == (4 if height < 3 else 3)
+
+
+def test_validate_block_runs_in_the_worker_beneath_state_validate(
+        tmp_path):
+    """state.validate is opened on the loop around the await;
+    validate.block is the whole call inside the worker thread, so the
+    parent less this child is the executor hop."""
+    from tendermint_tpu.abci.client import LocalClient
+    from tendermint_tpu.abci.kvstore import KVStoreApp
+    from tendermint_tpu.libs.db import MemDB
+    from tendermint_tpu.state import make_genesis_state
+    from tendermint_tpu.state.execution import BlockExecutor
+    from tendermint_tpu.state.store import Store
+
+    async def go():
+        gdoc, pvs = make_genesis(4)
+        state = make_genesis_state(gdoc)
+        store = Store(MemDB())
+        store.save(state)
+        client = LocalClient(KVStoreApp())
+        await client.start()
+        executor = BlockExecutor(store, client)
+        last_commit = None
+        for h in range(2):
+            block, bid = next_block(state, pvs, last_commit, [b"a%d=1" % h])
+            seen = commit_for(state, pvs, block, bid)
+            TRACER.clear()
+            state, _ = await executor.apply_block(state, bid, block)
+            last_commit = seen
+        await client.stop()
+        return TRACER.snapshot()
+
+    recs = run(go())   # the second block: it carries a LastCommit
+    (outer,) = [r for r in recs if r[0] == tracing.STATE_VALIDATE]
+    (whole,) = children(recs, outer)
+    assert whole[0] == tracing.VALIDATE_BLOCK
+    assert whole[6]["height"] == 2 and inside(whole, outer)
+    assert whole[3] != outer[3]            # another thread than the loop's
+    assert whole[6]["cpu_ns"] >= 0
+    assert "cpu_ns" not in (outer[6] or {})   # it wraps an await
+    kids = children(recs, whole)
+    assert [k[0] for k in kids] == [
+        tracing.VALIDATE_BASIC, tracing.VALIDATE_SET_HASHES,
+        tracing.VERIFY_COMMIT, tracing.VALIDATE_MEDIAN_TIME]
+    assert all(inside(k, whole) and k[3] == whole[3] for k in kids)
+    assert kids[1][6]["validators"] == 4
+    (update,) = [r for r in recs if r[0] == tracing.STATE_UPDATE]
+    assert update[6]["updates"] == 0 and "cpu_ns" in update[6]
+
+
+def _pinned_block(n_sigs=1000):
+    """One fixed block of height 2 with an `n_sigs`-signature LastCommit
+    and its seen commit: nothing in it moves with the clock or a seed
+    (the store checks no signature, so the signatures are digests)."""
+    import hashlib
+
+    from tendermint_tpu.state import make_genesis_state
+    from tendermint_tpu.types.block import (
+        BlockID, BlockIDFlag, Commit, CommitSig, PartSetHeader,
+    )
+    from tendermint_tpu.types.genesis import GenesisDoc, GenesisValidator
+
+    from helpers import deterministic_pv
+
+    gdoc = GenesisDoc(
+        chain_id="pinned-chain", genesis_time=1_700_000_000 * 10**9,
+        validators=[GenesisValidator(deterministic_pv(i).get_pub_key(), 10)
+                    for i in range(4)])
+    gdoc.validate_and_complete()
+    state = make_genesis_state(gdoc)
+
+    def commit(height, tag):
+        bid = BlockID(
+            hashlib.sha256(b"block-%d" % height).digest(),
+            PartSetHeader(1, hashlib.sha256(b"parts-%d" % height).digest()))
+        return Commit(height, 0, bid, [
+            CommitSig(BlockIDFlag.COMMIT,
+                      hashlib.sha256(b"addr-%d" % i).digest()[:20],
+                      1_700_000_001 * 10**9 + i,
+                      hashlib.sha512(b"%s-%d-%d" % (tag, height, i)).digest())
+            for i in range(n_sigs)])
+
+    block = state.make_block(
+        2, [b"k%d=v" % i for i in range(8)], commit(1, b"last"), [],
+        state.validators.get_proposer().address, 1_700_000_002 * 10**9)
+    return block, commit(2, b"seen")
+
+
+def test_save_block_children_and_the_batch_it_writes(tmp_path):
+    """store.save_block's three children cover it, and the batch handed
+    to write_batch is the one the store wrote before the spans: its
+    digest was taken at the commit before them (PR 36's), from this
+    block."""
+    import hashlib
+
+    from tendermint_tpu.libs.db import SqliteDB
+    from tendermint_tpu.store import BlockStore
+
+    block, seen = _pinned_block()
+    db = SqliteDB(str(tmp_path / "blockstore.sqlite"))
+    batches = []
+    real = db.write_batch
+
+    def capture(ops):
+        batches.append(list(ops))
+        return real(ops)
+
+    db.write_batch = capture
+    store = BlockStore(db)
+    parts = block.make_part_set()
+    TRACER.clear()
+    store.save_block(block, parts, seen)
+    recs = TRACER.snapshot()
+    assert store.load_seen_commit(2).signatures[999].signature == \
+        seen.signatures[999].signature
+    db.close()
+
+    (ops,) = batches
+    assert [k[:2] for k, _ in ops] == [b"H:", b"BH", b"SC", b"P:", b"P:",
+                                      b"C:", b"bl"]
+    digest = hashlib.sha256()
+    for k, v in ops:
+        digest.update(len(k).to_bytes(4, "big") + k
+                      + len(v).to_bytes(4, "big") + v)
+    assert digest.hexdigest() == ("5cecd69020e40e5bea71ec8ea99c7681"
+                                  "e674b5596157437ffc4c545b7a7e7fc7")
+    batch_bytes = sum(len(v) for _, v in ops)
+    assert batch_bytes == 309678
+
+    (whole,) = [r for r in recs if r[0] == tracing.STORE_SAVE_BLOCK]
+    kids = children(recs, whole)
+    assert [k[0] for k in kids] == [
+        tracing.STORE_ENCODE_COMMITS, tracing.STORE_ENCODE_PARTS,
+        tracing.STORE_WRITE]
+    assert all(inside(k, whole) for k in kids)
+    for a, b in zip(kids, kids[1:]):
+        assert a[4] + a[5] <= b[4]
+    commits, rows, write = (k[6] for k in kids)
+    assert rows["parts"] == parts.total == 2 and write == {"rows": 7}
+    # the thread's CPU on the box and its two pure-host children; the
+    # write waits on the disk and feeds no CPU reading
+    assert all(k[6]["cpu_ns"] >= 0 for k in (whole, kids[0], kids[1]))
+    assert set(commits) == {"cpu_ns"}
+    (commit_,) = children(recs, kids[2])     # the COMMIT, in store.write
+    assert commit_[0] == tracing.DB_WRITE and commit_[6]["ops"] == 7
+    # nothing of the store's work sits outside the three
+    assert sum(k[5] for k in kids) >= 0.9 * whole[5]
 
 
 # --------------------------------------------------------------- admission
