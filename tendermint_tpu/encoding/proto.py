@@ -104,6 +104,12 @@ class Writer:
         self._buf += payload
         return self
 
+    def raw(self, fields: bytes) -> "Writer":
+        """Append fields that are on the wire already: tag, length
+        and payload each."""
+        self._buf += fields
+        return self
+
     def finish(self) -> bytes:
         return bytes(self._buf)
 

@@ -293,9 +293,11 @@ STATE_VALSET_ROW = register_kind("state.valset_row")
 STATE_EVENTS = register_kind("state.events")
 STORE_SAVE_BLOCK = register_kind("store.save_block", cpu=True)
 # What store.save_block holds (store/__init__.py _save_block), in
-# order: the seen commit and the last commit as protos, the meta, hash,
-# part and store-state rows (attr parts), the batch's INSERTs and its
-# COMMIT (attr rows; db.write, the COMMIT, is its child).
+# order: the seen commit and the last commit as protos (attr columnar:
+# how many of the two types/sign_batch.py commit_sig_rows encoded from
+# their columns, 0-2; the per-slot writer took the others), the meta,
+# hash, part and store-state rows (attr parts), the batch's INSERTs and
+# its COMMIT (attr rows; db.write, the COMMIT, is its child).
 STORE_ENCODE_COMMITS = register_kind("store.encode_commits", cpu=True)
 STORE_ENCODE_PARTS = register_kind("store.encode_parts", cpu=True)
 STORE_WRITE = register_kind("store.write")

@@ -15,6 +15,7 @@ from ..libs import tracing
 from ..libs.tracing import TRACER
 from ..types.block import Block, BlockID, Commit, Part, PartSet
 from ..types.block_meta import BlockMeta
+from ..types.sign_batch import columnar_encodes
 
 _STORE_KEY = b"blockStore"
 
@@ -95,10 +96,14 @@ class BlockStore:
         # span (in their places among the rows the two groups would
         # take five); the batch holds the rows in the order it always
         # did: H, BH, SC, P..., C, blockStore
-        with TRACER.span(tracing.STORE_ENCODE_COMMITS):
+        with TRACER.span(tracing.STORE_ENCODE_COMMITS) as sp:
+            before = columnar_encodes()
             seen = seen_commit.to_proto().finish()
             last = (block.last_commit.to_proto().finish()
                     if block.last_commit is not None else None)
+            # how many of the two the array path encoded (0-2): fewer
+            # than the block has commits means a slot fitted no column
+            sp.set_attr("columnar", columnar_encodes() - before)
         with TRACER.span(tracing.STORE_ENCODE_PARTS, parts=parts.total):
             bid = BlockID(block.hash(), parts.header())
             meta = BlockMeta(bid, parts.byte_size, block.header,

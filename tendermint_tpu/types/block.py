@@ -262,8 +262,12 @@ class Commit:
 
     def hash(self) -> bytes:
         if self._hash is None:
+            from .sign_batch import commit_sig_rows
+
+            rows = commit_sig_rows(self)
             self._hash = merkle.hash_from_byte_slices(
                 [cs.to_proto().finish() for cs in self.signatures]
+                if rows is None else rows.leaves()
             )
         return self._hash
 
@@ -290,8 +294,17 @@ class Commit:
         w.varint(1, self.height)
         w.varint(2, self.round)
         w.message(3, block_id_writer(self.block_id))
-        for cs in self.signatures:
-            w.message(4, cs.to_proto())
+        # the slots from their columns (types/sign_batch.py), read
+        # anew at every encode; CommitSig.to_proto() is the definition
+        # and writes any commit with a slot that fits no column
+        from .sign_batch import commit_sig_rows
+
+        rows = commit_sig_rows(self)
+        if rows is None:
+            for cs in self.signatures:
+                w.message(4, cs.to_proto())
+        else:
+            w.raw(rows.wire)
         return w
 
     def to_bytes(self) -> bytes:

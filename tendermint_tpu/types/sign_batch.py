@@ -36,12 +36,15 @@ Shapes:
 
 from __future__ import annotations
 
+import threading
 from dataclasses import InitVar, dataclass, field
 from itertools import compress
 from operator import attrgetter, ne
+from typing import NamedTuple
 
 import numpy as np
 
+from ..crypto import tmhash
 from . import canonical
 from .block import BlockIDFlag
 
@@ -223,6 +226,113 @@ class CommitColumns:
             ts = np.array([_check_ts(sigs[s].timestamp) for s in slots],
                           np.int64)
         return ts
+
+
+class CommitSigRows(NamedTuple):
+    """A Commit's slots as they stand on the wire: `wire` is every
+    slot as field 4 of its Commit (22 len body), one after another;
+    `ends` is where each slot's row ends in it."""
+
+    wire: bytes
+    ends: np.ndarray  # (n,) intp
+
+    def leaves(self) -> list[bytes]:
+        """Each slot's CommitSig proto by itself (the leaves of
+        Commit.hash()): its row less the tag and the length, one byte
+        each since no slot that fits the columns reaches 128 bytes."""
+        ends = self.ends.tolist()
+        wire = self.wire
+        return [wire[s + 2:e] for s, e in zip([0] + ends, ends)]
+
+
+# commits whose slots this thread's commit_sig_rows encoded; a caller
+# that wants to know of its own encodes subtracts two readings
+# (store.encode_commits attr `columnar`). Per thread: a fast-sync
+# window job builds part sets beside the apply loop.
+_ENCODED = threading.local()
+
+
+def columnar_encodes() -> int:
+    return getattr(_ENCODED, "n", 0)
+
+
+_U8 = np.uint8
+_ADDR_W = tmhash.TRUNCATED_SIZE
+_SIG_W = 64  # SIGNATURE_SIZE of ed25519, sr25519 and secp256k1 alike
+
+
+def commit_sig_rows(commit) -> CommitSigRows | None:
+    """CommitSig.to_proto() of every slot, by array operations: what
+    `for cs in signatures: w.message(4, cs.to_proto())` writes, byte
+    for byte, or None where a slot fits no column (a flag past a byte,
+    a timestamp outside [0, 2^63), a present slot whose address is not
+    20 bytes or whose signature is not 64, an absent slot that carries
+    anything): the per-slot writer then encodes that commit. Nothing
+    is kept: CommitSig is mutable, so every encode reads the slots.
+
+    A slot's row is five pieces, each of fixed columns but for ONE
+    varint at its end (_varint_digits leaves zeros after a shorter
+    one), so every column is written over all rows at once and one
+    mask cuts each piece to its length:
+
+      22 len 08 flag | 12 14 addr[20] | 1a len 08 secs | 10 nanos
+      | 22 40 sig[64]
+    """
+    sigs = commit.signatures
+    n = len(sigs)
+    addrs = list(map(_ADDRESS, sigs))
+    sgs = list(map(_SIGNATURE, sigs))
+    try:
+        # a flag or a length past a byte is a ValueError here
+        flags, alen, slen = (
+            np.frombuffer(bytes(column), _U8) for column in
+            (map(_FLAG, sigs), map(len, addrs), map(len, sgs)))
+        ts = np.fromiter(map(_TIMESTAMP, sigs), np.int64, n)
+    except (ValueError, OverflowError, TypeError):
+        return None
+    present = flags != BlockIDFlag.ABSENT
+    if ((alen != present * _U8(_ADDR_W)).any()
+            or (slen != present * _U8(_SIG_W)).any()
+            or (ts < 0).any() or ts[~present].any()):
+        return None
+    secs, nanos = np.divmod(ts, 1_000_000_000)
+    # lengths are bytes throughout (a row is under 128 bytes): the
+    # mask below compares a hundred columns a row
+    lf, ls, ln = (_vlen(v).astype(_U8) for v in (flags, secs, nanos))
+    wf, ws, wn = (int(v.max(initial=0)) for v in (lf, ls, ln))
+    pay = ls + (ls > 0) + ln + (ln > 0)
+    # each piece's width, and each row's length within it
+    widths = (3 + wf, 2 + _ADDR_W, 3 + ws, 1 + wn, 2 + _SIG_W)
+    lens = (lf + (lf > 0) + _U8(2), present * _U8(2 + _ADDR_W),
+            ls + (ls > 0) + (pay > 0) * _U8(2), ln + (ln > 0),
+            present * _U8(2 + _SIG_W))
+    row_len = sum(lens[1:], lens[0])
+    c1, c2, c3, c4, width = np.cumsum(widths).tolist()
+    m = np.zeros((n, width), _U8)
+    every = slice(None)
+    m[:, 0] = 0x22
+    m[:, 1] = row_len - _U8(2)
+    m[:, 2] = 0x08
+    _varint_digits(m, every, 3, flags, wf)
+    m[:, c1] = 0x12
+    m[:, c1 + 1] = _ADDR_W
+    m[present, c1 + 2:c2] = np.frombuffer(
+        b"".join(addrs), _U8).reshape(-1, _ADDR_W)
+    m[:, c2] = 0x1A
+    m[:, c2 + 1] = pay
+    m[:, c2 + 2] = 0x08
+    _varint_digits(m, every, c2 + 3, secs, ws)
+    m[:, c3] = 0x10
+    _varint_digits(m, every, c3 + 1, nanos, wn)
+    m[:, c4] = 0x22
+    m[:, c4 + 1] = _SIG_W
+    m[present, c4 + 2:] = np.frombuffer(
+        b"".join(sgs), _U8).reshape(-1, _SIG_W)
+    col = np.concatenate([np.arange(w, dtype=_U8) for w in widths])
+    keep = col < np.repeat(np.stack(lens, axis=1), widths, axis=1)
+    _ENCODED.n = columnar_encodes() + 1
+    return CommitSigRows(m[keep].tobytes(),
+                         np.cumsum(row_len, dtype=np.intp))
 
 
 class StructuredSignBytes:

@@ -737,6 +737,9 @@ def test_save_block_children_and_the_batch_it_writes(tmp_path):
     block."""
     import hashlib
 
+    # the store imports it where it hits the failpoint, between two
+    # children: a process's first save would time the import there
+    from tendermint_tpu.libs import failpoints  # noqa: F401
     from tendermint_tpu.libs.db import SqliteDB
     from tendermint_tpu.store import BlockStore
 
@@ -784,11 +787,52 @@ def test_save_block_children_and_the_batch_it_writes(tmp_path):
     # the thread's CPU on the box and its two pure-host children; the
     # write waits on the disk and feeds no CPU reading
     assert all(k[6]["cpu_ns"] >= 0 for k in (whole, kids[0], kids[1]))
-    assert set(commits) == {"cpu_ns"}
+    # both 1,000-signature commits came from their columns
+    assert set(commits) == {"cpu_ns", "columnar"}
+    assert commits["columnar"] == 2
     (commit_,) = children(recs, kids[2])     # the COMMIT, in store.write
     assert commit_[0] == tracing.DB_WRITE and commit_[6]["ops"] == 7
     # nothing of the store's work sits outside the three
     assert sum(k[5] for k in kids) >= 0.9 * whole[5]
+
+
+@pytest.mark.parametrize("last_commit, columnar", [
+    (None, 1),       # a replay cell's first block carries none
+    ("empty", 2),    # consensus gives height 1 an empty Commit: no slot
+                     # fits no column, so the array path writes nothing
+    ("odd", 1),      # one slot of the last commit fits no column: the
+                     # per-slot writer takes that commit, the seen
+                     # commit still comes from its columns
+])
+def test_encode_commits_says_how_many_came_from_columns(
+        tmp_path, last_commit, columnar):
+    """store.encode_commits attr `columnar` counts the block's commits
+    that types/sign_batch.py commit_sig_rows encoded, 0-2."""
+    from tendermint_tpu.libs.db import SqliteDB
+    from tendermint_tpu.store import BlockStore
+    from tendermint_tpu.types.block import NIL_BLOCK_ID, Commit
+
+    block, seen = _pinned_block(n_sigs=4)
+    if last_commit is None:
+        block.last_commit = None
+    elif last_commit == "empty":
+        block.last_commit = Commit(0, 0, NIL_BLOCK_ID, [])
+    else:
+        block.last_commit.signatures[2].validator_address = b"\x01" * 19
+    db = SqliteDB(str(tmp_path / "blockstore.sqlite"))
+    store = BlockStore(db)
+    store.height = 1     # the pinned block is of height 2
+    TRACER.clear()
+    store.save_block(block, block.make_part_set(), seen)
+    (span,) = [r for r in TRACER.snapshot()
+               if r[0] == tracing.STORE_ENCODE_COMMITS]
+    assert span[6]["columnar"] == columnar
+    if last_commit is None:
+        assert store.load_block_commit(1) is None
+    else:
+        assert store.load_block_commit(1) == block.last_commit
+    assert store.load_seen_commit(2) == seen
+    db.close()
 
 
 # --------------------------------------------------------------- admission
