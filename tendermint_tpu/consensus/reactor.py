@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 
 from ..libs import clock, tracing
 from ..libs.bits import BitArray
@@ -378,6 +379,8 @@ class ConsensusReactor(Reactor):
     # -- inbound --
 
     async def receive(self, chan_id: int, peer, msgb: bytes) -> None:
+        # -> consensus.receive (no clock reads when tracing is off)
+        t0 = time.perf_counter_ns() if tracing.TRACER.enabled else 0
         msg = m.decode_consensus_msg(msgb)
         # Origin rehydration: the connection's recv routine runs us
         # inside a live p2p.recv_msg span — fold the sender's tag
@@ -408,13 +411,13 @@ class ConsensusReactor(Reactor):
                 return
             if isinstance(msg, m.ProposalMessage):
                 ps.set_proposal(msg.proposal)
-                await self.cs.add_peer_msg(msg, peer.id)
+                await self._to_consensus(msg, peer.id, msgb, t0)
             elif isinstance(msg, m.ProposalPOLMessage):
                 ps.apply_proposal_pol(msg)
             elif isinstance(msg, m.BlockPartMessage):
                 ps.set_has_part(msg.height, msg.round, msg.part.index)
                 ps.block_parts_received += 1
-                await self.cs.add_peer_msg(msg, peer.id)
+                await self._to_consensus(msg, peer.id, msgb, t0)
             else:
                 raise ValueError(f"bad msg on data channel: {type(msg)}")
         elif chan_id == VOTE_CHANNEL:
@@ -428,7 +431,7 @@ class ConsensusReactor(Reactor):
                 ps.set_has_vote(v.height, v.round, int(v.type),
                                 v.validator_index)
                 ps.votes_received += 1
-                await self.cs.add_peer_msg(msg, peer.id)
+                await self._to_consensus(msg, peer.id, msgb, t0)
                 # NOTE: no trust credit here — votes are credited (or
                 # debited) by the state machine AFTER signature
                 # verification (state.py _verify_and_commit_batch);
@@ -455,6 +458,15 @@ class ConsensusReactor(Reactor):
             else:
                 raise ValueError(
                     f"bad msg on votebits channel: {type(msg)}")
+
+    async def _to_consensus(self, msg, peer_id: str, msgb: bytes,
+                            t0: int) -> None:
+        """Into the state machine's funnel (which may hold the caller
+        back). The decode and the peer-state marks since `t0` are this
+        message's share of consensus.receive: the receive routine adds
+        its own and records the unit."""
+        await self.cs.add_peer_msg(
+            msg, peer_id, msgb, time.perf_counter_ns() - t0 if t0 else 0)
 
     async def _handle_maj23(self, ps: PeerState, peer,
                             msg: m.VoteSetMaj23Message) -> None:

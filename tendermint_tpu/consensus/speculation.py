@@ -167,6 +167,28 @@ class SpeculationPlane:
         if _ACTIVE_PLANE is self:
             _ACTIVE_PLANE = None
 
+    def load_programs(self, valset) -> int:
+        """Build the arena `valset`'s precommits will be verified in
+        and run its programs once (resident.load_programs), its keys
+        installed: called when consensus starts on a set whose votes
+        the device verifies, so that the first flush of a height
+        compiles nothing. Returns the programs run; 0 where the plane
+        would not use an arena for this set, or has one."""
+        from ..crypto import batch as cbatch
+
+        if len(valset.validators) < self.device_min \
+                or cbatch.host_forced() \
+                or not cbatch.device_available("ed25519"):
+            return 0
+        from ..crypto.tpu.resident import load_programs
+
+        with self._launch_lock:
+            had = self._arena
+            arena = self._arena_with_keys(valset, valset.hash())
+            if arena is None or arena is had:
+                return 0
+            return load_programs(arena)
+
     def _ensure_flusher(self) -> None:
         if self._flusher is not None and not self._flusher.done():
             return
@@ -350,8 +372,8 @@ class SpeculationPlane:
         n = len(kept)
         if n == 0:
             return []
-        want_dev = n >= self.device_min and \
-            all(0 <= ts < 1 << 63 for _, ts, _ in kept)
+        want_dev = n >= self.device_min and not cbatch.host_forced() \
+            and all(0 <= ts < 1 << 63 for _, ts, _ in kept)
         if want_dev and cbatch.breaker("ed25519").acquire():
             try:
                 out = self._device_verify(entry, kept, met)
@@ -475,16 +497,28 @@ class SpeculationPlane:
             return [bool(out[idx + 1]) for idx, _, _ in kept]
 
     def _ensure_arena(self, entry: _HeightSpec):
-        from ..crypto.tpu.resident import GROUPS, PRE_W, SUF_W, \
-            make_arena
+        from ..crypto.tpu.resident import GROUPS, PRE_W, SUF_W
 
-        if len(entry.valset.validators) + 1 > self.arena_lanes:
-            return None
         if len(entry.pre) > PRE_W or len(entry.suf) > SUF_W or \
                 GROUPS < 2:  # pragma: no cover - template guard
             return None
+        arena = self._arena_with_keys(entry.valset, entry.valset_hash)
+        if arena is not None and self._arena_entry is not entry:
+            arena.deactivate_all()
+            arena.set_template(1, entry.pre, entry.suf)
+            self._arena_entry = entry
+        return arena
+
+    def _arena_with_keys(self, valset, valset_hash: bytes):
+        """The arena, built if there is none, with `valset`'s keys in
+        it; None where it cannot hold the set (over capacity, other
+        key types than ed25519). Under _launch_lock."""
+        from ..crypto.tpu.resident import make_arena
+
+        if len(valset.validators) + 1 > self.arena_lanes:
+            return None
         if any(v.pub_key.type_name != "ed25519"
-               for v in entry.valset.validators):
+               for v in valset.validators):
             # the arena kernel is ed25519-only; mixed sets go host-side
             return None
         if self._arena is None:
@@ -498,22 +532,18 @@ class SpeculationPlane:
             # keys replay into the new layout, and this entry's lanes
             # re-splice below as they do every launch
             self._arena.ensure_mesh()
-        if len(entry.valset.validators) + 1 > self._arena.capacity:
+        if len(valset.validators) + 1 > self._arena.capacity:
             return None
-        if self._arena_keys_hash != entry.valset_hash:
+        if self._arena_keys_hash != valset_hash:
             self._arena.install_keys(
-                [v.pub_key.bytes() for v in entry.valset.validators])
-            self._arena_keys_hash = entry.valset_hash
-        if self._arena_entry is not entry:
-            self._arena.deactivate_all()
-            self._arena.set_template(1, entry.pre, entry.suf)
-            self._arena_entry = entry
+                [v.pub_key.bytes() for v in valset.validators])
+            self._arena_keys_hash = valset_hash
         return self._arena
 
     # -- the commit-time serve -----------------------------------------
 
     def serve_commit(self, valset, chain_id: str, block_id, height: int,
-                     commit) -> bool:
+                     commit, launch_lanes: int | None = None) -> bool:
         """verify_commit with speculated verdicts: byte-exact-matched
         lanes are served from the completed launch; every other lane
         re-verifies through the normal breaker-aware batch path.
@@ -521,7 +551,9 @@ class SpeculationPlane:
         nothing was speculated for this commit; True means the commit
         was fully checked here — with verify_commit's exact error
         behavior (VerificationError on bad signatures / insufficient
-        power)."""
+        power). `launch_lanes`: the caller is the live consensus
+        path, and the lanes that miss go as ValidatorSet.verify_live
+        sends them, that many lanes a launch."""
         from ..types.validator_set import VerificationError
 
         met = _metrics()
@@ -569,11 +601,22 @@ class SpeculationPlane:
             if miss:
                 # per-lane fallback batch: one mismatched lane costs
                 # one lane of re-verification, its batchmates keep
-                # their speculated verdicts (verdict scatter)
-                msgs = [commit.vote_sign_bytes(chain_id, s)
-                        for s in miss]
-                sigs = [commit.signatures[s].signature for s in miss]
-                _, fb = valset._batch_verify_lanes(miss, msgs, sigs)
+                # their speculated verdicts (verdict scatter). Sign
+                # bytes as every commit check builds them: structured
+                # where the tables take the batch (a third of a
+                # 10,000-validator commit arrives after the +2/3, and
+                # what of it the arena has not launched when the block
+                # is first validated is thousands of lanes), so the
+                # launch is the vote scheduler's program and, on the
+                # live path, its one shape
+                if launch_lanes:
+                    fb = valset.verify_commit_lanes_live(
+                        chain_id, commit, miss, launch_lanes)
+                else:
+                    msgs = valset._commit_msgs(chain_id, commit, miss,
+                                               miss)
+                    sigs = [commit.signatures[s].signature for s in miss]
+                    _, fb = valset._batch_verify_lanes(miss, msgs, sigs)
                 for s, ok in zip(miss, fb):
                     verd[s] = bool(ok)
             bad = [s for s in slots if not verd[s]]

@@ -45,11 +45,24 @@ from .wal import (
     EndHeightMessage, MsgInfo, RoundStateMessage, TimeoutInfo, WAL,
 )
 
+# The most windows (vote_batch_window_ms each) the vote scheduler holds
+# a cut for a burst that is still arriving: 100 ms at the shipped 2 ms,
+# a tenth of the shipped timeout_prevote, and what the receive routine
+# takes to hand it a batch of vote_batch_max. And how many of them in
+# a row may add nothing to the buffer before the burst counts as over
+# (one was too few: a peer's re-deliveries come a hundred duplicates
+# at a time, and every run of them cut a batch of a few dozen lanes:
+# PERF.md §6, PR 39).
+_HOLD_WINDOWS = 50
+_HOLD_IDLE_WINDOWS = 5
+
 
 @dataclass
 class _QueuedMsg:
     msg: object
     peer_id: str
+    raw: bytes | None = None   # the message as it came off the wire
+    spent_ns: int = 0   # what the reactor spent on it before the funnel
 
 
 class ConsensusState(Service):
@@ -113,6 +126,14 @@ class ConsensusState(Service):
                             lambda: len(self._vote_buf),
                             config.vote_buf_max, owner=self)
         self._tpu_metrics = None  # lazy tpu_metrics() handle (hot path)
+        # tracing of the vote path, per micro-batch (libs/tracing.py):
+        # when the buffer's first vote came, what the bound shed since
+        # the last cut, and the two folded per-message sites
+        self._vote_first_ns = 0
+        self._vote_shed = 0
+        # set while no vote is buffered or in a batch (_settle_votes)
+        self._vote_idle = asyncio.Event()
+        self._vote_idle.set()
         self._height_done = asyncio.Event()  # pulsed on every commit
         # reactor hooks: fn(event_name, payload); events: "step",
         # "proposal", "block_part", "vote", "has_vote", and the
@@ -147,10 +168,52 @@ class ConsensusState(Service):
     async def on_start(self) -> None:
         if self.wal is not None:
             await self._catchup_replay()
+        await self._load_programs()
         self.spawn(self._receive_routine(), name="cs-receive")
         if self.config.vote_batch_window_ms > 0:
             self.spawn(self._vote_scheduler(), name="cs-vote-batch")
         self._schedule_round0()
+
+    async def _load_programs(self) -> None:
+        """Before the first vote is taken: the device programs this
+        node's live path launches for its validator set, loaded off
+        the loop — the set's comb tables and the structured program at
+        vote_batch_max lanes (every launch of the vote scheduler and
+        of a LastCommit's unserved lanes: ValidatorSet.verify_live)
+        and the speculation plane's arena. A set without resident
+        tables loads nothing. After this the live path compiles
+        nothing, whatever lengths the timing cuts; a node that met a
+        cold program in the middle of a round held its executor for
+        ~50 s while the timeouts fired (PERF.md §6, PR 40). A load
+        that fails is logged and left to the first launch."""
+        vals = self.rs.validators
+        # (asked here, not in the executor: a set without tables must
+        # start without a turn of the loop, as it always has)
+        if vals is None or not vals.tables_resident():
+            return
+
+        def load() -> int:
+            programs = vals.load_live_programs(self.config.vote_batch_max)
+            if self.speculation is not None:
+                programs += self.speculation.load_programs(vals)
+            return programs
+
+        t0 = _time.perf_counter_ns()
+        try:
+            programs = await asyncio.get_running_loop().run_in_executor(
+                None, tracing.TRACER.wrap(load))
+        except Exception:
+            self.logger.exception("loading the live path's programs "
+                                  "failed; the first launches compile")
+            return
+        if programs:
+            seconds = (_time.perf_counter_ns() - t0) / 1e9
+            tracing.TRACER.begin(
+                tracing.CONSENSUS_LOAD_PROGRAMS, start_ns=t0,
+                validators=len(vals), programs=programs,
+                seconds=round(seconds, 3)).end()
+            self.logger.info("live path: %d programs loaded in %.1f s",
+                             programs, seconds)
 
     async def on_stop(self) -> None:
         self.ticker.stop()
@@ -293,33 +356,68 @@ class ConsensusState(Service):
     # -- the serialized event loop --
 
     async def _receive_routine(self) -> None:
+        now_ns = _time.perf_counter_ns
+        sources = (self.internal_msg_queue, self.peer_funnel,
+                   self.ticker.queue)
         while True:
-            internal = asyncio.ensure_future(self.internal_msg_queue.get())
-            peer = asyncio.ensure_future(self.peer_funnel.get())
-            timeout = asyncio.ensure_future(self.ticker.queue.get())
-            done, pending = await asyncio.wait(
-                [internal, peer, timeout],
-                return_when=asyncio.FIRST_COMPLETED,
-            )
-            for p in pending:
-                p.cancel()
+            # What is already queued is taken where it lies, one
+            # message of each source a turn (our own, then a peer's,
+            # then a timeout: a stream of votes never holds a timeout
+            # back), with one turn of the loop between two turns; only
+            # an idle routine waits on the three sources at once (three
+            # tasks made and two cancelled: a third of a vote's cost
+            # when that was every message's way in, PERF.md §6, PR 39).
+            internal, peer, timeout = (
+                q.get_nowait() if q.qsize() else None for q in sources)
+            if internal is None and peer is None and timeout is None:
+                waits = [asyncio.ensure_future(q.get()) for q in sources]
+                try:
+                    await asyncio.wait(
+                        waits, return_when=asyncio.FIRST_COMPLETED)
+                finally:
+                    for w in waits:
+                        if not w.done():
+                            w.cancel()
+                internal, peer, timeout = (
+                    w.result() if w.done() and not w.cancelled() else None
+                    for w in waits)
+            else:
+                await asyncio.sleep(0)
+            traced = tracing.TRACER.enabled   # no clock reads otherwise
+            t_woke = now_ns() if traced else 0
             try:
-                if internal in done:
-                    qm = internal.result()
+                if internal is not None:
+                    qm = internal
                     self._wal_write_sync(MsgInfo(
                         "", m.encode_consensus_msg(qm.msg)
                     ))
                     async with self._state_mtx:
                         await self._handle_msg(qm)
-                if peer in done:
-                    qm = peer.result()
+                if peer is not None:
+                    qm = peer
                     self._wal_write(MsgInfo(
-                        qm.peer_id, m.encode_consensus_msg(qm.msg)
+                        qm.peer_id, qm.raw if qm.raw is not None
+                        else m.encode_consensus_msg(qm.msg)
                     ))
+                    # one unit of consensus.receive: the reactor's
+                    # decode and marks, then this turn of the loop from
+                    # its wake: a vote's up to the scheduler's buffer; a
+                    # proposal's or a part's up to here, for what it
+                    # sets off (a block's validation) is a step's work,
+                    # with awaits
+                    is_vote = isinstance(qm.msg, m.VoteMessage)
+                    if not is_vote:
+                        if self._ahead_of_us(qm.msg):
+                            await self._settle_votes()
+                            t_woke = now_ns() if traced else 0
+                        if traced:
+                            self._trace_received(qm, t_woke)
                     async with self._state_mtx:
                         await self._handle_msg(qm)
-                if timeout in done:
-                    ti = timeout.result()
+                    if is_vote and traced:
+                        self._trace_received(qm, t_woke)
+                if timeout is not None:
+                    ti = timeout
                     self._wal_write_sync(ti)
                     async with self._state_mtx:
                         await self._handle_timeout(ti)
@@ -328,6 +426,33 @@ class ConsensusState(Service):
             except Exception:
                 self.logger.exception("consensus handler failed; halting")
                 raise
+
+    def _trace_received(self, qm: _QueuedMsg, t_woke: int) -> None:
+        """One unit of the folded consensus.receive: [t_woke, now)
+        and, before it, what the reactor spent on the message."""
+        tracing.TRACER.leaf(
+            tracing.CONSENSUS_RECEIVE, t_woke - qm.spent_ns,
+            fold_key=(tracing.CONSENSUS_RECEIVE, id(self)),
+            parent=self._ht_span)
+
+    def _ahead_of_us(self, msg) -> bool:
+        height = msg.proposal.height if isinstance(
+            msg, m.ProposalMessage) else getattr(msg, "height", 0)
+        return height > self.rs.height
+
+    async def _settle_votes(self) -> None:
+        """Hold the intake until every vote taken off the funnel so
+        far has its verdict and is tallied. The funnel hands messages
+        over in the order they came, but a vote goes on through the
+        scheduler (a window, a launch, the tally) while a proposal or
+        a part is handled at once: the next height's proposal can
+        overtake the precommits that end this one, find the node a
+        height behind and be dropped, to come again only by gossip.
+        Asked for just then: a message ahead of our height. (The
+        scheduler's hold ends once its buffer has stood still for
+        _HOLD_IDLE_WINDOWS windows, so an intake that waits here
+        ends it.)"""
+        await self._vote_idle.wait()
 
     def _wal_write(self, msg) -> None:
         if self.wal is not None and not self._replay_mode:
@@ -566,7 +691,8 @@ class ConsensusState(Service):
         else:
             try:
                 await self.block_exec.validate_block_async(
-                    self.state, rs.proposal_block)
+                    self.state, rs.proposal_block,
+                    self.config.vote_batch_max)
                 await self._sign_add_vote(
                     VoteType.PREVOTE, rs.proposal_block.hash(),
                     rs.proposal_block_parts.header(),
@@ -634,7 +760,8 @@ class ConsensusState(Service):
         if rs.proposal_block is not None and rs.proposal_block.hash() == bid.hash:
             try:
                 await self.block_exec.validate_block_async(
-                    self.state, rs.proposal_block)
+                    self.state, rs.proposal_block,
+                    self.config.vote_batch_max)
             except Exception as e:
                 self.logger.error("polka for invalid block: %r", e)
                 await self._sign_add_vote(VoteType.PRECOMMIT, b"", None)
@@ -761,7 +888,7 @@ class ConsensusState(Service):
 
         state_copy = self.state.copy()
         new_state, retain_height = await self.block_exec.apply_block(
-            state_copy, bid, block
+            state_copy, bid, block, self.config.vote_batch_max
         )
         if retain_height > 0:
             try:
@@ -942,11 +1069,15 @@ class ConsensusState(Service):
             # re-sends votes the votebits reconciliation shows we
             # still lack.
             CONTROLLER.shed("consensus.vote_buf")
+            self._vote_shed += 1
             self._vote_pending.set()  # make sure the drain is awake
             return True
         # vals rides along so the scheduler can route the batch
         # through the expanded structured path (validator-index lanes
         # against the SAME set pk was resolved from).
+        if not self._vote_buf:
+            self._vote_first_ns = _time.perf_counter_ns()
+            self._vote_idle.clear()
         self._vote_buf.append((vote, peer_id, pk, vals))
         m = self._tpu_metrics
         if m is None:
@@ -993,22 +1124,71 @@ class ConsensusState(Service):
         met = consensus_metrics()
         tmet = tpu_metrics()
         loop = asyncio.get_running_loop()
+
+        def full() -> bool:
+            # Early flush under pressure: once the buffer passes half
+            # its bound, waiting only deepens the backlog (and the
+            # shedding it causes) — verify NOW.
+            return (len(self._vote_buf) >= self.config.vote_batch_max
+                    or len(self._vote_buf) * 2 >= self.config.vote_buf_max)
+
         while True:
+            if not self._vote_buf:
+                self._vote_idle.set()   # the last batch is tallied
             await self._vote_pending.wait()
             t_window = _time.perf_counter()
             window = self.config.vote_batch_window_ms / 1e3
-            # Early flush under pressure: once the buffer passes half
-            # its bound, waiting out the batching window only deepens
-            # the backlog (and the shedding it causes) — verify NOW.
-            if window > 0 and \
-                    len(self._vote_buf) < self.config.vote_batch_max and \
-                    len(self._vote_buf) * 2 < self.config.vote_buf_max:
-                await asyncio.sleep(window)
-            batch, self._vote_buf = self._vote_buf, []
-            tmet.verify_queue_depth.set(0)
-            self._vote_pending.clear()
+            cut_by = "full" if full() else "idle"
+            if window > 0 and not full():
+                # A burst still arriving: votes queued in the funnel
+                # behind the receive routine. One window catches what
+                # that routine handles in 2 ms — a dozen votes of a
+                # 10,000-validator step, under the device threshold,
+                # so every batch was the host's and a height 20,000
+                # thread hops (PERF.md §6, PR 39). So the cut is held,
+                # window by window, while the funnel has votes and the
+                # batch is not full, _HOLD_WINDOWS at most, and no
+                # longer than _HOLD_IDLE_WINDOWS in a row that added
+                # nothing to the buffer: a stream of votes that add
+                # nothing (another height's, duplicates of what is
+                # tallied) and an intake that waits for this very
+                # batch (_settle_votes) end the hold within 10 ms, and
+                # a trickle cannot stretch it past a tenth of a step's
+                # timeout.
+                idle = 0
+                for _ in range(_HOLD_WINDOWS):
+                    seen = len(self._vote_buf)
+                    await asyncio.sleep(window)
+                    idle = 0 if len(self._vote_buf) > seen else idle + 1
+                    if full():
+                        cut_by = "full"
+                        break
+                    if not self.peer_funnel.high_depth() \
+                            or idle >= _HOLD_IDLE_WINDOWS:
+                        break
+                else:
+                    cut_by = "cap"
+            # vote_batch_max lanes a launch and no more: the rest of a
+            # deeper buffer is the next batch's, at once (the launch
+            # shapes a node meets then end at the bucket of
+            # vote_batch_max, and a tally holds the loop for one
+            # batch's worth of votes)
+            cut = self.config.vote_batch_max
+            batch, self._vote_buf = self._vote_buf[:cut], \
+                self._vote_buf[cut:]
+            shed, self._vote_shed = self._vote_shed, 0
+            tmet.verify_queue_depth.set(len(self._vote_buf))
+            first_ns = self._vote_first_ns
+            if self._vote_buf:
+                self._vote_first_ns = _time.perf_counter_ns()
+            else:
+                self._vote_pending.clear()
             if not batch:
                 continue
+            tracing.TRACER.begin(
+                tracing.CONSENSUS_VOTE_QUEUE_WAIT, parent=self._ht_span,
+                start_ns=first_ns, lanes=len(batch), shed=shed,
+                cut=cut_by).end()
             met.vote_batch_wait_seconds.observe(
                 _time.perf_counter() - t_window)
             try:
@@ -1088,15 +1268,17 @@ class ConsensusState(Service):
         executor, off the event loop).
 
         Lanes group by the validator set each vote resolved against
-        (current height vs last-commit precommits); each group routes
-        through ValidatorSet._batch_verify_lanes — the same
-        structured->bytes->host ladder every commit-verify call site
-        uses, so big all-ed25519 bursts hit the expanded comb tables
-        with device-assembled sign bytes (VoteSignBatch: one template
-        group per distinct (type, height, round, block_id)) instead of
-        shipping full sign-byte rows through the general kernel."""
+        (current height vs last-commit precommits); each group goes
+        through ValidatorSet.verify_live: for a set with resident comb
+        tables ONE structured launch of vote_batch_max lanes with
+        device-assembled sign bytes (VoteSignBatch: one template group
+        per distinct (type, height, round, block_id)), whatever the
+        batch's length from the device threshold up; for any other set
+        the structured->bytes->host ladder every commit-verify call
+        site uses. Its launches carry the ledger tag `votes`."""
         import numpy as _np
 
+        from ..crypto.tpu import ledger as tpu_ledger
         from ..types.sign_batch import VoteSignBatch
 
         verdicts = _np.zeros(len(batch), bool)
@@ -1113,15 +1295,13 @@ class ConsensusState(Service):
             def picked(pick, votes=votes):
                 return votes if pick is None else [votes[i] for i in pick]
 
-            msgs = vals.structured_or_bytes(
-                lanes,
-                lambda pick: VoteSignBatch(chain_id, picked(pick)),
-                lambda pick: [v.sign_bytes(chain_id)
-                              for v in picked(pick)],
-            )
-            _, group_verdicts = vals._batch_verify_lanes(
-                lanes, msgs, sigs)
-            verdicts[_np.asarray(idxs)] = _np.asarray(group_verdicts)
+            with tpu_ledger.workload("votes"):
+                verdicts[_np.asarray(idxs)] = vals.verify_live(
+                    lanes,
+                    lambda pick: VoteSignBatch(chain_id, picked(pick)),
+                    lambda pick: [v.sign_bytes(chain_id)
+                                  for v in picked(pick)],
+                    sigs, self.config.vote_batch_max)
         return verdicts
 
     async def _verify_and_commit_batch(self, batch, met, loop) -> None:
@@ -1141,11 +1321,16 @@ class ConsensusState(Service):
             else:
                 verdicts = self._batch_verdicts(batch, chain_id)
         per_peer: dict[str, list[int]] = {}  # peer -> [good, bad]
+        added = rejected = 0
+        tally = tracing.TRACER.begin(tracing.CONSENSUS_VOTE_TALLY,
+                                     parent=self._ht_span,
+                                     votes=len(batch))
         for (vote, peer_id, _, _), ok in zip(batch, verdicts):
             if peer_id:
                 counts = per_peer.setdefault(peer_id, [0, 0])
                 counts[0 if ok else 1] += 1
             if not ok:
+                rejected += 1
                 self.logger.debug(
                     "batch-verify rejected vote from %r (val %s)",
                     peer_id, vote.validator_address.hex(),
@@ -1157,11 +1342,14 @@ class ConsensusState(Service):
             # re-report trust for votes already processed here.
             try:
                 async with self._state_mtx:
-                    await self._try_add_vote(vote, peer_id,
-                                             preverified=True)
+                    added += await self._try_add_vote(vote, peer_id,
+                                                      preverified=True)
             except Exception:
                 self.logger.exception(
                     "dropping unprocessable vote from %r", peer_id)
+        tally.set_attr("added", added)
+        tally.set_attr("rejected", rejected)
+        tally.end()
         # Trust metric feedback on VERIFIED outcomes: credit good
         # lanes, debit rejected ones, disconnect on collapsed trust
         # (behaviour.py; a peer streaming well-formed-but-invalid
@@ -1230,7 +1418,19 @@ class ConsensusState(Service):
                 return False
             added = rs.last_commit.add_vote(vote, verify=verify)
             if added:
+                if self.speculation is not None:
+                    # the next block's LastCommit holds this precommit
+                    # too: at 10,000 validators a third of a commit
+                    # arrives after the +2/3, and a lane the plane
+                    # never saw is a lane the LastCommit check verifies
+                    # again, three times a height
+                    self.speculation.observe_precommit(vote)
                 self._publish_vote(vote)
+                # reference addVote fires EventVote here too, and the
+                # reactor tells every peer (broadcastHasVoteMessage):
+                # peers still see this node at vote.height until its
+                # next NewRoundStep
+                self._broadcast_has_vote(vote)
             return added
         if vote.height != rs.height:
             return False
@@ -1244,15 +1444,25 @@ class ConsensusState(Service):
             # handled inside: they poison the lane, never serve)
             self.speculation.observe_precommit(vote)
         self._publish_vote(vote)
-        self._broadcast("has_vote", m.HasVoteMessage(
-            vote.height, vote.round, int(vote.type), vote.validator_index
-        ))
+        self._broadcast_has_vote(vote)
 
         if vote.type == VoteType.PREVOTE:
             await self._on_prevote_added(vote)
         else:
             await self._on_precommit_added(vote)
         return True
+
+    def _broadcast_has_vote(self, vote: Vote) -> None:
+        traced = tracing.TRACER.enabled
+        t0 = _time.perf_counter_ns() if traced else 0
+        self._broadcast("has_vote", m.HasVoteMessage(
+            vote.height, vote.round, int(vote.type), vote.validator_index
+        ))
+        if traced:
+            tracing.TRACER.leaf(
+                tracing.CONSENSUS_HAS_VOTE, t0,
+                fold_key=(tracing.CONSENSUS_HAS_VOTE, id(self)),
+                parent=self._ht_span)
 
     def _publish_vote(self, vote: Vote) -> None:
         if self.event_bus is not None:
@@ -1426,7 +1636,9 @@ class ConsensusState(Service):
             return True
         return False
 
-    async def add_peer_msg(self, msg, peer_id: str) -> None:
+    async def add_peer_msg(self, msg, peer_id: str,
+                           raw: bytes | None = None,
+                           spent_ns: int = 0) -> None:
         """Priority-aware admission into the bounded funnel. High
         class blocks when full — backpressure onto the calling peer's
         recv loop, matching the reference's `cs.peerMsgQueue <-
@@ -1434,8 +1646,11 @@ class ConsensusState(Service):
         test pinned that a burst must slow the sender, not raise).
         Low class (block parts / catchup) sheds when full instead:
         missing parts are re-requested by gossip, and a data flood
-        must never wedge votes behind it."""
-        qm = _QueuedMsg(msg, peer_id)
+        must never wedge votes behind it. `raw`: the bytes `msg`
+        was decoded from, which the WAL then records as they are;
+        `spent_ns`: the caller's own time on it (its decode), which
+        consensus.receive counts in."""
+        qm = _QueuedMsg(msg, peer_id, raw, spent_ns)
         if self._funnel_class(msg):
             if self._shed_duplicate_vote(msg):
                 return

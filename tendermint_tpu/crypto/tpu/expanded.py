@@ -1112,7 +1112,7 @@ class ExpandedKeys:
     # overflow falls back to full bytes at the call site.
     _S_GROUPS = 32
 
-    def _prepare_structured(self, indices, sbatch, sigs):
+    def _prepare_structured(self, indices, sbatch, sigs, lanes=None):
         n = len(indices)
         assert len(sbatch) == n
         idx = self._check_idx(indices, len(sigs))
@@ -1138,6 +1138,11 @@ class ExpandedKeys:
             raise ValueError("templates too large for structured path")
         # sharded tables: no pre-pad — _route buckets per device
         bucket = n if self.sharded else self._bucket(n)
+        if lanes is not None and not self.sharded:
+            # the live consensus path: one lane count, one width
+            if n > lanes or width != self._S_WIDTHS[0]:
+                raise ValueError("batch does not fit the live program")
+            bucket = lanes
         pad = bucket - n
         sig_raw, well_formed = self._sig_rows(sigs, pad)
 
@@ -1227,12 +1232,18 @@ class ExpandedKeys:
                                       sharding=repl_s))
         return avals
 
-    def verify_structured(self, indices, sbatch, sigs) -> np.ndarray:
+    def verify_structured(self, indices, sbatch, sigs,
+                          lanes: int | None = None) -> np.ndarray:
         """verify() for commit votes in structured form: identical
         verdicts to verify(indices, sbatch.materialize(), sigs), but
         the device assembles the sign bytes from the commit-wide
         template + per-lane timestamp patch (types/sign_batch.py), so
-        the launch ships ~100 B/lane instead of ~330 B/lane."""
+        the launch ships ~100 B/lane instead of ~330 B/lane. `lanes`:
+        launch at exactly that many lanes, the batch padded up to them,
+        in place of _bucket's shape (the live consensus path,
+        ValidatorSet.verify_live: the one shape load_structured has
+        loaded); ValueError if the batch does not fit it. Key-range-
+        sharded tables bucket per device as ever."""
         n = len(indices)
         if n == 0:
             return np.zeros(0, bool)
@@ -1240,11 +1251,38 @@ class ExpandedKeys:
 
         def prepare():
             idx, fields, well_formed, width = self._prepare_structured(
-                indices, sbatch, sigs)
+                indices, sbatch, sigs, lanes)
             return (idx, fields, width), well_formed
 
         return self._traced_verify(n, "structured", prepare,
                                    self._launch_structured)
+
+
+    def load_structured(self, lanes: int) -> int:
+        """Compile (or load from the compile cache) and run once the
+        structured program at `lanes` lanes and the narrow width, over
+        these tables and lanes that verify nothing: what
+        verify_structured(lanes=...) launches from then on. Returns
+        the programs loaded: 1, or 0 where it was loaded already."""
+        from ...types.sign_batch import PATCH_W
+
+        width = self._S_WIDTHS[0]
+        if self.sharded:
+            return 0
+        _LIVE_LANES.add(lanes)
+        known = ("structured", lanes, width) in tv._COMPILED_SHAPES
+        kp = self._S_GROUPS
+        zeros = np.zeros
+        fields = dict(
+            sb=zeros((lanes, 64), np.uint8), s_ok=zeros(lanes, np.bool_),
+            pre=zeros((kp, 128), np.uint8), pre_len=zeros(kp, np.int32),
+            suf=zeros((kp, 64), np.uint8), suf_len=zeros(kp, np.int32),
+            patch=zeros((lanes, PATCH_W), np.uint8),
+            split=zeros(lanes, np.int32), patch_len=zeros(lanes, np.int32),
+            group=zeros(lanes, np.int32))
+        out = self._launch_structured(zeros(lanes, np.int32), fields, width)
+        out.block_until_ready()
+        return 0 if known else 1
 
 
 # -- process-wide LRU of expanded sets (one active + one in transition) --
@@ -1260,6 +1298,11 @@ _CACHE_MAX = 2
 _CACHE_LOCK = threading.Lock()
 _BUILDS: dict[bytes, threading.Event] = {}
 _WARM_THREAD = "expanded-warm"   # warm_async's thread, by name
+# the lane counts load_structured has been asked for in this process
+# (the live consensus path's): a set warmed after a validator-set
+# change gets the same programs over ITS tables (their shape is part
+# of the program) before its first vote arrives
+_LIVE_LANES: set[int] = set()
 
 
 def max_keys() -> int:
@@ -1385,7 +1428,9 @@ def warm_async(pubkeys: list[bytes]) -> threading.Thread:
 
     def build():
         try:
-            get_expanded(pubkeys)
+            exp = get_expanded(pubkeys)
+            for lanes in sorted(_LIVE_LANES):
+                exp.load_structured(lanes)
         except Exception:  # pragma: no cover - depends on device state
             from .. import batch as _batch
 

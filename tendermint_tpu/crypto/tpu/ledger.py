@@ -59,7 +59,7 @@ from collections import deque
 from . import backend as _backend
 
 # Workload tags (closed set; the lint and docs table enumerate it).
-WORKLOADS = ("consensus", "speculation", "admission", "light",
+WORKLOADS = ("consensus", "votes", "speculation", "admission", "light",
              "fastsync", "evidence", "probe", "bench")
 
 DEFAULT_CAPACITY = 512

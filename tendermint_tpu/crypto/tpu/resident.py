@@ -789,3 +789,32 @@ def make_arena(lanes: int, width: int = WIDTH):
     if _ARENA_SHARDS and mesh is not None:
         return MeshResidentArena(lanes, width, mesh=mesh)
     return ResidentArena(lanes, width)
+
+
+def load_programs(arena) -> int:
+    """Run once everything an arena's steady state launches, on an
+    arena that holds nothing yet: a splice of every delta bucket
+    (powers of two from _MIN_DELTA up to the capacity), the verify
+    launch at capacity, the clear. For whoever would rather pay the
+    compiles before the first precommit than at it (the speculation
+    plane, when consensus starts). The lanes spliced verify nothing
+    and are inactive again afterwards. Returns the programs run."""
+    sizes, k = [], _MIN_DELTA
+    while k < arena.capacity - 1:
+        sizes.append(k)
+        k <<= 1
+    sizes.append(arena.capacity - 1)
+    for k in sizes:
+        # as a height does it: the clear, then splices; a splice takes
+        # the buffers the clear or another splice left (on a mesh their
+        # placements are part of the program's key)
+        arena.deactivate_all()
+        for _ in range(2):
+            arena.splice(
+                np.arange(1, k + 1), np.zeros((k, 64), np.uint8),
+                np.zeros((k, PATCH_W), np.uint8), np.zeros(k, np.int32),
+                np.zeros(k, np.int32), np.zeros(k, np.int32))
+    with _ledger.workload("probe"):
+        arena.launch()
+    arena.deactivate_all()
+    return len(sizes) + 2

@@ -213,8 +213,11 @@ class PriorityFunnel:
         self._high_streak = 0
         self._seq = 0  # arrival order across both classes
         self._not_empty = asyncio.Event()
-        self._high_space = asyncio.Event()
-        self._high_space.set()
+        # producers held back by a full high queue, first come first
+        # admitted: a freed slot wakes ONE of them (an Event woke them
+        # all, and all but one went back to sleep: with ten peers
+        # behind a 10,000-validator step, ten task switches a message)
+        self._putters: collections.deque = collections.deque()
         self._controller.register(high_queue, lambda: len(self._high),
                                   high_capacity, owner=self)
         self._controller.register(low_queue, lambda: len(self._low),
@@ -241,40 +244,62 @@ class PriorityFunnel:
         return (len(self._high) >= ratio * self.high_capacity
                 or len(self._low) >= ratio * self.low_capacity)
 
-    async def get(self):
+    def get_nowait(self):
         """Next message — high class first; after LOW_SERVICE_INTERVAL
         consecutive high pops, serve a low item IF it arrived before
         every queued high item (aging that can never invert arrival
         order — see the class docstring for why that guard is
-        load-bearing). Single-consumer (the serialized receive
-        routine); safe against the consumer's wait-future being
-        cancelled between items."""
+        load-bearing). Raises QueueEmpty when there is none."""
+        aged_low = (self._low
+                    and self._high_streak >= self.LOW_SERVICE_INTERVAL
+                    and (not self._high
+                         or self._low[0][0] < self._high[0][0]))
+        if self._high and not aged_low:
+            _, item = self._high.popleft()
+            self._high_streak += 1
+            self._wake_putter()
+            return item
+        if self._low:
+            self._high_streak = 0
+            return self._low.popleft()[1]
+        raise asyncio.QueueEmpty
+
+    async def get(self):
+        """get_nowait, waiting for a message if there is none.
+        Single-consumer (the serialized receive routine); safe
+        against the consumer's wait-future being cancelled between
+        items."""
         while True:
-            aged_low = (self._low
-                        and self._high_streak >= self.LOW_SERVICE_INTERVAL
-                        and (not self._high
-                             or self._low[0][0] < self._high[0][0]))
-            if self._high and not aged_low:
-                _, item = self._high.popleft()
-                self._high_streak += 1
-                if len(self._high) < self.high_capacity:
-                    self._high_space.set()
-                return item
-            if self._low:
-                self._high_streak = 0
-                return self._low.popleft()[1]
-            self._not_empty.clear()
-            await self._not_empty.wait()
+            try:
+                return self.get_nowait()
+            except asyncio.QueueEmpty:
+                self._not_empty.clear()
+                await self._not_empty.wait()
 
     def _next_seq(self) -> int:
         self._seq += 1
         return self._seq
 
+    def _wake_putter(self) -> None:
+        while self._putters:
+            waiter = self._putters.popleft()
+            if not waiter.done():
+                waiter.set_result(None)
+                return
+
     async def put_high(self, item) -> None:
         """Blocking admit — backpressure onto the caller when full."""
         while len(self._high) >= self.high_capacity:
-            self._high_space.clear()
-            await self._high_space.wait()
+            waiter = asyncio.get_running_loop().create_future()
+            self._putters.append(waiter)
+            try:
+                await waiter
+            except BaseException:
+                # cancelled after it was woken: the slot is another's
+                if waiter.done() and not waiter.cancelled() and \
+                        len(self._high) < self.high_capacity:
+                    self._wake_putter()
+                raise
         self._high.append((self._next_seq(), item))
         self._not_empty.set()
 

@@ -184,6 +184,28 @@ CONSENSUS_PREVOTE_WAIT = register_kind("consensus.prevote_wait")
 CONSENSUS_PRECOMMIT = register_kind("consensus.precommit")
 CONSENSUS_COMMIT = register_kind("consensus.commit")
 CONSENSUS_VOTE_BATCH = register_kind("consensus.vote_batch")
+# Around it (consensus/state.py), one of each per micro-batch, never
+# one per vote: a 10,000-validator height is 20,000 votes and the ring
+# holds 16,384 entries. vote_queue_wait: first vote buffered -> the
+# scheduler's cut (attrs lanes, shed = votes dropped at the buffer's
+# bound since the last cut, cut = full | idle | cap: what ended the
+# hold); vote_tally: verdicts in hand -> the last
+# verified vote added under the state mutex (attrs votes, added,
+# rejected). receive and has_vote are FOLDED (Tracer.leaf with a
+# fold_key of the state machine's): every peer message's decode,
+# peer-state marks, WAL record and handling up to the vote buffer, and
+# every HasVote broadcast, summed into one entry a run (attrs n,
+# busy_ns; the entry's own length is the wall time the n were spread
+# over; a receive unit counts in the reactor's decode, which a burst
+# does ahead of the routine, so its busy_ns can pass that length).
+# load_programs: once, when consensus starts (attrs validators,
+# programs, seconds): the live path's device programs, loaded before
+# the first vote.
+CONSENSUS_LOAD_PROGRAMS = register_kind("consensus.load_programs")
+CONSENSUS_VOTE_QUEUE_WAIT = register_kind("consensus.vote_queue_wait")
+CONSENSUS_VOTE_TALLY = register_kind("consensus.vote_tally")
+CONSENSUS_RECEIVE = register_kind("consensus.receive")
+CONSENSUS_HAS_VOTE = register_kind("consensus.has_vote")
 
 _STEP_KINDS = {
     "PROPOSE": CONSENSUS_PROPOSE,
@@ -436,14 +458,17 @@ DEFAULT_CAPACITY = int(os.environ.get("TM_TPU_TRACE_CAPACITY", "16384"))
 # of caller time between them; writes of different blocks are far
 # apart and stay apart.
 LEAF_FOLD_NS = 2_000_000
+# leaf(fold_key=...): how many of the ring's newest entries are looked
+# through for the entry the key wrote last.
+LEAF_KEY_LOOKBACK = 32
 
 
 class Tracer:
     """Ring-buffered span recorder. Every change to the ring (a span's
-    append, leaf()'s fold of the newest entry, resize) and every copy
-    of it happens under one lock, so a snapshot never misses a span
-    another thread has sealed and entries keep the order they ended
-    in."""
+    append, leaf()'s fold into an entry, resize) and every copy of it
+    happens under one lock, so a snapshot never misses a span another
+    thread has sealed and entries keep the order they ended in (a
+    folded entry: the order its first unit ended in)."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  enabled: bool = True):
@@ -452,6 +477,7 @@ class Tracer:
         self._ring: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._dropped = 0
+        self._keyed: dict = {}   # leaf(fold_key=...): key -> its entry
         # tracing→metrics bridge: fn(kind, seconds) called on every
         # span close (libs/metrics.py installs span_metrics_sink on
         # the global TRACER). None = no bridge (private test tracers).
@@ -490,40 +516,68 @@ class Tracer:
         return Span(self, kind, parent.span_id if parent else 0,
                     attrs or None, start_ns)
 
-    def leaf(self, kind: str, start_ns: int, **sums) -> None:
+    def leaf(self, kind: str, start_ns: int, fold_key=None,
+             parent: Span | None = None, **sums) -> None:
         """Record a finished childless span [start_ns, now) under the
-        current span — the form for a site whose unit may be a tx (a
-        durable db commit). A repeat that follows the ring's newest
-        entry — same kind, parent and thread, begun within
-        LEAF_FOLD_NS of its end — extends that entry instead of
-        adding one: `n` counts the repeats, `busy_ns` sums their own
-        durations (the entry's duration then includes the caller's
-        time between them) and each of `sums` adds up. A tx-rate
-        writer thus costs the ring one entry per run, not one per tx."""
+        current span (or `parent`) — the form for a site whose unit
+        may be a tx (a durable db commit) or a peer message. A repeat
+        that follows the ring's newest entry — same kind, parent and
+        thread, begun within LEAF_FOLD_NS of its end — extends that
+        entry instead of adding one: `n` counts the repeats, `busy_ns`
+        sums their own durations (the entry's duration then includes
+        the caller's time between them) and each of `sums` adds up. A
+        tx-rate writer thus costs the ring one entry per run, not one
+        per tx.
+
+        `fold_key` (any hashable the owner picks): the repeat extends
+        the entry this key wrote last instead of the newest — for a
+        site whose repeats interleave with other spans (a vote's
+        receive between the scheduler's batches). The entry is looked
+        for among the ring's LEAF_KEY_LOOKBACK newest, so a run is cut
+        by a pause, by a new parent, or by that many spans sealed
+        since it began; it keeps the place in the ring its first unit
+        took."""
         if not self.enabled:
             return
         _require_registered(kind)
         t1 = time.perf_counter_ns()
         dur = t1 - start_ns
-        parent = _CURRENT.get()
+        if parent is None:
+            parent = _CURRENT.get()
         pid = parent.span_id if parent else 0
         tid = threading.get_ident()
         with self._lock:
             ring = self._ring
-            last = ring[-1] if ring else None
-            folds = (last is not None and last[0] == kind
+            at = 1 if ring else 0
+            if fold_key is None:
+                last = ring[-1] if ring else None
+                lo = 0    # a repeat begins after the newest has ended
+            else:
+                last = self._keyed.get(fold_key)
+                at = next((i for i in range(
+                    1, min(len(ring), LEAF_KEY_LOOKBACK) + 1)
+                    if ring[-i] is last), 0) if last is not None else 0
+                lo = -LEAF_FOLD_NS   # units of one owner may overlap
+            folds = (at and last[0] == kind
                      and last[2] == pid and last[3] == tid
-                     and 0 <= start_ns - (last[4] + last[5]) <= LEAF_FOLD_NS)
+                     and lo <= start_ns - (last[4] + last[5])
+                     <= LEAF_FOLD_NS)
             if folds:
                 attrs = dict(last[6] or ())
                 attrs["n"] = attrs.get("n", 1) + 1
                 attrs["busy_ns"] = attrs.get("busy_ns", last[5]) + dur
                 for k, v in sums.items():
                     attrs[k] = attrs.get(k, 0) + v
-                ring[-1] = (kind, last[1], pid, tid, last[4],
-                            t1 - last[4], attrs)
+                t0 = min(last[4], start_ns)
+                rec = (kind, last[1], pid, tid, t0,
+                       max(t1, last[4] + last[5]) - t0, attrs)
+                ring[-at] = rec
+            else:
+                rec = (kind, next(_ids), pid, tid, start_ns, dur, sums)
+            if fold_key is not None:
+                self._keyed[fold_key] = rec
         if not folds:
-            self._append((kind, next(_ids), pid, tid, start_ns, dur, sums))
+            self._append(rec)
         self._observe(kind, dur)
 
     def _append(self, rec: tuple) -> None:
@@ -588,6 +642,7 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._keyed.clear()
             self._dropped = 0
 
     def resize(self, capacity: int) -> None:

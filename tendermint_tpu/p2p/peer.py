@@ -57,6 +57,14 @@ class Peer:
     def send_rate(self) -> float:
         return self.mconn.send_rate()
 
+    def send_queue_depth(self) -> int:
+        """Messages queued on this peer's channels, not yet on the wire."""
+        return sum(ch.queue.qsize() for ch in self.mconn.channels.values())
+
+    def send_queue_capacity(self) -> int:
+        return sum(ch.desc.send_queue_capacity
+                   for ch in self.mconn.channels.values())
+
     def get(self, key: str):
         return self._kv.get(key)
 

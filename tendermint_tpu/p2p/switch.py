@@ -122,12 +122,10 @@ class Switch(Service):
         # aggregate p2p send-queue saturation for the overload level
         CONTROLLER.register(
             "p2p.send",
-            lambda: sum(ch.queue.qsize()
-                        for p in self.peers.values()
-                        for ch in p.mconn.channels.values()),
-            lambda: sum(ch.desc.send_queue_capacity
-                        for p in self.peers.values()
-                        for ch in p.mconn.channels.values()),
+            lambda: sum(p.send_queue_depth()
+                        for p in self.peers.values()),
+            lambda: sum(p.send_queue_capacity()
+                        for p in self.peers.values()),
             owner=self)
 
     async def on_stop(self) -> None:

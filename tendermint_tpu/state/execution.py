@@ -134,13 +134,19 @@ class BlockExecutor:
 
     # -- the apply path --
 
-    def validate_block(self, state: State, block: Block) -> None:
+    def validate_block(self, state: State, block: Block,
+                       live_lanes: int | None = None) -> None:
+        """`live_lanes` (here and below): the live consensus path's
+        lane count a launch, where that is the caller
+        (state/validation.py validate_block)."""
         with TRACER.span(tracing.VALIDATE_BLOCK,
                          height=block.header.height):
             validate_block(state, block, self.evpool,
-                           speculation=self.speculation)
+                           speculation=self.speculation,
+                           live_lanes=live_lanes)
 
-    async def validate_block_async(self, state: State, block: Block) -> None:
+    async def validate_block_async(self, state: State, block: Block,
+                                   live_lanes: int | None = None) -> None:
         """validate_block in a worker thread: the LastCommit signature
         batch runs on device without freezing the event loop (gossip,
         RPC and timeouts stay live during a mega-commit verify).
@@ -149,25 +155,28 @@ class BlockExecutor:
         import asyncio
 
         await asyncio.get_running_loop().run_in_executor(
-            None, TRACER.wrap(self.validate_block), state, block
-        )
+            None, TRACER.wrap(self.validate_block), state, block,
+            live_lanes)
 
     async def apply_block(self, state: State, block_id: BlockID,
-                          block: Block) -> tuple[State, int]:
+                          block: Block, live_lanes: int | None = None
+                          ) -> tuple[State, int]:
         """Returns (new_state, retain_height). Raises on invalid block."""
         from ..libs.metrics import state_metrics
 
         with state_metrics().block_processing_seconds.time(), \
                 TRACER.span(tracing.STATE_APPLY_BLOCK,
                             height=block.header.height):
-            return await self._apply_block(state, block_id, block)
+            return await self._apply_block(state, block_id, block,
+                                           live_lanes)
 
     async def _apply_block(self, state: State, block_id: BlockID,
-                           block: Block) -> tuple[State, int]:
+                           block: Block, live_lanes: int | None = None
+                           ) -> tuple[State, int]:
         # the seven state.* spans below are what a block costs the
         # host, in order
         with TRACER.span(tracing.STATE_VALIDATE):
-            await self.validate_block_async(state, block)
+            await self.validate_block_async(state, block, live_lanes)
 
         with TRACER.span(tracing.STATE_EXEC, txs=len(block.data.txs)):
             abci_responses = await self._exec_block_on_proxy_app(

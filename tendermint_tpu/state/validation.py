@@ -19,7 +19,10 @@ class BlockValidationError(Exception):
 
 
 def validate_block(state: State, block: Block, evidence_pool=None,
-                   speculation=None) -> None:
+                   speculation=None, live_lanes: int | None = None) -> None:
+    """`live_lanes`: the caller is the live consensus path, whose
+    signature launches all have that many lanes (the LastCommit's here:
+    ValidatorSet.verify_live)."""
     with TRACER.span(tracing.VALIDATE_BASIC):
         block.validate_basic()
     h = block.header
@@ -91,11 +94,11 @@ def validate_block(state: State, block: Block, evidence_pool=None,
                     served = speculation.serve_commit(
                         state.last_validators, state.chain_id,
                         state.last_block_id, h.height - 1,
-                        block.last_commit)
+                        block.last_commit, live_lanes)
                 if not served:
                     state.last_validators.verify_commit(
                         state.chain_id, state.last_block_id,
-                        h.height - 1, block.last_commit,
+                        h.height - 1, block.last_commit, live_lanes,
                     )
         except VerificationError as e:
             raise BlockValidationError(f"invalid LastCommit: {e}") from e

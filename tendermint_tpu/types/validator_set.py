@@ -428,17 +428,28 @@ class ValidatorSet:
         return self._tables_take(cols, int(np.count_nonzero(rows >= 0)))
 
     def _tables_take(self, cols: _SetColumns, ed_lanes: int) -> bool:
-        from ..crypto import batch as _batch
         from ..crypto.tpu import verify as tv
 
         # Above _MAX_BATCH a single launch is off the table (the
         # BatchVerifier fallback self-splits); e.g. a full fast-sync
-        # window at 10k validators. The valset-size cap is
-        # backend-dependent (expanded.max_keys: HBM budget on chips,
-        # one build chunk on the CPU backend where tables buy nothing).
-        if not (_EXPAND_MIN <= ed_lanes <= tv._MAX_BATCH
-                and not _batch.host_forced()
-                and _batch.device_available("ed25519")):
+        # window at 10k validators.
+        return _EXPAND_MIN <= ed_lanes <= tv._MAX_BATCH \
+            and self._tables_serve(cols)
+
+    def _tables_resident(self, cols: _SetColumns) -> bool:
+        """_tables_take's question asked of the SET, as the live
+        consensus path asks it (verify_live): does this set keep comb
+        tables on the device at all? Then every batch of its votes
+        from the device threshold up is theirs, whatever its length."""
+        return len(cols.ed_keys) >= _EXPAND_MIN and self._tables_serve(cols)
+
+    def _tables_serve(self, cols: _SetColumns) -> bool:
+        from ..crypto import batch as _batch
+
+        # The valset-size cap is backend-dependent (expanded.max_keys:
+        # HBM budget on chips, one build chunk on the CPU backend
+        # where tables buy nothing).
+        if _batch.host_forced() or not _batch.device_available("ed25519"):
             return False
         try:
             from ..crypto.tpu import expanded
@@ -520,18 +531,23 @@ class ValidatorSet:
         caller has read them."""
         if not len(slots):
             return []
-
-        def of(pick):
-            return slots if pick is None else np.asarray(slots)[pick]
-
         with TRACER.span(tracing.VERIFY_SIGN_BATCH, lanes=len(slots)):
             return self.structured_or_bytes(
-                lanes,
-                lambda pick: CommitSignBatch(chain_id, commit, of(pick),
+                lanes, *self._commit_builders(chain_id, commit, slots,
+                                              columns))
+
+    @staticmethod
+    def _commit_builders(chain_id: str, commit, slots, columns):
+        """structured_or_bytes' (build, materialize) over commit slots."""
+        slots = np.asarray(slots, np.intp)
+
+        def of(pick):
+            return slots if pick is None else slots[pick]
+
+        return (lambda pick: CommitSignBatch(chain_id, commit, of(pick),
                                              columns),
                 lambda pick: [commit.vote_sign_bytes(chain_id, s)
-                              for s in of(pick)],
-            )
+                              for s in of(pick)])
 
     def _batch_verify_lanes(self, lanes: list[int], msgs,
                             sigs: list[bytes], rows=None):
@@ -569,18 +585,11 @@ class ValidatorSet:
         # structured implies the tables were to take the batch when it
         # was built (structured_or_bytes)
         if structured or self._tables_take(cols, len(lanes)):
-            from ..crypto.tpu import expanded
             from ..libs import failpoints
 
             try:
                 failpoints.hit("device.verify")
-                with TRACER.span(tracing.VERIFY_TABLES,
-                                 keys=len(cols.ed_keys)) as tspan:
-                    held = cols.digest is not None
-                    tspan.set_attr("digest", "held" if held else "hashed")
-                    if not held:
-                        cols.digest = expanded.key_digest(cols.ed_keys)
-                    exp = expanded.get_expanded(cols.ed_keys, cols.digest)
+                exp = self._expanded(cols)
                 if structured:
                     try:
                         verdicts = exp.verify_structured(
@@ -616,6 +625,142 @@ class ValidatorSet:
         for i, m, s in zip(lanes, msgs, sigs):
             bv.add(self.validators[i].pub_key, m, s)
         return bv.verify()
+
+    def _expanded(self, cols: _SetColumns):
+        """The comb tables of this set's ed25519 keys, from the
+        process's cache or built now."""
+        from ..crypto.tpu import expanded
+
+        with TRACER.span(tracing.VERIFY_TABLES,
+                         keys=len(cols.ed_keys)) as tspan:
+            held = cols.digest is not None
+            tspan.set_attr("digest", "held" if held else "hashed")
+            if not held:
+                cols.digest = expanded.key_digest(cols.ed_keys)
+            return expanded.get_expanded(cols.ed_keys, cols.digest)
+
+    # -- the live consensus path: one lane count a launch --
+
+    def verify_live(self, lanes: list[int], build, materialize,
+                    sigs: list[bytes], launch_lanes: int) -> np.ndarray:
+        """Per-lane verdicts for votes verified as consensus runs (the
+        vote scheduler's micro-batches, the lanes of a LastCommit the
+        speculation plane holds no verdict for): structured_or_bytes
+        and _batch_verify_lanes in one, with `build` and `materialize`
+        as the former takes them.
+
+        For a set whose comb tables are resident (_tables_resident:
+        the question is the SET's here, not the batch's) every ed25519
+        launch is the structured program at exactly `launch_lanes`
+        lanes, the consensus config's vote_batch_max: a shorter batch
+        is padded, a longer one goes as several launches, and a tail
+        shorter than the device threshold stays on the host, as every
+        batch that short does. So a node meets ONE program here
+        whatever the timing cuts (load_live_programs loads it when
+        consensus starts), where _bucket's ladder is fourteen for a
+        10,000-key set and a cold one holds the executor for ~50 s in
+        the middle of a round. Lanes that do not fit the structured
+        layout (build's ValueError: hostile timestamps, too many
+        template groups, oversized sign bytes) are the host's. The
+        other key types of a mixed set go as they always do: one
+        BatchVerifier. Any other set takes the ordinary ladder."""
+        cols = self._columns()
+        if not self._tables_resident(cols):
+            msgs = self.structured_or_bytes(lanes, build, materialize)
+            return np.asarray(
+                self._batch_verify_lanes(lanes, msgs, sigs)[1], bool)
+        from ..crypto.batch import _DEVICE_THRESHOLD
+
+        verdicts = np.zeros(len(lanes), bool)
+        if cols.all_ed25519:
+            ed_pos = np.arange(len(lanes))
+        else:
+            ed_pos, rest_pos = self._lane_split(cols, lanes)
+            if len(rest_pos):
+                verdicts[rest_pos] = self._verify_host_or_general(
+                    lanes, rest_pos, materialize, sigs)
+        for lo in range(0, len(ed_pos), launch_lanes):
+            pick = ed_pos[lo:lo + launch_lanes]
+            on_tables = None
+            if len(pick) >= _DEVICE_THRESHOLD:
+                on_tables = self._verify_on_tables(
+                    cols, lanes, pick, build, sigs, launch_lanes)
+            verdicts[pick] = on_tables if on_tables is not None else \
+                self._verify_host_or_general(lanes, pick, materialize,
+                                             sigs, use_device=False)
+        return verdicts
+
+    def _verify_on_tables(self, cols, lanes, pick, build, sigs,
+                          launch_lanes: int):
+        """One structured launch of `launch_lanes` lanes over the lanes
+        at positions `pick`; None where the host has to take them (the
+        input does not fit the layout, or the device failed: then its
+        breaker is open as after any failed launch)."""
+        from ..crypto import batch as _batch
+        from ..libs import failpoints
+
+        try:
+            with TRACER.span(tracing.VERIFY_SIGN_BATCH, lanes=len(pick)):
+                sbatch = build(pick)
+        except ValueError:
+            return None
+        rows = [lanes[i] for i in pick]
+        if not cols.all_ed25519:
+            rows = cols.ed_row[rows]
+        try:
+            failpoints.hit("device.verify")
+            return self._expanded(cols).verify_structured(
+                rows, sbatch, [sigs[i] for i in pick], lanes=launch_lanes)
+        except ValueError:
+            _batch.logger.exception(
+                "structured vote verify rejected the batch (%d lanes); "
+                "host path", len(pick))
+        except Exception:
+            _batch.mark_device_failed("ed25519")
+            _batch.logger.exception(
+                "expanded-valset verify failed (%d lanes); degrading",
+                len(pick))
+        return None
+
+    def _verify_host_or_general(self, lanes, pick, materialize, sigs,
+                                use_device=None) -> np.ndarray:
+        """The lanes at positions `pick` through a BatchVerifier: on
+        the host (`use_device` False: verify_live's ed25519 lanes that
+        the tables did not take) or wherever it sends their key type."""
+        bv = BatchVerifier(use_device=use_device)
+        for i, msg in zip(pick, materialize(pick)):
+            bv.add(self.validators[lanes[i]].pub_key, msg, sigs[i])
+        return bv.verify()[1]
+
+    def tables_resident(self) -> bool:
+        """Does the live path verify this set's votes on resident
+        comb tables (verify_live)?"""
+        return self._tables_resident(self._columns())
+
+    def load_live_programs(self, launch_lanes: int) -> int:
+        """Load what verify_live launches for this set, its tables
+        built first if they are not: called when consensus starts, so
+        that no vote waits for a compile. Returns the programs loaded
+        (0: the set has no resident tables and verify_live launches
+        nothing of its own)."""
+        cols = self._columns()
+        if not self._tables_resident(cols):
+            return 0
+        return self._expanded(cols).load_structured(launch_lanes)
+
+    def verify_commit_lanes_live(self, chain_id: str, commit, slots,
+                                 launch_lanes: int,
+                                 columns: CommitColumns | None = None
+                                 ) -> np.ndarray:
+        """verify_live over the given slots of a commit (the lanes of
+        a LastCommit that still need a verdict). `columns`: the
+        commit's, where the caller has read them."""
+        if columns is None:
+            columns = CommitColumns(commit)
+        return self.verify_live(
+            [int(s) for s in slots],
+            *self._commit_builders(chain_id, commit, slots, columns),
+            [commit.signatures[s].signature for s in slots], launch_lanes)
 
     def _verify_split(self, cols: _SetColumns, lanes, msgs, sigs):
         """_batch_verify_lanes for a set of several key types: the
@@ -659,14 +804,18 @@ class ValidatorSet:
                 int(tally[len(slots) - 1]))
 
     def verify_commit(self, chain_id: str, block_id: BlockID, height: int,
-                      commit) -> None:
+                      commit, launch_lanes: int | None = None) -> None:
         """Verify ALL non-absent signatures; tally for-block power must
-        exceed 2/3 (reference: validator_set.go:662)."""
+        exceed 2/3 (reference: validator_set.go:662). `launch_lanes`:
+        the caller is the live consensus path, whose launches have
+        that many lanes each (verify_live)."""
         with TRACER.span(tracing.VERIFY_COMMIT, form="full") as span:
-            self._verify_commit(chain_id, block_id, height, commit, span)
+            self._verify_commit(chain_id, block_id, height, commit, span,
+                                launch_lanes)
 
     def _verify_commit(self, chain_id: str, block_id: BlockID,
-                       height: int, commit, span) -> None:
+                       height: int, commit, span,
+                       launch_lanes: int | None = None) -> None:
         with TRACER.span(tracing.VERIFY_COLLECT):
             self._check_commit_basics(block_id, height, commit)
             cols = CommitColumns(commit)
@@ -679,10 +828,15 @@ class ValidatorSet:
             lanes = slots.tolist()
             sigs = cols.signatures(cols.present)
             tallied = int(mine.power[cols.for_block].sum())
-        msgs = self._commit_msgs(chain_id, commit, slots, lanes, cols)
         span.set_attr("lanes", len(lanes))
-        span.set_attr("structured", _is_structured(msgs))
-        ok, verdicts = self._batch_verify_lanes(lanes, msgs, sigs)
+        if launch_lanes:
+            verdicts = self.verify_commit_lanes_live(
+                chain_id, commit, slots, launch_lanes, cols)
+            ok = bool(verdicts.all())
+        else:
+            msgs = self._commit_msgs(chain_id, commit, slots, lanes, cols)
+            span.set_attr("structured", _is_structured(msgs))
+            ok, verdicts = self._batch_verify_lanes(lanes, msgs, sigs)
         if not ok:
             bad = [lanes[i] for i in range(len(lanes)) if not verdicts[i]]
             raise VerificationError(f"invalid signature(s) at index(es) {bad}")

@@ -357,3 +357,203 @@ def test_timeout_propose_event_fires_when_proposer_absent(tmp_path):
             await node.stop()
 
     asyncio.run(go())
+
+
+def _stray_vote(gdoc, height=999):
+    """A well-formed vote the node cannot place (a far height): the
+    cheapest message the receive routine can be kept busy with."""
+    from tendermint_tpu.types.block import BlockID, PartSetHeader
+    from tendermint_tpu.types.vote import Vote, VoteType
+
+    val = gdoc.validator_set().validators[0]
+    return Vote(VoteType.PREVOTE, height, 0,
+                BlockID(b"\x11" * 32, PartSetHeader(1, b"\x22" * 32)),
+                1_700_000_000_000_000_000, val.address, 0, b"\x33" * 64)
+
+
+def test_a_stream_of_votes_does_not_hold_a_timeout_back():
+    """The receive routine takes one message of EACH source a turn: a
+    funnel that never runs empty (10,000 validators' votes behind ten
+    peers) must not keep the propose timeout from firing."""
+    async def go():
+        from tendermint_tpu.consensus.cstypes import RoundStep
+
+        gdoc, _ = make_genesis(4)
+        node = Node(gdoc, None)      # follows; nobody proposes
+        await node.start()
+        cs = node.cs
+        msg = m.VoteMessage(_stray_vote(gdoc))
+        handed = 0
+
+        async def flood():
+            nonlocal handed
+            while True:
+                await cs.add_peer_msg(msg, "flood")   # back-pressure
+                handed += 1
+
+        floods = [asyncio.ensure_future(flood()) for _ in range(4)]
+        try:
+            loop = asyncio.get_running_loop()
+            deadline = loop.time() + 20
+            while cs.rs.step < RoundStep.PREVOTE:
+                assert loop.time() < deadline, (cs.rs.step, handed)
+                await asyncio.sleep(0.05)
+            # the funnel was full all the while
+            assert cs.peer_funnel.high_depth() > 0 and handed > 1000
+        finally:
+            for f in floods:
+                f.cancel()
+            await asyncio.gather(*floods, return_exceptions=True)
+            await node.stop()
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("kind", ["stale_height", "tallied_duplicate"])
+def test_net_commits_under_a_stream_of_votes_that_add_nothing(kind):
+    """The scheduler holds its cut for a burst that is still arriving,
+    and only for that: a peer that keeps the funnel's vote class from
+    ever running empty with votes that add nothing to the buffer (a
+    far height's, dropped by the sync path; copies of a precommit the
+    LastCommit holds, dropped as duplicates) must not keep the four
+    validators' own votes, far from a full batch, from being cut and
+    tallied (REVIEW, PR 39: the hold had no deadline)."""
+    async def go():
+        from tendermint_tpu.types.vote import Vote, VoteType
+
+        gdoc, pvs = make_genesis(4)
+        nodes = [Node(gdoc, pv) for pv in pvs]
+        for n in nodes:
+            await n.start()
+        wire_network(nodes)
+        cs = nodes[0].cs
+        handed = 0
+
+        def useless() -> m.VoteMessage:
+            if kind == "stale_height":
+                return m.VoteMessage(_stray_vote(gdoc))
+            # a precommit of the height just committed, as the seen
+            # commit has it: a LastCommit vote the node holds already
+            h = cs.rs.height - 1
+            seen = cs.block_store.load_seen_commit(h)
+            idx, sig = next((i, c) for i, c in enumerate(seen.signatures)
+                            if not c.is_absent())
+            return m.VoteMessage(Vote(
+                VoteType.PRECOMMIT, h, seen.round,
+                sig.block_id_for(seen.block_id), sig.timestamp,
+                sig.validator_address, idx, sig.signature))
+
+        async def flood():
+            # never empty, never full: wire_network's hooks (the real
+            # votes) raise on a full funnel
+            nonlocal handed
+            while True:
+                if cs.peer_funnel.high_depth() < 64:
+                    msg = useless()
+                    for _ in range(64):
+                        cs.add_peer_msg_nowait(msg, "flood")
+                    handed += 64
+                await asyncio.sleep(0)
+
+        try:
+            await cs.wait_for_height(1, timeout=60)
+            flooder = asyncio.ensure_future(flood())
+            try:
+                h0 = cs.rs.height
+                await cs.wait_for_height(h0 + 4, timeout=60)
+                assert not flooder.done(), flooder.exception()
+                assert cs.peer_funnel.high_depth() > 0 and handed >= 512
+            finally:
+                flooder.cancel()
+                await asyncio.gather(flooder, return_exceptions=True)
+        finally:
+            for n in nodes:
+                await n.stop()
+
+    asyncio.run(go())
+
+
+def test_a_trickle_cannot_stretch_the_hold_past_its_windows(monkeypatch):
+    """Votes that DO add to the buffer, one in every window the
+    scheduler holds, with the funnel never empty: the hold ends after
+    _HOLD_WINDOWS windows all the same and the batch is cut (garbage
+    signatures under a validator's index cost the sender nothing)."""
+    async def go():
+        from tendermint_tpu.consensus import state as cstate
+        from tendermint_tpu.crypto import batch
+        from tendermint_tpu.libs import tracing
+
+        gdoc, _ = make_genesis(4)
+        node = Node(gdoc, None)      # follows; nobody proposes
+        await node.start()
+        cs = node.cs
+        prev = batch.set_force_host(True)
+        tracing.TRACER.clear()
+        stray = m.VoteMessage(_stray_vote(gdoc))
+        window = cs.config.vote_batch_window_ms / 1e3
+        real_sleep = asyncio.sleep
+
+        async def sleep(delay, *args):
+            if delay == window:      # the scheduler's: one more lane
+                # placeable (our height, validator 0), never tallied:
+                # every copy is one more lane of the buffer
+                assert cs._enqueue_vote(
+                    _stray_vote(gdoc, height=cs.rs.height), "trickle")
+                while cs.peer_funnel.high_depth() < 512:
+                    cs.add_peer_msg_nowait(stray, "flood")
+            return await real_sleep(delay, *args)
+
+        def cuts():
+            return [r[6]["lanes"] for r in tracing.TRACER.snapshot()
+                    if r[0] == tracing.CONSENSUS_VOTE_QUEUE_WAIT]
+
+        try:
+            monkeypatch.setattr(asyncio, "sleep", sleep)
+            deadline = asyncio.get_running_loop().time() + 30
+            while len(cuts()) < 3:
+                assert asyncio.get_running_loop().time() < deadline, cuts()
+                if not cs._vote_buf:     # wakes the scheduler
+                    assert cs._enqueue_vote(_stray_vote(
+                        gdoc, height=cs.rs.height), "trickle")
+                await real_sleep(0.01)
+            # a lane a window and the one that woke the scheduler
+            assert max(cuts()) <= cstate._HOLD_WINDOWS + 2, cuts()
+            assert cs.peer_funnel.high_depth() > 0
+        finally:
+            monkeypatch.setattr(asyncio, "sleep", real_sleep)
+            batch.set_force_host(prev)
+            await node.stop()
+
+    asyncio.run(go())
+
+
+def test_wal_records_a_peer_message_as_it_came_off_the_wire(tmp_path):
+    """The reactor hands the funnel the bytes beside the decoded
+    message, and the WAL records THOSE before the message is handled:
+    no second encoding of 20,000 votes a height, and what a replay
+    decodes is what the peer sent."""
+    async def go():
+        gdoc, _ = make_genesis(4)
+        node = Node(gdoc, None, tmp_path)
+        await node.start()
+        vote = _stray_vote(gdoc, height=1)
+        canonical = m.encode_consensus_msg(m.VoteMessage(vote))
+        # a field this build does not know: decoded over, kept on disk
+        raw = canonical + b"\x70\x01"
+        assert m.decode_consensus_msg(raw).vote == vote
+        await node.cs.add_peer_msg(m.decode_consensus_msg(raw), "wire", raw)
+        await node.cs.add_peer_msg(m.VoteMessage(vote), "hook")   # no bytes
+        for _ in range(200):
+            if node.cs.peer_funnel.qsize() == 0:
+                break
+            await asyncio.sleep(0.01)
+        await asyncio.sleep(0.05)
+        await node.stop()
+        from tendermint_tpu.consensus.wal import MsgInfo
+
+        seen = {r.msg.peer_id: r.msg.msg_bytes
+                for r in WAL.decode_all(node.wal_path)
+                if isinstance(r.msg, MsgInfo)}
+        assert seen["wire"] == raw and seen["hook"] == canonical
+
+    asyncio.run(go())

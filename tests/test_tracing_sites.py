@@ -134,6 +134,69 @@ def test_leaf_does_not_fold_across_parents_pauses_or_kinds():
     assert all("n" not in w[6] for w in writes)
 
 
+def test_keyed_leaf_folds_across_other_spans():
+    """`fold_key`: a site whose repeats interleave with other spans
+    (a vote's receive between the scheduler's batches) folds into the
+    entry its key wrote last, under the parent it names, and units
+    that overlap (stamped before the last one ended) fold too."""
+    import time
+
+    t = Tracer(capacity=256)
+    now = time.perf_counter_ns
+    root = t.begin(tracing.CONSENSUS_HEIGHT, parent=tracing.NOOP_SPAN)
+    for i in range(6):
+        with t.span(tracing.CRYPTO_PACK):      # another span between
+            t0 = now()
+        t.leaf(tracing.CONSENSUS_RECEIVE, t0 - 1000, fold_key="a",
+               parent=root)
+        t.leaf(tracing.CONSENSUS_HAS_VOTE, now(), fold_key="b",
+               parent=root)
+    recs = t.snapshot()
+    for kind in (tracing.CONSENSUS_RECEIVE, tracing.CONSENSUS_HAS_VOTE):
+        (rec,) = [r for r in recs if r[0] == kind]
+        assert rec[2] == root.span_id and rec[6]["n"] == 6
+        assert 0 < rec[6]["busy_ns"] <= rec[5]
+    assert sum(r[0] == tracing.CRYPTO_PACK for r in recs) == 6
+    # the entry keeps the place its first unit took
+    assert [r[0] for r in recs][:3] == [
+        tracing.CRYPTO_PACK, tracing.CONSENSUS_RECEIVE,
+        tracing.CONSENSUS_HAS_VOTE]
+
+
+@pytest.mark.parametrize("cut", ["parent", "pause", "lookback", "clear"])
+def test_keyed_leaf_run_ends(cut):
+    """What ends a keyed run: another parent, a pause past
+    LEAF_FOLD_NS, LEAF_KEY_LOOKBACK spans sealed since it began, a
+    cleared ring."""
+    import time
+
+    t = Tracer(capacity=256)
+    now = time.perf_counter_ns
+    a = t.begin(tracing.CONSENSUS_HEIGHT, parent=tracing.NOOP_SPAN)
+    b = t.begin(tracing.CONSENSUS_HEIGHT, parent=tracing.NOOP_SPAN)
+    t.leaf(tracing.CONSENSUS_RECEIVE, now(), fold_key="k", parent=a)
+    t.leaf(tracing.CONSENSUS_RECEIVE, now(), fold_key="k", parent=a)
+    start, parent = now(), a
+    if cut == "parent":
+        parent = b
+    elif cut == "pause":
+        last = t.snapshot()[-1]
+        start = last[4] + last[5] + tracing.LEAF_FOLD_NS + 1
+    elif cut == "lookback":
+        for _ in range(tracing.LEAF_KEY_LOOKBACK):
+            with t.span(tracing.CRYPTO_PACK):
+                pass
+        start = now()
+    else:
+        t.clear()
+    t.leaf(tracing.CONSENSUS_RECEIVE, start, fold_key="k", parent=parent)
+    t.leaf(tracing.CONSENSUS_RECEIVE, now() if cut != "pause" else start,
+           fold_key="k", parent=parent)       # the new run folds on
+    got = [(r[6] or {}).get("n", 1) for r in t.snapshot()
+           if r[0] == tracing.CONSENSUS_RECEIVE]
+    assert got == ([2] if cut == "clear" else [2, 2])
+
+
 def test_leaf_counts_a_drop_when_the_ring_is_full():
     import time
 

@@ -69,6 +69,8 @@ REQUIRED_KINDS = frozenset({
     "crypto.table_build", "crypto.table_wait", "sync.window_cut",
     "verify.lane_split", "crypto.sr_merlin",
     "evidence.check", "evidence.collect", "evidence.update",
+    "consensus.vote_queue_wait", "consensus.vote_tally",
+    "consensus.receive", "consensus.has_vote", "consensus.load_programs",
     # height forensics reads these two by name: recv spans carry the
     # rehydrated origin tags, send_flush is the wire-side counterpart
     "p2p.recv_msg", "p2p.send_flush",
