@@ -7,13 +7,15 @@ and, later, the consensus reactor channels."""
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import re
+import threading
+from dataclasses import dataclass, field
 
 from ..types.block import BlockID, Part, block_id_writer, read_block_id
 from ..encoding.proto import Reader, Writer
 from ..libs.bits import BitArray
 from ..types.proposal import Proposal
-from ..types.vote import Vote
+from ..types.vote import Vote, VoteType
 
 
 @dataclass
@@ -62,6 +64,9 @@ class BlockPartMessage:
 class VoteMessage:
     vote: Vote
     origin: bytes | None = None
+    # how it was decoded, no part of what it says: True where
+    # decode_consensus_msg read the wire bytes by their shape
+    shaped: bool = field(default=False, compare=False, repr=False)
 
 
 @dataclass
@@ -189,9 +194,137 @@ def encode_consensus_msg(msg) -> bytes:
     return bytes([tag]) + w.finish()
 
 
+# A VoteMessage read by its SHAPE. A height of a 10,000-validator chain
+# hands the reactor ~26,000 of them, and every encoder that writes
+# canonical proto3 (this module's, a Go peer's marshaller) lays one out
+# the same way: fields in ascending order, a zero left out, every
+# varint minimal. One compiled pattern over that layout takes the
+# fields apart in a single C-level pass,
+#
+#   06 0a len | 08 type | [10 height] | [18 round] | [22 48 block_id[72]
+#   | 22 02 12 00] | [2a len [08 secs] [10 nanos]] | 32 14 addr[20]
+#   | [38 index] | 42 40 sig[64] | [7a len origin]
+#
+# and the bytes ALONE pick the path: whatever the pattern does not
+# match exactly (an unknown or repeated field, another order, a varint
+# with a spare byte or of ten, a type outside {1, 2}, an address not of
+# 20 bytes, a signature not of 64, a block_id of another size, a length
+# that disagrees with what it covers) goes to the general decoder
+# whole, which accepts or raises as it always did. The varints the
+# pattern takes are 1-9 bytes with a last byte that is not zero: under
+# 2^63, so no sign and no overflow is left to check.
+_UV = rb"[\x80-\xff]{0,8}[\x01-\x7f]"
+_VOTE_SHAPE = re.compile(
+    rb"\x06\x0a([\x01-\x7f]|[\x80-\xff][\x01-\x7f])"
+    rb"\x08([\x01\x02])"
+    rb"(?:\x10(" + _UV + rb"))?"
+    rb"(?:\x18(" + _UV + rb"))?"
+    rb"(?:\x22(\x48.{72}|\x02\x12\x00))?"
+    rb"(?:\x2a([\x00-\x14])((?:\x08(" + _UV + rb"))?(?:\x10(" + _UV + rb"))?))?"
+    rb"\x32\x14(.{20})"
+    rb"(?:\x38(" + _UV + rb"))?"
+    rb"\x42\x40(.{64})"
+    rb"(?:\x7a([\x01-\x7f])(.+))?",
+    re.DOTALL)
+_VOTE_TYPE = {bytes([t]): t for t in VoteType}
+
+# The 20,000 votes of a height name one block (and nil): their BlockID
+# comes from this map, keyed by the sub-message's bytes (behind their
+# length byte) and built by the general reader on a miss. BlockID and
+# PartSetHeader are frozen, so one object serves every vote; the bound
+# (oldest out) is what a peer that invents block IDs can make it hold.
+# Votes are never shared.
+_BLOCK_IDS: dict[bytes, BlockID] = {}
+_BLOCK_IDS_MAX = 8
+_block_ids_lock = threading.Lock()
+
+# VoteMessages decoded by their shape / handed to the general decoder:
+# a process's running count for whoever asks (the tests; a restart's
+# WAL catch-up, whose messages nobody else sees). No path reads it: a
+# message says how it was decoded itself (VoteMessage.shaped).
+_vote_decodes = [0, 0]
+
+
+def vote_decode_counts() -> tuple[int, int]:
+    """(shaped, general): how many VoteMessages this process decoded
+    by their layout, and how many it left to the general decoder
+    (which may have refused them)."""
+    return _vote_decodes[0], _vote_decodes[1]
+
+
+def _uvarint(b: bytes) -> int:
+    """A varint the pattern took: minimal, under 2^63."""
+    if len(b) == 1:
+        return b[0]
+    if len(b) == 2:     # a vote's length, an index past 127
+        return b[0] & 0x7F | b[1] << 7
+    v = 0
+    for c in reversed(b):
+        v = v << 7 | c & 0x7F
+    return v
+
+
+def _interned_block_id(raw: bytes) -> BlockID | None:
+    """The map's miss: the general reader's BlockID for the sub-message
+    behind `raw`'s length byte (None where it refuses the bytes: the
+    general decoder then does too)."""
+    try:
+        bid = read_block_id(raw[1:])
+    except ValueError:
+        return None
+    with _block_ids_lock:
+        if len(_BLOCK_IDS) >= _BLOCK_IDS_MAX:
+            del _BLOCK_IDS[next(iter(_BLOCK_IDS))]
+        _BLOCK_IDS[raw] = bid
+    return bid
+
+
+def _decode_shaped_vote(data: bytes) -> "VoteMessage | None":
+    """`data` as a VoteMessage if it has the canonical layout, else
+    None (and the general decoder says what it is)."""
+    mt = _VOTE_SHAPE.fullmatch(data)
+    if mt is None:
+        return None
+    (ln, vtype, height, round_, raw_id, ts_len, ts, secs, nanos,
+     addr, index, sig, origin_len, origin) = mt.groups()
+    # the two outer lengths, against the bytes they cover
+    covered = len(data) - 2 - len(ln)
+    if origin is not None:
+        if origin_len[0] != len(origin):
+            return None
+        covered -= 2 + len(origin)
+    if _uvarint(ln) != covered:
+        return None
+    if ts_len is None:
+        stamp = 0
+    elif ts_len[0] != len(ts):
+        return None
+    else:
+        stamp = (_uvarint(secs) * 1_000_000_000 if secs else 0) + (
+            _uvarint(nanos) if nanos else 0)
+    if raw_id is None:
+        block_id = None
+    else:
+        block_id = _BLOCK_IDS.get(raw_id)
+        if block_id is None:
+            block_id = _interned_block_id(raw_id)
+            if block_id is None:
+                return None
+    return VoteMessage(Vote(
+        _VOTE_TYPE[vtype], _uvarint(height) if height else 0,
+        _uvarint(round_) if round_ else 0, block_id, stamp, addr,
+        _uvarint(index) if index else 0, sig), origin, True)
+
+
 def decode_consensus_msg(data: bytes):
     if not data:
         raise ValueError("empty consensus message")
+    if data[0] == 6:
+        msg = _decode_shaped_vote(data)
+        if msg is not None:
+            _vote_decodes[0] += 1
+            return msg
+        _vote_decodes[1] += 1
     cls = _BY_TAG.get(data[0])
     if cls is None:
         raise ValueError(f"unknown consensus message tag {data[0]}")

@@ -433,7 +433,9 @@ class ConsensusState(Service):
         tracing.TRACER.leaf(
             tracing.CONSENSUS_RECEIVE, t_woke - qm.spent_ns,
             fold_key=(tracing.CONSENSUS_RECEIVE, id(self)),
-            parent=self._ht_span)
+            parent=self._ht_span,
+            decode_ms=qm.spent_ns / 1e6,
+            shaped=int(getattr(qm.msg, "shaped", False)))
 
     def _ahead_of_us(self, msg) -> bool:
         height = msg.proposal.height if isinstance(
@@ -1649,7 +1651,9 @@ class ConsensusState(Service):
         must never wedge votes behind it. `raw`: the bytes `msg`
         was decoded from, which the WAL then records as they are;
         `spent_ns`: the caller's own time on it (its decode), which
-        consensus.receive counts in."""
+        consensus.receive counts in, and sums beside as `decode_ms`
+        (with `shaped`, the VoteMessages that were decoded by their
+        shape and say so themselves)."""
         qm = _QueuedMsg(msg, peer_id, raw, spent_ns)
         if self._funnel_class(msg):
             if self._shed_duplicate_vote(msg):

@@ -7,6 +7,7 @@ and of the benchmark cell `consensus10k.live`: the same node, the same
 peers, 64 and 256 validators instead of 10,000."""
 
 import asyncio
+import json
 import os
 
 import pytest
@@ -77,11 +78,24 @@ def node_config(tmp_path, gdoc, **consensus):
     return cfg
 
 
+async def settled(cs, net):
+    """The peers hand over nothing more; what the reactor had in its
+    hands is through the funnel and what the scheduler held is
+    tallied: the node acknowledges nothing after this returns."""
+    net.pause()
+    quiet = 0
+    while quiet < 3:
+        await asyncio.sleep(0.05)
+        quiet = quiet + 1 if cs._vote_idle.is_set() and \
+            not cs.peer_funnel.qsize() else 0
+
+
 async def follow(tmp_path, chain, peers=4, upto=None, timeout=120,
-                 query_maj23_s=2.0, **consensus):
+                 query_maj23_s=2.0, settle=False, **consensus):
     """Boot the ordinary node, attach the scripted net, wait until the
     node has PROPOSED height `upto` + 1 (so `upto`'s late precommits
-    are in), stop; return what there is to compare."""
+    are in), stop (`settle`: once the node has finished what it was
+    handed); return what there is to compare."""
     upto = upto or len(chain.heights) - 1
     cfg = node_config(tmp_path, chain.gdoc, timeout_commit_ms=150,
                       **consensus)
@@ -99,6 +113,8 @@ async def follow(tmp_path, chain, peers=4, upto=None, timeout=120,
             assert asyncio.get_running_loop().time() < deadline, \
                 (cs.rs.height, cs.rs.step, net.handed_over())
             await asyncio.sleep(0.02)
+        if settle:
+            await settled(cs, net)
         out = {
             "watch": watch, "net": net, "acked": net.acknowledged(),
             "block_ids": {h: node.block_store.load_block_meta(h).block_id
@@ -400,7 +416,10 @@ def test_vote_path_spans_are_one_a_batch_not_one_a_vote(tmp_path):
 
     chain = make_chain(64, 4, seed=5)
     tracing.TRACER.clear()
-    got = asyncio.run(follow(tmp_path, chain))
+    # settled: a batch tallied between the reading of `acked` and the
+    # node's stop would be acknowledgements the spans count and it
+    # does not
+    got = asyncio.run(follow(tmp_path, chain, settle=True))
     by_kind: dict[str, list] = {}
     for rec in tracing.TRACER.snapshot():
         by_kind.setdefault(rec[0], []).append(rec)
@@ -418,7 +437,10 @@ def test_vote_path_spans_are_one_a_batch_not_one_a_vote(tmp_path):
     assert {r[6]["cut"] for r in by_kind[
         tracing.CONSENSUS_VOTE_QUEUE_WAIT]} <= {"full", "idle", "cap"}
     assert sum(r[6]["added"] for r in tallies) == acked
-    assert sum(r[6]["rejected"] for r in tallies) == sum(
+    # every spoiled copy once: all of the three whole heights', and of
+    # height 4's the ones the peers had handed over when they paused
+    late = sum(1 for at in got["net"].planted_at if at[1] == 4)
+    assert sum(r[6]["rejected"] for r in tallies) == late + sum(
         len(chain.at(h).planted) for h in range(1, 4))
     assert sum(r[6]["votes"] for r in tallies) == sum(
         r[6]["lanes"] for r in verifies[:batches])
@@ -426,14 +448,31 @@ def test_vote_path_spans_are_one_a_batch_not_one_a_vote(tmp_path):
     units = lambda r: (r[6] or {}).get("n", 1)   # a lone unit has no n
     assert sum(units(r) for r in has_vote) == acked
     receive = by_kind[tracing.CONSENSUS_RECEIVE]
+    busy = lambda r: (r[6] or {}).get("busy_ns", r[5])
     # every vote, proposal and part of the three whole heights, once
     # each; what height 4 had brought when the node was stopped beside
     whole = sum(hs.signatures() + len(hs.planted) + 1 + len(hs.part_msgs)
                 for hs in chain.heights[:3])
     assert whole <= sum(units(r) for r in receive) <= handed + 8
+    # beside them the two sums of the intake's decode: every vote of
+    # the three heights came in the canonical layout, and the reactor's
+    # share (decode + marks) is part of the units' own time
+    votes = sum(hs.signatures() + len(hs.planted) for hs in chain.heights[:3])
+    assert votes <= sum(r[6]["shaped"] for r in receive) <= handed
+    assert 0 < sum(r[6]["decode_ms"] for r in receive) * 1e6 <= sum(
+        busy(r) for r in receive)
+    # the benchmark's two metrics read these sums by their names
+    for metric in ("vote_decode_ms_per_height.live",
+                   "vote_decode_shaped_per_height.live"):
+        with open(os.path.join(
+                os.path.dirname(os.path.abspath(__file__)), "..",
+                "benchmark", "layer_metrics", metric + ".json")) as f:
+            params = json.load(f)["params"]
+        assert params["kind"] == tracing.CONSENSUS_RECEIVE
+        assert params["per"] == tracing.CONSENSUS_HEIGHT
+        assert all(params["attr"] in r[6] for r in receive)
     # (a receive unit counts the reactor's decode in, which a burst
     # does ahead of the routine: its busy time can pass the entry's)
-    busy = lambda r: (r[6] or {}).get("busy_ns", r[5])
     odd = [(r[0], r[6], r[5]) for r in receive + has_vote
            if not 0 < busy(r) <= (r[5] if r in has_vote else busy(r))]
     assert not odd, odd[:5]
@@ -474,6 +513,100 @@ def test_no_clock_is_read_for_the_vote_path_when_tracing_is_off(
     # a read a micro-batch (when its first vote was buffered), none a
     # message: far fewer than the votes
     assert len(reads) < acked / 4, (len(reads), acked)
+
+
+def test_handed_over_votes_are_shaped_and_their_wal_replays_alike(
+        tmp_path, monkeypatch):
+    """At the rehearsal's 216 validators: every vote the scripted peers
+    hand over is decoded by its shape, the same bytes with an unknown
+    field appended by the general decoder, and a restart's WAL catch-up
+    of the height in flight (ConsensusState._catchup_replay, which
+    decodes the records through the same entry) ends with the votes,
+    the tallied sets and the app hash that the field-by-field decoder
+    alone replays them to."""
+    import shutil
+
+    from tendermint_tpu.consensus import messages as m
+    from tendermint_tpu.consensus.reactor import VOTE_CHANNEL
+
+    n_vals, inflight = 216, 3
+    chain = make_chain(n_vals, inflight, seed=41)
+    unknown = b"\x80\x01\x01"     # field 16, a varint
+
+    def held(cs):
+        sets = {}
+        for vtype, vs in ((VoteType.PREVOTE, cs.rs.votes.prevotes(0)),
+                          (VoteType.PRECOMMIT, cs.rs.votes.precommits(0))):
+            sets[int(vtype)] = [
+                (v.validator_index, v.timestamp, v.signature, v.block_id)
+                for v in vs.votes if v is not None] if vs else []
+        return {"height": cs.rs.height, "sets": sets,
+                "app_hash": cs.state.app_hash,
+                "proposal": cs.rs.proposal_block.hash()
+                if cs.rs.proposal_block else None}
+
+    async def live():
+        cfg = node_config(tmp_path / "live", chain.gdoc,
+                          timeout_commit_ms=150)
+        node = Node.default_new_node(cfg)
+        await node.start()
+        cs = node.consensus_state
+        net = ScriptedNet(chain, 4, query_maj23_s=600.0)
+        shaped0, general0 = m.vote_decode_counts()
+        try:
+            await net.attach(node.switch, node.consensus_reactor)
+            loop = asyncio.get_running_loop()
+            deadline = loop.time() + 120
+            # two whole heights, then a third of the next one's
+            # prevotes: the height stays in flight
+            key = (inflight, VoteType.PREVOTE)
+            while sum(len(p.shares[key].sent)
+                      for p in net.peers) < n_vals // 3:
+                assert loop.time() < deadline, (cs.rs.height, cs.rs.step)
+                await asyncio.sleep(0)
+            await settled(cs, net)
+            shaped, general = m.vote_decode_counts()
+            assert shaped - shaped0 == net.handed_over() > 2 * 2 * 144
+            assert general == general0
+            raw = chain.at(inflight).msgs[VoteType.PREVOTE][0] + unknown
+            await node.consensus_reactor.receive(
+                VOTE_CHANNEL, net.peers[0], raw)
+            assert m.vote_decode_counts() == (shaped, general0 + 1)
+            await settled(cs, net)
+            before = held(cs)
+        finally:
+            await net.stop(node.switch)
+            await node.stop()
+        return cfg.base.home, before
+
+    async def restart(name):
+        shutil.copytree(home, str(tmp_path / name / "node"))
+        cfg = node_config(tmp_path / name, chain.gdoc,
+                          timeout_commit_ms=150)
+        counts0 = m.vote_decode_counts()
+        node = Node.default_new_node(cfg)
+        await node.start()
+        try:
+            counts = m.vote_decode_counts()
+            return held(node.consensus_state), (
+                counts[0] - counts0[0], counts[1] - counts0[1])
+        finally:
+            await node.stop()
+
+    home, before = asyncio.run(live())
+    assert before["height"] == inflight and before["proposal"] == \
+        chain.at(inflight).block_id.hash
+    assert n_vals // 3 <= len(before["sets"][int(VoteType.PREVOTE)]) \
+        < 2 * n_vals // 3
+    by_shape, (shaped, general) = asyncio.run(restart("by_shape"))
+    monkeypatch.setattr(m, "_decode_shaped_vote", lambda data: None)
+    by_field, (none, every) = asyncio.run(restart("by_field"))
+    assert by_shape == by_field == before
+    assert by_shape["app_hash"] == chain.at(inflight - 1).app_hash
+    # the WAL holds the bytes as they came: the vote with the unknown
+    # field is the general decoder's in the replay too
+    assert general == 1 and none == 0
+    assert shaped + general == every >= n_vals // 3
 
 
 def test_model_copies_are_one_text():
