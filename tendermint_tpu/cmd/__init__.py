@@ -197,14 +197,74 @@ def cmd_testnet(args) -> int:
     return 0
 
 
+def light_store_db(cfg, path: str):
+    """The light proxy's trusted store: `base.db_backend` (sqlite as
+    shipped, every save a durable commit as the reference's
+    SaveLightBlock is a WriteSync) at `path`; in memory without one."""
+    from ..libs.db import FileDB, MemDB, SqliteDB
+
+    if not path or cfg.base.db_backend == "memdb":
+        return MemDB()
+    if cfg.base.db_backend == "filedb":
+        return FileDB(path)
+    return SqliteDB(path, synchronous=cfg.base.db_synchronous)
+
+
+async def start_light_pool(cfg, client, host: str, port: int,
+                           forward_clients=None):
+    """The light proxy as `cmd light` serves it: [crypto] applied, a
+    ServingPool of `[light] workers` proxies over ONE serving plane
+    built from `[light]`, the plane's launch shape loaded, the trust
+    root pinned through it, and only then the ports opened (worker i
+    on port + i). Returns the pool; its caller closes it."""
+    from ..light.serving import ServingPool
+    from ..node import apply_crypto_config
+
+    apply_crypto_config(cfg.crypto)
+    pool = ServingPool(client, config=cfg.light,
+                       forward_clients=forward_clients)
+    await pool.start(host, port)
+    return pool
+
+
+async def follow_light(plane, seen: int, interval: float,
+                       once: bool = False) -> None:
+    """`cmd light`'s follow loop: ask the plane for the newest header
+    every `interval` seconds and print each newly verified height. A
+    shed (LightServingShedError: the collector's backlog is full of
+    the RPC callers' checks) is transient backpressure, which a public
+    proxy meets routinely: that tick is skipped and said so. Any other
+    error (a verification verdict against the primary, a divergence, a
+    provider that cannot be reached) ends the daemon, as it did when
+    the loop called Client.update()."""
+    from ..light.serving import LightServingShedError
+
+    while True:
+        try:
+            new = await plane.get_verified(0)
+        except LightServingShedError as e:
+            print(f"light: follow tick skipped: {e}")
+        else:
+            if new.height() > seen:
+                seen = new.height()
+                print(f"verified height {new.height()}: "
+                      f"{new.hash().hex()[:16]}…")
+        if once:
+            return
+        await asyncio.sleep(interval)
+
+
 def cmd_light(args) -> int:
     """Light client daemon: follow a chain through an RPC primary,
     verifying every header (reference: cmd/tendermint/commands/light.go
-    + light/proxy)."""
-    from ..libs.db import FileDB, MemDB
+    + light/proxy). With --laddr it serves verified RPC through the
+    serving plane (light/serving.py): `[light]` of the home's
+    config.toml sizes it, `[crypto] backend` selects the device as it
+    does for a node."""
     from ..light import Client, LightStore, TrustOptions
     from ..light.provider import RPCProvider
 
+    cfg = _load_config(args.home)
     host, _, port = args.primary.rpartition(":")
     primary = RPCProvider(host or "127.0.0.1", int(port))
     witnesses = []
@@ -212,7 +272,7 @@ def cmd_light(args) -> int:
         if w:
             wh, _, wp = w.rpartition(":")
             witnesses.append(RPCProvider(wh or "127.0.0.1", int(wp)))
-    store = LightStore(FileDB(args.store) if args.store else MemDB())
+    store = LightStore(light_store_db(cfg, args.store))
 
     async def run():
         cl = Client(
@@ -221,32 +281,38 @@ def cmd_light(args) -> int:
                          height=args.trust_height,
                          hash=bytes.fromhex(args.trust_hash)),
             primary, witnesses, store)
-        lb = await cl.initialize()
-        print(f"trusted root at height {lb.height()}: "
-              f"{lb.hash().hex()[:16]}…")
-        proxy = None
+        pool = plane = None
         if args.laddr:
-            from ..light.proxy import LightProxy
             from ..rpc.jsonrpc import HTTPClient
 
             lh, _, lp = args.laddr.rpartition(":")
-            proxy = LightProxy(
-                cl, forward_client=HTTPClient(host or "127.0.0.1",
-                                              int(port)))
-            p = await proxy.listen(lh or "127.0.0.1", int(lp))
-            print(f"light proxy: verified RPC on {lh or '127.0.0.1'}:{p}")
+            pool = await start_light_pool(
+                cfg, cl, lh or "127.0.0.1", int(lp),
+                [HTTPClient(host or "127.0.0.1", int(port))
+                 for _ in range(cfg.light.workers)])
+            plane = pool.plane
+            print(f"light proxy: verified RPC on {lh or '127.0.0.1'}:"
+                  f"{','.join(map(str, pool.ports))} "
+                  f"({cfg.light.workers} workers, one serving plane)")
+        else:
+            # nothing is served: the plane follows the chain alone, and
+            # loads no program ahead of a first launch it may never make
+            from ..light.serving import ServingPlane
+            from ..node import apply_crypto_config
+
+            apply_crypto_config(cfg.crypto)
+            plane = ServingPlane(cl, cfg.light)
+        lb = await plane.initialize()   # pinned already when serving
+        print(f"trusted root at height {lb.height()}: "
+              f"{lb.hash().hex()[:16]}…")
         try:
-            while True:
-                new = await cl.update()
-                if new is not None:
-                    print(f"verified height {new.height()}: "
-                          f"{new.hash().hex()[:16]}…")
-                if args.once:
-                    return
-                await asyncio.sleep(args.interval)
+            await follow_light(plane, lb.height(), args.interval,
+                               args.once)
         finally:
-            if proxy is not None:
-                proxy.close()
+            if pool is not None:
+                pool.close()
+            else:
+                plane.close()
 
     asyncio.run(run())
     return 0

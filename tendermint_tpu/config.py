@@ -195,14 +195,22 @@ class MempoolConfig:
 class LightConfig:
     """Light-client serving plane (light/serving.py; this framework's
     addition — the reference light proxy verifies per request with no
-    cross-request sharing). Knobs for the shared verification plane a
-    LightProxy / ServingPool runs requests through."""
+    cross-request sharing). Knobs for the shared verification plane
+    `cmd light --laddr` serves through (a ServingPool of `workers`
+    LightProxy workers over ONE plane). A height is verified forwards
+    from the latest trusted block, backwards below the first, and BY
+    SIGNATURE from the closest trusted block below when it lies
+    between them (the reference's three cases)."""
 
     # verified-header LRU entries (trusting-period-aware; a second
     # client hitting a cached height costs a dict lookup, not a
     # device launch)
     cache_size: int = 4096
-    # coalesced verify launches flush at this many signature lanes ...
+    # the widest coalesced verify launch, in lanes, the sentinel's
+    # lane counted in: a cut holds at most batch_max - 1 signature
+    # lanes. Every device launch of the plane has batch_max lanes
+    # (1,024), the ONE program loaded before the first request is
+    # accepted; a batch cuts when full ...
     batch_max: int = 1024
     # ... or this many ms after the first pending check, whichever
     # comes first (the admission-collector window shape)

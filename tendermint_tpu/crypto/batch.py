@@ -696,14 +696,33 @@ def host_ed25519_launch(pubs, msgs, sigs) -> tuple[np.ndarray, str]:
     return _host_verify_ed25519(pubs, msgs, sigs), "host"
 
 
+def load_ed25519_programs(shapes) -> int:
+    """Load the general program at a plane's closed set of launch
+    shapes (crypto/tpu/verify.py LaunchShapes) before the plane takes
+    its first request, so that nothing it launches afterwards compiles.
+    Returns the programs loaded; 0, with nothing touched, where
+    verification is pinned to the host or the breaker is open (the
+    first device launch then compiles, as it always did)."""
+    if _FORCE_HOST or not device_available("ed25519"):
+        return 0
+    from .tpu import ledger as tpu_ledger
+    from .tpu import verify as tpu_verify
+
+    with tpu_ledger.workload("probe"):
+        return tpu_verify.load_general(shapes)
+
+
 def guarded_ed25519_launch(pubs, msgs, sigs, workload: str,
-                           device_threshold: int
+                           device_threshold: int, shapes=None
                            ) -> tuple[np.ndarray, str]:
     """Verify raw ed25519 triples in one launch: on the device when
     the batch reaches `device_threshold` lanes and the breaker admits
     it, on the host oracle otherwise. `workload` tags the launch in
-    the ledger (crypto/tpu/ledger.py). Returns (per-lane verdicts,
-    backend), backend one of "device", "host", "host_recheck"."""
+    the ledger (crypto/tpu/ledger.py). `shapes`: the plane's closed
+    set of launch shapes (LaunchShapes), the sentinel's lane counted
+    in; without it the launch takes the ladder of lane buckets.
+    Returns (per-lane verdicts, backend), backend one of "device",
+    "host", "host_recheck"."""
     from ..libs import failpoints
     from ..libs.metrics import crypto_metrics, tpu_metrics
 
@@ -732,7 +751,8 @@ def guarded_ed25519_launch(pubs, msgs, sigs, workload: str,
             spub, smsg, ssig = _ed_probe_triple()
             with tpu_ledger.workload(workload):
                 out = np.asarray(tpu_verify.verify_batch(
-                    [*pubs, spub], [*msgs, smsg], [*sigs, ssig]), bool)
+                    [*pubs, spub], [*msgs, smsg], [*sigs, ssig],
+                    shapes=shapes), bool)
             crypto_metrics().batch_lanes.inc(
                 n, backend=_tpu_backend.platform())
             if out[-1]:

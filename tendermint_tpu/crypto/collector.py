@@ -5,10 +5,11 @@ only in what an item is and what a batch of them costs to verify.
 ``submit(item, weight)`` parks the item and awaits its result. A
 single flusher task opens a ``flush_ms`` deadline at the first pending
 arrival, cuts a batch at ``batch_max`` of weight or at the deadline
-(whichever first) and runs it through the plane's verify callable in
-an executor thread — so a slow device (or an armed delay failpoint)
-backs up the bounded backlog and sheds instead of stalling the event
-loop. One batch is in flight at a time.
+(whichever first; a cut holds at most ``batch_max`` of weight, and an
+item heavier than that goes alone) and runs it through the plane's
+verify callable in an executor thread — so a slow device (or an armed
+delay failpoint) backs up the bounded backlog and sheds instead of
+stalling the event loop. One batch is in flight at a time.
 
 The backlog bound counts ITEMS (parked + in verify): at the bound the
 newest arrival is refused with BacklogFull, which each plane turns
@@ -158,10 +159,14 @@ class BatchCollector:
                 except asyncio.TimeoutError:
                     break
             full = self._pending_weight >= self.batch_max
-            # an item heavier than batch_max still goes, alone
+            # a cut never outweighs batch_max (a plane that launches a
+            # closed set of shapes counts on it); an item heavier than
+            # batch_max still goes, alone
             batch: list[_Parked] = []
             lanes = 0
-            while self._pending and (not batch or lanes < self.batch_max):
+            while self._pending and (
+                    not batch or lanes + self._pending[0].weight
+                    <= self.batch_max):
                 batch.append(self._pending.popleft())
                 lanes += batch[-1].weight
             self._pending_weight -= lanes

@@ -29,6 +29,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import re
+from typing import NamedTuple
 
 import numpy as np
 
@@ -113,7 +114,8 @@ def _bytes32_to_limbs(arr: np.ndarray) -> np.ndarray:
         limbs.T.astype(np.asarray(fe.to_limbs(0)).dtype))
 
 
-def pack_batch(pubs, msgs, sigs) -> dict[str, np.ndarray]:
+def pack_batch(pubs, msgs, sigs, min_blocks: int = 1
+               ) -> dict[str, np.ndarray]:
     """Host-side preparation: raw byte arrays + SHA padding + S < L.
 
     All numpy-vectorized; no per-signature Python.
@@ -121,15 +123,17 @@ def pack_batch(pubs, msgs, sigs) -> dict[str, np.ndarray]:
     n = len(pubs)
     a_raw = np.frombuffer(b"".join(pubs), np.uint8).reshape(n, 32)
     sig_raw = np.frombuffer(b"".join(sigs), np.uint8).reshape(n, 64)
-    return pack_arrays(a_raw, sig_raw, msgs)
+    return pack_arrays(a_raw, sig_raw, msgs, min_blocks)
 
 
-def pack_arrays(a_raw: np.ndarray, sig_raw: np.ndarray, msgs) -> dict[str, np.ndarray]:
+def pack_arrays(a_raw: np.ndarray, sig_raw: np.ndarray, msgs,
+                min_blocks: int = 1) -> dict[str, np.ndarray]:
     """pack_batch core on pre-built (N, 32)/(N, 64) uint8 arrays."""
-    return dict(pack_sig_msg(sig_raw, msgs), ab=a_raw)
+    return dict(pack_sig_msg(sig_raw, msgs, min_blocks), ab=a_raw)
 
 
-def pack_sig_msg(sig_raw: np.ndarray, msgs) -> dict[str, np.ndarray]:
+def pack_sig_msg(sig_raw: np.ndarray, msgs, min_blocks: int = 1
+                 ) -> dict[str, np.ndarray]:
     """Signature/message half of the pack — everything except the
     pubkey rows. The expanded-valset path sends only this plus the
     (N,) key indices per launch: its pubkey bytes are already
@@ -142,8 +146,10 @@ def pack_sig_msg(sig_raw: np.ndarray, msgs) -> dict[str, np.ndarray]:
     # Bucket the padded width to power-of-two block counts so kernel
     # shapes (and recompiles) stay bounded; extra blocks are zeros and
     # every lane past its own nblocks is frozen in compress_blocks.
+    # `min_blocks` (a power of two) is the floor a caller with a closed
+    # set of launch shapes gives (LaunchShapes.blocks).
     total_blocks = (msg_pad.shape[1] + 64) // 128
-    tb = 1
+    tb = min_blocks
     while tb < total_blocks:
         tb <<= 1
     if tb != total_blocks:
@@ -463,10 +469,47 @@ def _chunks(n: int) -> list[int]:
     return out
 
 
-def verify_batch(pubs, msgs, sigs) -> np.ndarray:
+class LaunchShapes(NamedTuple):
+    """A CLOSED set of shapes for the general program: what a plane
+    that may never compile inside its service hands to verify_batch
+    and loads at its start (load_general). `lanes` is the ONE lane
+    count a launch may have; `blocks` the SHA-512 blocks of
+    R || A || message a launch is packed to at least (a power of two;
+    2 holds every canonical vote: sign bytes of up to 175 bytes, and a
+    50-character chain id makes 166). A batch is cut into pieces of
+    `lanes`, the last padded to it. A message past `blocks` still
+    verifies, at a shape of its own."""
+
+    lanes: int
+    blocks: int = 2
+
+    def fit(self, n: int) -> list[int]:
+        return [self.lanes] * -(-n // self.lanes)
+
+
+def load_general(shapes: LaunchShapes) -> int:
+    """Compile (or load from the compile cache) and run once the
+    general program at the shape of `shapes`, over lanes that verify
+    nothing but the pad triple: what verify_batch(shapes=...) launches
+    from then on. Returns the programs loaded (0 for a shape this
+    process has launched already)."""
+    width = 128 * shapes.blocks - 64
+    known = ("general", shapes.lanes, width) in _COMPILED_SHAPES
+    dp, dm, ds = _dummy_triple()
+    out = _launch_chunk([dp], [dm], [ds], shapes.lanes,
+                        min_blocks=shapes.blocks)
+    if hasattr(out, "block_until_ready"):
+        out.block_until_ready()
+    return int(not known)
+
+
+def verify_batch(pubs, msgs, sigs,
+                 shapes: LaunchShapes | None = None) -> np.ndarray:
     """Verify a batch of ed25519 (pub, msg, sig) triples on the default
     JAX device. Returns per-lane verdicts as (N,) bool. ZIP-215 semantics
-    identical to ed25519_ref.verify; malformed lengths fail cleanly."""
+    identical to ed25519_ref.verify; malformed lengths fail cleanly.
+    `shapes`: the caller's closed set of launch shapes (LaunchShapes);
+    without it a batch takes _chunks' power-of-two ladder."""
     n = len(pubs)
     assert len(msgs) == n and len(sigs) == n
     if n == 0:
@@ -490,7 +533,8 @@ def verify_batch(pubs, msgs, sigs) -> np.ndarray:
     from ...libs.metrics import tpu_metrics
 
     tmet = tpu_metrics()
-    sizes = _chunks(n)
+    sizes = _chunks(n) if shapes is None else shapes.fit(n)
+    min_blocks = 1 if shapes is None else shapes.blocks
     tmet.batch_occupancy.observe(n / sum(sizes))
     if len(sizes) > 1:
         tmet.batch_splits.inc()
@@ -502,7 +546,8 @@ def verify_batch(pubs, msgs, sigs) -> np.ndarray:
             rec.lanes = end - start
             try:
                 fut = _launch_chunk(pubs[start:end], msgs[start:end],
-                                    sigs[start:end], size, rec=rec)
+                                    sigs[start:end], size, rec=rec,
+                                    min_blocks=min_blocks)
             except Exception as exc:
                 rec.fail(exc)
                 raise
@@ -554,7 +599,8 @@ def count_compile(kernel: str, shape: tuple) -> bool:
     return False
 
 
-def _launch_chunk(pubs, msgs, sigs, bucket: int, rec=None):
+def _launch_chunk(pubs, msgs, sigs, bucket: int, rec=None,
+                  min_blocks: int = 1):
     """Dispatch one bucket-sized kernel launch; returns the device array
     (async — caller materializes). Padding lanes use a fixed valid
     triple so they cannot affect real lanes. `rec` is the caller's
@@ -583,7 +629,7 @@ def _launch_chunk(pubs, msgs, sigs, bucket: int, rec=None):
             pubs = list(pubs) + [dp] * pad
             msgs = list(msgs) + [dm] * pad
             sigs = list(sigs) + [ds] * pad
-        packed = pack_batch(pubs, msgs, sigs)
+        packed = pack_batch(pubs, msgs, sigs, min_blocks)
     hit = count_compile("general", (bucket, packed["msg"].shape[1]))
     if rec is not None:
         rec.capacity = bucket

@@ -72,6 +72,12 @@ links over the hot paths:
     crypto.verify ...                  (executor thread, via wrap)
   light.queue_wait / light.flush       the same pair for the light
                                        plane's commit-check batches
+  light.request / .fetch / .plan / .step / .store_save / .detect
+                                       the light proxy's per-request
+                                       sites, each FOLDED (leaf with a
+                                       fold_key: attrs n, busy_ns)
+  light.load_programs                  once, before the first request:
+                                       the plane's launch shapes loaded
   p2p.send_flush / p2p.recv_msg        wire-side attribution
 
 Design constraints (this stays ON in production):
@@ -286,6 +292,36 @@ ADMISSION_QUEUE_WAIT = register_kind("admission.queue_wait")
 ADMISSION_FLUSH = register_kind("admission.flush")
 LIGHT_QUEUE_WAIT = register_kind("light.queue_wait")
 LIGHT_FLUSH = register_kind("light.flush")
+# The light proxy's path (light/proxy.py, client.py, serving.py,
+# store.py). Every per-request site is FOLDED (Tracer.leaf with the
+# owner's fold_key, LIGHT_FOLD_NS, LIGHT_FOLD_LOOKBACK): a proxy with
+# 256 callers answers some hundred requests a second, nine units each,
+# and the ring holds 16,384. attrs n, busy_ns as for every folded kind;
+# the sums beside them:
+#   light.request     a verified route's entry -> its reply (hits,
+#                     coalesced, misses: how the plane resolved it;
+#                     failed: it ended in an error)
+#   light.fetch       one provider's answer and its decode into a
+#                     LightBlock (bytes where the provider knows them;
+#                     witness: 1 for a cross-check's fetch)
+#   light.plan        the building of ONE CommitVerifyPlan (lanes;
+#                     trusting: 1 for the trusted set's overlap check,
+#                     0 for the new set's own +2/3)
+#   light.step        one verification of an untrusted block against
+#                     a trusted one, both checks (adjacent, pivots = it
+#                     ended in a bisection pivot, gap = to - from,
+#                     walks = hash-chain walks, counted here too)
+#   light.store_save  one LightStore.save
+#   light.detect      one witness cross-check of a verified block
+# load_programs: once, before the first request is accepted (attrs
+# programs, seconds, lanes): the plane's launch shapes.
+LIGHT_REQUEST = register_kind("light.request")
+LIGHT_FETCH = register_kind("light.fetch")
+LIGHT_PLAN = register_kind("light.plan")
+LIGHT_STEP = register_kind("light.step")
+LIGHT_STORE_SAVE = register_kind("light.store_save")
+LIGHT_DETECT = register_kind("light.detect")
+LIGHT_LOAD_PROGRAMS = register_kind("light.load_programs")
 
 # State machine + durability + wire. The state.* children follow
 # BlockExecutor._apply_block in order.
@@ -407,6 +443,16 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class _QuietSpan(_NoopSpan):
+    """The current span inside Tracer.quiet(): nothing begun or
+    recorded beneath it reaches the ring."""
+
+    __slots__ = ()
+
+
+QUIET = _QuietSpan()
+
+
 class _SpanCtx:
     """Context manager: starts a span parented on the task-local
     current span, makes it current for the body, seals it on exit."""
@@ -461,6 +507,13 @@ LEAF_FOLD_NS = 2_000_000
 # leaf(fold_key=...): how many of the ring's newest entries are looked
 # through for the entry the key wrote last.
 LEAF_KEY_LOOKBACK = 32
+# The light proxy's folded kinds: their units are whole requests,
+# milliseconds apart and seconds long (256 callers queue for the one
+# loop), so a run tolerates that much pause and that much overlap, and
+# its entry is looked for further back (seven spans a launch are sealed
+# between two units of a kind).
+LIGHT_FOLD_NS = 10_000_000_000
+LIGHT_FOLD_LOOKBACK = 256
 
 
 class Tracer:
@@ -513,11 +566,14 @@ class Tracer:
         _require_registered(kind)
         if parent is None:
             parent = _CURRENT.get()
+        if parent is QUIET:
+            return NOOP_SPAN
         return Span(self, kind, parent.span_id if parent else 0,
                     attrs or None, start_ns)
 
     def leaf(self, kind: str, start_ns: int, fold_key=None,
-             parent: Span | None = None, **sums) -> None:
+             parent: Span | None = None, fold_ns: int = LEAF_FOLD_NS,
+             lookback: int = LEAF_KEY_LOOKBACK, **sums) -> None:
         """Record a finished childless span [start_ns, now) under the
         current span (or `parent`) — the form for a site whose unit
         may be a tx (a durable db commit) or a peer message. A repeat
@@ -533,10 +589,12 @@ class Tracer:
         the entry this key wrote last instead of the newest — for a
         site whose repeats interleave with other spans (a vote's
         receive between the scheduler's batches). The entry is looked
-        for among the ring's LEAF_KEY_LOOKBACK newest, so a run is cut
-        by a pause, by a new parent, or by that many spans sealed
-        since it began; it keeps the place in the ring its first unit
-        took."""
+        for among the ring's `lookback` newest, so a run is cut by a
+        pause, by a new parent, or by that many spans sealed since it
+        began; it keeps the place in the ring its first unit took.
+        `fold_ns` is the longest pause a run survives and, for a keyed
+        run, the most its units may overlap (the light proxy's units
+        are whole requests: LIGHT_FOLD_NS)."""
         if not self.enabled:
             return
         _require_registered(kind)
@@ -544,6 +602,8 @@ class Tracer:
         dur = t1 - start_ns
         if parent is None:
             parent = _CURRENT.get()
+        if parent is QUIET:
+            return
         pid = parent.span_id if parent else 0
         tid = threading.get_ident()
         with self._lock:
@@ -555,13 +615,13 @@ class Tracer:
             else:
                 last = self._keyed.get(fold_key)
                 at = next((i for i in range(
-                    1, min(len(ring), LEAF_KEY_LOOKBACK) + 1)
+                    1, min(len(ring), lookback) + 1)
                     if ring[-i] is last), 0) if last is not None else 0
-                lo = -LEAF_FOLD_NS   # units of one owner may overlap
+                lo = -fold_ns   # units of one owner may overlap
             folds = (at and last[0] == kind
                      and last[2] == pid and last[3] == tid
                      and lo <= start_ns - (last[4] + last[5])
-                     <= LEAF_FOLD_NS)
+                     <= fold_ns)
             if folds:
                 attrs = dict(last[6] or ())
                 attrs["n"] = attrs.get("n", 1) + 1
@@ -621,6 +681,14 @@ class Tracer:
         children under a manually-managed span (e.g. the commit step
         span during finalize) regardless of which task runs the code."""
         return _AttachCtx(span)
+
+    def quiet(self) -> _AttachCtx:
+        """`with TRACER.quiet(): ...` — the body's spans and leaves are
+        not recorded. For a site that is itself ONE unit of a folded
+        kind (a plan the light proxy builds, a block it saves): its
+        body's per-commit spans would each cost the ring an entry a
+        request, which is what the fold is there to spare."""
+        return _AttachCtx(QUIET)
 
     def wrap(self, fn):
         """Explicit executor handoff: capture the caller's active span
@@ -809,3 +877,10 @@ def chrome_trace(records: list[tuple], meta: dict | None = None) -> dict:
     if meta is not None:
         out["tm_tpu"] = meta
     return out
+
+
+def light_leaf(kind: str, start_ns: int, **sums) -> None:
+    """One unit of a per-request site of the light proxy's path, folded
+    into the process's run of that kind (the kind is the fold key)."""
+    TRACER.leaf(kind, start_ns, fold_key=kind, fold_ns=LIGHT_FOLD_NS,
+                lookback=LIGHT_FOLD_LOOKBACK, **sums)

@@ -8,7 +8,13 @@ overlap is below the trust level, pivot to the midpoint and recurse.
 Each verified header is cross-checked against every witness
 (reference detector.go:28); a conflicting witness raises
 DivergenceError carrying both blocks so the caller can submit
-LightClientAttackEvidence."""
+LightClientAttackEvidence.
+
+A height is verified by where it lies against the trusted store
+(reference client.go verifyLightBlock): at or above the latest trusted
+block, forwards from the latest; below the FIRST trusted block,
+backwards by hash linkage; between the two, forwards by signature from
+the closest trusted block below it (LightStore.light_block_before)."""
 
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ import time
 from dataclasses import dataclass
 from fractions import Fraction
 
+from ..libs import tracing
 from .errors import (
     DivergenceError,
     LightClientError,
@@ -75,9 +82,11 @@ class Client:
 
     # -- bootstrap --
 
-    async def initialize(self) -> LightBlock:
+    async def initialize(self, check=None) -> LightBlock:
         """Fetch + pin the trusted block (reference client.go
-        initializeWithTrustOptions)."""
+        initializeWithTrustOptions). `check(plan)`: an awaitable that
+        verifies the root's own commit check in the caller's place (the
+        serving plane's collector); inline without one."""
         existing = self.store.get(self.trust_options.height)
         if existing is not None:
             self._initialized = True
@@ -90,9 +99,13 @@ class Client:
                 f"{self.trust_options.height}: got {lb.hash().hex()}, "
                 f"want {self.trust_options.hash.hex()}")
         # +2/3 of ITS OWN valset must have signed it (self-consistency)
-        lb.validator_set.verify_commit_light(
+        plan = lb.validator_set.plan_commit_light(
             self.chain_id, lb.signed_header.commit.block_id,
             lb.height(), lb.signed_header.commit)
+        if check is None:
+            plan.execute()
+        else:
+            await check(plan)
         self.store.save(lb)
         self._initialized = True
         return lb
@@ -109,14 +122,28 @@ class Client:
         cached = self.store.get(height)
         if cached is not None:
             return cached
-        latest_trusted = self.store.latest()
-        assert latest_trusted is not None
-        if height < latest_trusted.height():
+        trusted = self.trusted_base(height)
+        if trusted is None:
             return await self._verify_backwards(height, now_ns)
         target = await self._from_primary(height)
-        await self._verify_skipping(latest_trusted, target, now_ns)
+        await self._verify_skipping(trusted, target, now_ns)
         await self._detect_divergence(target, now_ns)
         return target
+
+    def trusted_base(self, height: int, live=None) -> LightBlock | None:
+        """The trusted block a height that is not stored is verified
+        FORWARDS from (reference client.go verifyLightBlock): the
+        latest one for a height above it, the closest one below for a
+        height between the first and the last. None: the height lies
+        below the first trusted block and only the hash chain leads
+        down to it (_verify_backwards). `live(height)` may hand the
+        block over without the store's decode (the plane's cache)."""
+        base = self.store.latest_height()
+        if height < base:
+            base = self.store.height_before(height)
+            if not base:
+                return None
+        return (live(base) if live else None) or self.store.get(base)
 
     async def _from_primary(self, height: int) -> LightBlock:
         """Fetch from the primary; on a TRANSPORT failure promote the
@@ -129,7 +156,7 @@ class Client:
         tries = 0
         while True:
             try:
-                return await self.primary.light_block(height)
+                return await self._fetch(self.primary, height)
             except BlockNotFoundError:
                 raise
             except (ProviderError, OSError) as e:
@@ -150,6 +177,16 @@ class Client:
                     "(failed primary demoted to witness)",
                     old, e, self.primary)
 
+    @staticmethod
+    async def _fetch(provider: Provider, height: int,
+                     witness: int = 0) -> LightBlock:
+        """One provider's answer, decoded (span light.fetch)."""
+        t0 = time.perf_counter_ns()
+        try:
+            return await provider.light_block(height)
+        finally:
+            tracing.light_leaf(tracing.LIGHT_FETCH, t0, witness=witness)
+
     async def _verify_backwards(self, height: int,
                                 now_ns: int) -> LightBlock:
         """Hash-chain walk DOWN from the nearest trusted block above
@@ -160,6 +197,7 @@ class Client:
         trusting period."""
         from .verifier import verify_backwards
 
+        t0 = time.perf_counter_ns()
         # Anchor on the nearest TRUSTED block — the trusting-period
         # check applies to it, never to a cached interim (an interim's
         # older timestamp could fail the check while a perfectly valid
@@ -200,6 +238,8 @@ class Client:
             self._interim_cache[interim.height()] = interim
             cur = interim
         self.store.save(cur)
+        tracing.light_leaf(tracing.LIGHT_STEP, t0, walks=1,
+                           gap=anchor_h - height)
         return cur
 
     async def update(self, now_ns: int | None = None) -> LightBlock | None:
@@ -248,11 +288,15 @@ class Client:
             if steps > 200:  # 2^200 heights — unreachable honestly
                 raise LightClientError("bisection did not converge")
             block = pending[-1]
+            t0 = time.perf_counter_ns()
+            gap = block.height() - trusted.height()
             try:
                 verify(self.chain_id, trusted, block,
                        self.trust_options.period_ns, now_ns,
                        self.trust_level)
             except NewValSetCantBeTrustedError:
+                tracing.light_leaf(tracing.LIGHT_STEP, t0, pivots=1,
+                                   gap=gap)
                 pivot_h = (trusted.height() + block.height()) // 2
                 if pivot_h in (trusted.height(), block.height()) or \
                         pivot_h in cache:
@@ -261,6 +305,8 @@ class Client:
                 cache[pivot_h] = pivot
                 pending.append(pivot)
                 continue
+            tracing.light_leaf(tracing.LIGHT_STEP, t0,
+                               adjacent=int(gap == 1), gap=gap)
             if persist:
                 self.store.save(block)
             trusted = block
@@ -282,6 +328,14 @@ class Client:
         and DivergenceError (carrying the evidence) is raised."""
         if not self.witnesses:
             return
+        t0 = time.perf_counter_ns()
+        try:
+            await self._cross_check(verified, now_ns)
+        finally:
+            tracing.light_leaf(tracing.LIGHT_DETECT, t0)
+
+    async def _cross_check(self, verified: LightBlock,
+                           now_ns: int) -> None:
         results = await asyncio.gather(
             *(self._compare_with_witness(i, w, verified)
               for i, w in enumerate(self.witnesses)),
@@ -315,7 +369,7 @@ class Client:
 
     async def _compare_with_witness(self, idx: int, witness: Provider,
                                     verified: LightBlock) -> None:
-        wb = await witness.light_block(verified.height())
+        wb = await self._fetch(witness, verified.height(), witness=1)
         if wb.hash() != verified.hash():
             raise DivergenceError(idx, wb, verified)
 

@@ -15,7 +15,9 @@ instead.
 from __future__ import annotations
 
 import logging
+import time
 
+from ..libs import tracing
 from ..rpc.jsonrpc import JSONRPCServer, RPCError
 from .client import Client
 from .errors import LightClientError
@@ -62,13 +64,31 @@ class LightProxy:
     def close(self) -> None:
         self.server.close()
 
+    def _request_span(self, route):
+        """A verified route under span light.request: its entry -> its
+        reply built (folded; the sums say how the plane resolved the
+        requests since the last one, `failed` that this one raised)."""
+        async def traced(ctx, **params):
+            t0 = time.perf_counter_ns()
+            failed = 1
+            try:
+                reply = await route(ctx, **params)
+                failed = 0
+                return reply
+            finally:
+                tracing.light_leaf(
+                    tracing.LIGHT_REQUEST, t0, failed=failed,
+                    **(self.plane.resolved_since() if self.plane else {}))
+
+        return traced
+
     def _routes(self) -> dict:
         routes = {
             "status": self.status,
-            "commit": self.commit,
-            "validators": self.validators,
+            "commit": self._request_span(self.commit),
+            "validators": self._request_span(self.validators),
             "block": self.block,
-            "header": self.header,
+            "header": self._request_span(self.header),
             "health": self.health,
         }
         if self.forward is not None:
@@ -113,7 +133,10 @@ class LightProxy:
             # backpressure, not a verdict: same 429 vocabulary as the
             # RPC overload limiter and the mempool admission sheds
             raise RPCError(CODE_BUSY, str(e), "queue_full")
-        except (LightClientError, BlockNotFoundError) as e:
+        except (LightClientError, BlockNotFoundError, ValueError) as e:
+            # (ValueError: a block that fails validate_basic — a commit
+            # for another block, a set that does not hash to the
+            # header's — is a refusal too, not an internal error)
             raise RPCError(-32603, f"light verification failed: {e}")
         if lb is None:
             raise RPCError(-32603, "no trusted block yet")

@@ -6,8 +6,9 @@ A skipping verify is two >1/3-power commit checks — exactly the shape
 the batched verify kernel already accelerates — yet a proxy serving N
 concurrent read-mostly clients used to pay N independent serial
 verification walks. The ServingPlane here sits between the LightProxy
-RPC surface (one or many workers — ServingPool) and the light
-``Client`` and turns N concurrent requests into few wide launches:
+RPC surface (one or many workers — ServingPool; `cmd light` runs one)
+and the light ``Client`` and turns N concurrent requests into few wide
+launches:
 
   * **request coalescing + verified-header cache** — a singleflight
     map keyed by height makes concurrent requests for the same height
@@ -20,12 +21,26 @@ RPC surface (one or many workers — ServingPool) and the light
     by its lanes) takes ``types/validator_set.py`` CommitVerifyPlans
     from independent requests AND from both checks of one bisection
     step (the trusted-overlap check and the new set's own +2/3 check
-    run concurrently) and executes them as single wide ed25519
-    launches through crypto/batch.py's one guarded launch —
-    breaker-aware with host fallback, one known-answer sentinel lane
-    per device batch (a NaN-ing kernel fails the sentinel and the
-    batch re-runs on host instead of failing requests on wrong
-    verdicts);
+    run concurrently) and executes them as wide ed25519 launches
+    through crypto/batch.py's one guarded launch — breaker-aware with
+    host fallback, one known-answer sentinel lane per device launch (a
+    NaN-ing kernel fails the sentinel and the launch re-runs on host
+    instead of failing requests on wrong verdicts);
+
+  * **ONE launch shape, loaded at start** — every device launch of
+    the plane has ``batch_max`` lanes (1,024 as shipped: with the
+    callers a proxy is run for, the loop hands the collector plans
+    faster than a launch takes, so a cut is full whatever the
+    deadline, and a narrower second shape cost every start a second
+    program: PERF.md §6, PR 42). The sentinel's lane is counted in, so
+    a cut holds at most batch_max - 1 signature lanes; a plan wider
+    than that goes alone and is split onto the same shape; the
+    trust root's own check included (``ServingPlane.initialize``), and
+    ``ServingPlane.load_programs`` loads it off the loop before the
+    first request is accepted (span ``light.load_programs``): after
+    start nothing on the light path compiles, whatever a cut holds. A
+    verify that met a cold shape held the executor, and every request
+    behind it, for the minute or two of a compile;
 
   * **bounded pending-verify backlog** — the collector's parked +
     in-verify commit checks are the ``light.pending_verify`` entry in
@@ -35,15 +50,21 @@ RPC surface (one or many workers — ServingPool) and the light
     the ``light.verify`` failpoint's `delay` shape is the proof).
 
 The plane preserves the Client's verification semantics exactly —
-same bisection pivots, same error classes, same witness
-cross-checking after the target verifies, same trusted-store writes —
-only the signature work is pooled.
+the same three cases of a height against the trusted store (at or
+above the latest trusted block: forwards from it; below the first:
+backwards by hash linkage; between them: forwards BY SIGNATURE from
+the closest trusted block below, ``LightStore.light_block_before``,
+as the reference's verifyLightBlock does), same bisection pivots,
+same error classes, same witness cross-checking after the target
+verifies, same trusted-store writes — only the signature work is
+pooled.
 """
 
 from __future__ import annotations
 
 import asyncio
 import collections
+import itertools
 import logging
 import time
 
@@ -147,18 +168,25 @@ class LightVerifyCollector(BatchCollector):
     tables) and the per-lane verdicts scatter back per plan. A plan
     with any invalid lane gets the same VerificationError its inline
     execute() would raise — one request's lying provider never poisons
-    the verdicts of the batchmates."""
+    the verdicts of the batchmates.
+
+    A device launch has ``shapes.lanes`` lanes, the sentinel's lane
+    counted in: the cut leaves that lane free (``batch_max - 1``
+    signature lanes), and lanes past it (a plan that went alone) go
+    as further launches of the same shape."""
 
     def __init__(self, batch_max: int = 1024, flush_ms: float = 2.0,
                  pending_max: int = 1024,
                  device_threshold: int | None = None, controller=None):
         from ..crypto import batch as cbatch
+        from ..crypto.tpu.verify import LaunchShapes
 
         self.device_threshold = cbatch._DEVICE_THRESHOLD \
             if device_threshold is None else device_threshold
+        self.shapes = LaunchShapes(max(2, batch_max))
         super().__init__(
             queue="light.pending_verify", limit=pending_max,
-            batch_max=batch_max, flush_ms=flush_ms,
+            batch_max=max(1, batch_max - 1), flush_ms=flush_ms,
             run_batch=lambda plans: self._verify_jobs(plans),
             span_kinds=(tracing.LIGHT_QUEUE_WAIT, tracing.LIGHT_FLUSH),
             controller=controller)
@@ -229,19 +257,24 @@ class LightVerifyCollector(BatchCollector):
                     out[i] = pk.verify_signature(m, s)
                 except Exception:
                     out[i] = False
-            backend = "host"
-            if ed:
-                lanes = ([triples[i][0].bytes() for i in ed],
-                         [triples[i][1] for i in ed],
-                         [triples[i][2] for i in ed])
-                if injected:
-                    dv, backend = cbatch.host_ed25519_launch(*lanes)
-                else:
-                    dv, backend = cbatch.guarded_ed25519_launch(
-                        *lanes, workload="light",
-                        device_threshold=self.device_threshold)
-                out[np.asarray(ed)] = dv
-            cbatch.note_plane_launch(met.verify_launches, backend)
+            # one launch holds the shape less the sentinel's lane
+            room = self.shapes.lanes - 1
+            for part in [ed[lo:lo + room]
+                         for lo in range(0, len(ed), room)] or [[]]:
+                backend = "host"
+                if part:
+                    lanes = ([triples[i][0].bytes() for i in part],
+                             [triples[i][1] for i in part],
+                             [triples[i][2] for i in part])
+                    if injected:
+                        dv, backend = cbatch.host_ed25519_launch(*lanes)
+                    else:
+                        dv, backend = cbatch.guarded_ed25519_launch(
+                            *lanes, workload="light",
+                            device_threshold=self.device_threshold,
+                            shapes=self.shapes)
+                    out[np.asarray(part)] = dv
+                cbatch.note_plane_launch(met.verify_launches, backend)
             return out
         finally:
             met.verify_seconds.observe(time.perf_counter() - t0)
@@ -271,9 +304,75 @@ class ServingPlane:
         self.coalesced = 0
         self.cache_hits = 0
         self.cache_misses = 0
+        self.store_hits = 0     # LRU misses the trusted store served
+        self.verifications = 0  # singleflight verifications started
+        self.steps = 0          # verifications of one block by signature
+        self.hash_walks = 0     # heights served by the backwards walk
         self.sheds: dict[str, int] = {r: 0 for r in SHED_REASONS}
+        # An observer's list (None: nobody listens): the plane's
+        # decisions in the order its loop made them, as tuples
+        #   ("verify", rid, height)          a verification begins
+        #   ("step", rid, trusted, untrusted, trusting lanes, own
+        #    lanes, "ok" | the error's class name)
+        #   ("walk", rid, height)            a backwards walk's end
+        #   ("done", rid, height served or None, error class or None)
+        # so that a model of the verification rules (the benchmark's,
+        # the tests') can be replayed beside them. Nothing reads it
+        # here.
+        self.journal: list | None = None
+        self._told = (0, 0, 0)   # resolved_since()'s last reading
+        self._rids = itertools.count(1)
         global _ACTIVE_PLANE
         _ACTIVE_PLANE = self
+
+    async def load_programs(self) -> int:
+        """Load the collector's launch shape off the loop, before the
+        first request is accepted (ServingPool.start): afterwards no
+        launch of the light path compiles. Returns the programs
+        loaded. A load that fails is logged and left to the first
+        launch."""
+        from ..crypto import batch as cbatch
+
+        shapes = self.collector.shapes
+        t0 = time.perf_counter_ns()
+        try:
+            programs = await asyncio.get_running_loop().run_in_executor(
+                None, cbatch.load_ed25519_programs, shapes)
+        except Exception:
+            logger.exception("loading the light plane's programs "
+                             "failed; the first launches compile")
+            return 0
+        if programs:
+            seconds = (time.perf_counter_ns() - t0) / 1e9
+            tracing.TRACER.begin(
+                tracing.LIGHT_LOAD_PROGRAMS, start_ns=t0,
+                programs=programs, seconds=round(seconds, 3),
+                lanes=shapes.lanes).end()
+            logger.info("light plane: %d programs loaded in %.1f s "
+                        "(%d lanes)", programs, seconds, shapes.lanes)
+        return programs
+
+    async def initialize(self) -> LightBlock:
+        """Pin the client's trust root with its own +2/3 check run
+        through the collector: a launch of the plane's shape like every
+        other (the serial client's inline check would be a launch of a
+        bucket of its own, one more program at every start)."""
+        return await self.client.initialize(check=self.collector.check)
+
+    def resolved_since(self) -> dict:
+        """How the requests since the last call were resolved (the
+        sums a light.request span carries): hits = served from the
+        LRU or the trusted store, coalesced = joined a verification
+        in flight, misses = began one."""
+        now = (self.cache_hits + self.store_hits, self.coalesced,
+               self.verifications)
+        was, self._told = self._told, now
+        return dict(zip(("hits", "coalesced", "misses"),
+                        (a - b for a, b in zip(now, was))))
+
+    def _note(self, *event) -> None:
+        if self.journal is not None:
+            self.journal.append(event)
 
     def close(self) -> None:
         self.collector.close()
@@ -313,6 +412,7 @@ class ServingPlane:
             stored = self.client.store.get(height)
             if stored is not None and stored.time() + \
                     self.client.trust_options.period_ns > now_ns:
+                self.store_hits += 1
                 self.cache.put(stored, now_ns)
                 return stored
         task = self._inflight.get(height)
@@ -335,6 +435,7 @@ class ServingPlane:
             self._count_shed(SHED_QUEUE_FULL)
             raise LightServingShedError(self.collector.depth(),
                                         self.collector.pending_max)
+        self.verifications += 1
         task = asyncio.get_running_loop().create_task(
             self._verify_height(height, now_ns),
             name=f"light-verify-h{height}")
@@ -382,9 +483,21 @@ class ServingPlane:
 
     async def _verify_height(self, height: int,
                              now_ns: int) -> LightBlock:
+        rid = next(self._rids)
+        self._note("verify", rid, height)
+        try:
+            lb = await self._verify(rid, height, now_ns)
+        except BaseException as e:
+            self._note("done", rid, None, type(e).__name__)
+            raise
+        self._note("done", rid, lb.height(), None)
+        return lb
+
+    async def _verify(self, rid: int, height: int,
+                      now_ns: int) -> LightBlock:
         cl = self.client
         if not cl._initialized:
-            await cl.initialize()
+            await self.initialize()
         period = cl.trust_options.period_ns
         if height:
             stored = cl.store.get(height)
@@ -405,13 +518,17 @@ class ServingPlane:
                     raise OutsideTrustingPeriodError(
                         f"stored header {height} outside trusting "
                         "period")
-                return await cl._verify_backwards(height, now_ns)
-            latest = cl.store.latest()
-            if latest is not None and height < latest.height():
-                # hash-chain walk down — no commit signatures to
-                # batch; the client's walk (with its linkage cache)
-                # is already the right tool
-                lb = await cl._verify_backwards(height, now_ns)
+                return await self._walk_backwards(rid, height, now_ns)
+            # the three cases of a height against the trusted store
+            # (Client.trusted_base; the cache spares the store's
+            # decode of the base)
+            trusted = cl.trusted_base(
+                height, live=lambda h: self.cache.get(h, now_ns))
+            if trusted is None:
+                # below the first trusted block: the hash chain down —
+                # no commit signatures to batch; the client's walk
+                # (with its linkage cache) is already the right tool
+                lb = await self._walk_backwards(rid, height, now_ns)
                 self.cache.put(lb, now_ns)
                 return lb
             target = await cl._from_primary(height)
@@ -426,15 +543,15 @@ class ServingPlane:
                         "trusting period")
                 self.cache.put(latest, now_ns)
                 return latest
-        # verify from the head captured BEFORE the fetch (the serial
-        # client's order): a concurrent task may have advanced
-        # store.latest() past `height` while _from_primary awaited,
-        # and a re-read here would make _common_checks refuse a
-        # perfectly servable height ("target not above trusted")
-        trusted = latest
+            trusted = latest
+        # `trusted` was captured BEFORE the fetch (the serial client's
+        # order): a concurrent task may have advanced the store past
+        # `height` while _from_primary awaited, and a re-read here
+        # would make _common_checks refuse a perfectly servable
+        # height ("target not above trusted")
         assert trusted is not None
         try:
-            await self._verify_skipping(trusted, target, now_ns)
+            await self._verify_skipping(rid, trusted, target, now_ns)
             await cl._detect_divergence(target, now_ns)
         except DivergenceError:
             # a PROVEN fork purged the trusted store above the common
@@ -449,9 +566,16 @@ class ServingPlane:
         self.cache.put(target, now_ns)
         return target
 
+    async def _walk_backwards(self, rid: int, height: int,
+                              now_ns: int) -> LightBlock:
+        lb = await self.client._verify_backwards(height, now_ns)
+        self.hash_walks += 1
+        self._note("walk", rid, height)
+        return lb
+
     # -- batched skipping verification ---------------------------------
 
-    async def _verify_skipping(self, trusted: LightBlock,
+    async def _verify_skipping(self, rid: int, trusted: LightBlock,
                                target: LightBlock,
                                now_ns: int) -> None:
         """Client._verify_skipping with the commit checks routed
@@ -467,7 +591,7 @@ class ServingPlane:
                 raise LightClientError("bisection did not converge")
             block = pending[-1]
             try:
-                await self._verify_one(trusted, block, now_ns)
+                await self._verify_one(rid, trusted, block, now_ns)
             except NewValSetCantBeTrustedError:
                 pivot_h = (trusted.height() + block.height()) // 2
                 if pivot_h in (trusted.height(), block.height()) or \
@@ -482,8 +606,45 @@ class ServingPlane:
             trusted = block
             pending.pop()
 
-    async def _verify_one(self, trusted: LightBlock,
+    async def _verify_one(self, rid: int, trusted: LightBlock,
                           untrusted: LightBlock, now_ns: int) -> None:
+        """One step (span light.step, one journal entry): _check with
+        its outcome recorded. `lanes` are the trusting and the own
+        plan's, 0 where a plan was never built."""
+        t0 = time.perf_counter_ns()
+        gap = untrusted.height() - trusted.height()
+        lanes = [0, 0]
+        outcome = "ok"
+        self.steps += 1
+        try:
+            await self._check(trusted, untrusted, now_ns, lanes)
+        except BaseException as e:
+            outcome = type(e).__name__
+            raise
+        finally:
+            self._note("step", rid, trusted.height(), untrusted.height(),
+                       lanes[0], lanes[1], outcome)
+            tracing.light_leaf(
+                tracing.LIGHT_STEP, t0, adjacent=int(gap == 1), gap=gap,
+                pivots=int(outcome == "NewValSetCantBeTrustedError"))
+
+    @staticmethod
+    def _plan(build, lanes: list, slot: int) -> CommitVerifyPlan:
+        """Build one plan (span light.plan); its width into `lanes`."""
+        t0 = time.perf_counter_ns()
+        plan = None
+        try:
+            with tracing.TRACER.quiet():   # verify.collect, .sign_batch
+                plan = build()
+            lanes[slot] = len(plan)
+            return plan
+        finally:
+            tracing.light_leaf(tracing.LIGHT_PLAN, t0,
+                               lanes=len(plan) if plan else 0,
+                               trusting=int(slot == 0))
+
+    async def _check(self, trusted: LightBlock, untrusted: LightBlock,
+                     now_ns: int, lanes: list) -> None:
         """verifier.verify with the signature work pooled: the
         non-crypto checks run inline, the commit check(s) become
         CommitVerifyPlans awaited through the collector — the two
@@ -496,6 +657,12 @@ class ServingPlane:
         chain_id = cl.chain_id
         period = cl.trust_options.period_ns
         sh = untrusted.signed_header
+
+        def own():
+            return untrusted.validator_set.plan_commit_light(
+                chain_id, sh.commit.block_id, sh.header.height,
+                sh.commit)
+
         if untrusted.height() == trusted.height() + 1:
             _common_checks(chain_id, trusted, untrusted, period, now_ns)
             if sh.header.validators_hash != \
@@ -503,9 +670,7 @@ class ServingPlane:
                 raise VerificationFailedError(
                     "new validators_hash != trusted next_validators_hash")
             try:
-                plan = untrusted.validator_set.plan_commit_light(
-                    chain_id, sh.commit.block_id, sh.header.height,
-                    sh.commit)
+                plan = self._plan(own, lanes, 1)
             except VerificationError as e:
                 raise VerificationFailedError(
                     f"invalid commit: {e}") from e
@@ -517,23 +682,18 @@ class ServingPlane:
             return
         _common_checks(chain_id, trusted, untrusted, period, now_ns)
         try:
-            plan_trusting = trusted.validator_set.plan_commit_trusting(
-                chain_id, sh.commit, cl.trust_level.numerator,
-                cl.trust_level.denominator)
+            plan_trusting = self._plan(
+                lambda: trusted.validator_set.plan_commit_trusting(
+                    chain_id, sh.commit, cl.trust_level.numerator,
+                    cl.trust_level.denominator), lanes, 0)
         except VerificationError as e:
             raise NewValSetCantBeTrustedError(str(e)) from e
         try:
-            plan_light = untrusted.validator_set.plan_commit_light(
-                chain_id, sh.commit.block_id, sh.header.height,
-                sh.commit)
+            plan_light = self._plan(own, lanes, 1)
         except VerificationError as e:
-            # own-commit cannot even reach 2/3 — but the reference
-            # order gives the TRUSTING check its verdict first, and a
-            # failed overlap drives bisection, not rejection
-            try:
-                await self.collector.check(plan_trusting)
-            except VerificationError as e2:
-                raise NewValSetCantBeTrustedError(str(e2)) from e2
+            # own-commit cannot even reach 2/3: the step is refused as
+            # invalid whatever the trusting plan's signatures say, so
+            # neither plan is launched
             raise VerificationFailedError(f"invalid commit: {e}") from e
         # both-or-neither admission for the gathered pair: if only
         # ONE slot remains, parking the trusting check and shedding
@@ -548,13 +708,14 @@ class ServingPlane:
             self.collector.check(plan_trusting),
             self.collector.check(plan_light),
             return_exceptions=True)
-        # error-class parity with verifier.verify_non_adjacent: a
-        # failed TRUSTING check (insufficient overlap OR bad overlap
-        # signature) drives bisection; a failed own-commit check is a
+        # error-class parity with verifier.verify_non_adjacent: too
+        # little trusted power drove bisection above, at the plan; a
+        # signature that does not verify, in either check, is a
         # definitive rejection; anything else (shed, cancellation)
         # propagates untouched
         if isinstance(res_t, VerificationError):
-            raise NewValSetCantBeTrustedError(str(res_t)) from res_t
+            raise VerificationFailedError(
+                f"invalid commit: {res_t}") from res_t
         if isinstance(res_t, BaseException):
             raise res_t
         if isinstance(res_l, VerificationError):
@@ -585,6 +746,8 @@ class ServingPlane:
             "queue_depth": depth,
             "queue_capacity": cap,
             "shed": {r: n for r, n in self.sheds.items() if n},
+            "steps": self.steps,
+            "hash_walks": self.hash_walks,
             "trusted_height": self.client.store.latest_height(),
             "verify_launches": {
                 b: int(met.verify_launches.value(backend=b))
@@ -643,6 +806,16 @@ class ServingPool:
         logger.info("light serving pool: %d workers on %s:%s",
                     len(self.proxies), host, self.ports)
         return self.ports
+
+    async def start(self, host: str, port: int = 0) -> list[int]:
+        """What `cmd light` does to serve: load the plane's launch
+        shape, pin the trust root through it, THEN accept requests —
+        worker i on `port + i` (any free ports for 0)."""
+        await self.plane.load_programs()
+        await self.plane.initialize()
+        return await self.listen(
+            host, [port + i if port else 0
+                   for i in range(len(self.proxies))])
 
     def close(self) -> None:
         for proxy in self.proxies:

@@ -5,8 +5,11 @@ the highest trusted block after restart."""
 
 from __future__ import annotations
 
+import bisect
 import json
+import time
 
+from ..libs import tracing
 from ..state.store import _valset_from_json, _valset_to_json
 from ..types.block import Commit, Header
 from .types import LightBlock, SignedHeader
@@ -21,24 +24,34 @@ def _key(height: int) -> bytes:
 class LightStore:
     def __init__(self, db):
         self.db = db
-        # Highest saved height, maintained incrementally after the
-        # first scan. latest_height() used to walk the WHOLE prefix on
-        # every call — and the light client calls it (via latest()) on
-        # every single verify request, so a proxy serving a long chain
-        # paid an O(stored-heights) scan per request. None = unknown
-        # (not yet scanned, or invalidated by a delete/prune that may
-        # have removed the maximum).
-        self._latest: int | None = None
+        # The stored heights, ascending, read from the db by ONE scan
+        # and kept in step by save / delete / prune. The light client
+        # asks for the latest, the lowest and the closest height below
+        # a target on every verify request; a scan of the prefix there
+        # made a proxy over a long chain pay O(stored heights) a
+        # request. None = not scanned yet.
+        self._index: list[int] | None = None
+
+    def _heights(self) -> list[int]:
+        if self._index is None:
+            self._index = [int.from_bytes(k[len(_PREFIX):], "big")
+                           for k, _ in self.db.iterate_prefix(_PREFIX)]
+        return self._index
 
     def save(self, lb: LightBlock) -> None:
+        t0 = time.perf_counter_ns()
         payload = json.dumps({
             "header": lb.signed_header.header.to_proto().finish().hex(),
             "commit": lb.signed_header.commit.to_bytes().hex(),
             "validators": _valset_to_json(lb.validator_set),
         }).encode()
-        self.db.set(_key(lb.height()), payload)
-        if self._latest is not None:
-            self._latest = max(self._latest, lb.height())
+        with tracing.TRACER.quiet():   # db.write: the unit holds it
+            self.db.set(_key(lb.height()), payload)
+        hs = self._heights()
+        at = bisect.bisect_left(hs, lb.height())
+        if at == len(hs) or hs[at] != lb.height():
+            hs.insert(at, lb.height())
+        tracing.light_leaf(tracing.LIGHT_STORE_SAVE, t0)
 
     def get(self, height: int) -> LightBlock | None:
         raw = self.db.get(_key(height))
@@ -56,34 +69,39 @@ class LightStore:
         return self.get(latest_h) if latest_h else None
 
     def latest_height(self) -> int:
-        if self._latest is None:
-            best = 0
-            for k, _ in self.db.iterate_prefix(_PREFIX):
-                h = int.from_bytes(k[len(_PREFIX):], "big")
-                best = max(best, h)
-            self._latest = best
-        return self._latest
+        hs = self._heights()
+        return hs[-1] if hs else 0
 
     def lowest_height(self) -> int:
-        for k, _ in self.db.iterate_prefix(_PREFIX):
-            return int.from_bytes(k[len(_PREFIX):], "big")
-        return 0
+        hs = self._heights()
+        return hs[0] if hs else 0
+
+    def height_before(self, height: int) -> int:
+        """The highest stored height below `height`; 0 if none."""
+        hs = self._heights()
+        at = bisect.bisect_left(hs, height)
+        return hs[at - 1] if at else 0
+
+    def light_block_before(self, height: int) -> LightBlock | None:
+        """The stored block closest below `height` (reference:
+        light/store/db/db.go LightBlockBefore): what a height between
+        the first and the last trusted block is verified from."""
+        before = self.height_before(height)
+        return self.get(before) if before else None
 
     def heights(self) -> list[int]:
-        return [int.from_bytes(k[len(_PREFIX):], "big")
-                for k, _ in self.db.iterate_prefix(_PREFIX)]
+        return list(self._heights())
 
     def delete(self, height: int) -> None:
         self.db.delete(_key(height))
-        if self._latest is not None and height >= self._latest:
-            # the cached maximum may be gone; rescan on next read
-            self._latest = None
+        hs = self._heights()
+        at = bisect.bisect_left(hs, height)
+        if at < len(hs) and hs[at] == height:
+            del hs[at]
 
     def prune(self, keep: int) -> None:
-        hs = self.heights()
-        for h in hs[:-keep] if keep else hs:
+        hs = self._heights()
+        gone = hs[:-keep] if keep else list(hs)
+        for h in gone:
             self.db.delete(_key(h))
-        # pruning keeps the TOP `keep` heights, so the maximum
-        # survives when keep > 0 — but a full prune empties the store
-        if not keep:
-            self._latest = None
+        del hs[:len(gone)]

@@ -80,13 +80,22 @@ def verify_non_adjacent(chain_id: str, trusted: LightBlock,
     _common_checks(chain_id, trusted, untrusted, trusting_period_ns,
                    now_ns, max_clock_drift_ns)
     sh = untrusted.signed_header
-    # ≥ trust-level of the TRUSTED valset must have signed the new block
+    # ≥ trust-level of the TRUSTED valset must have signed the new
+    # block. Too little of its power among the signers is what drives
+    # bisection; a signature of it that does not verify is a forged
+    # commit and ends the verification (reference verifier.go
+    # VerifyNonAdjacent: only ErrNotEnoughVotingPowerSigned becomes
+    # ErrNewValSetCantBeTrusted)
     try:
-        trusted.validator_set.verify_commit_light_trusting(
+        plan = trusted.validator_set.plan_commit_trusting(
             chain_id, sh.commit,
             trust_level.numerator, trust_level.denominator)
     except VerificationError as e:
         raise NewValSetCantBeTrustedError(str(e)) from e
+    try:
+        plan.execute()
+    except VerificationError as e:
+        raise VerificationFailedError(f"invalid commit: {e}") from e
     # and the new valset itself must have +2/3 committed it
     try:
         untrusted.validator_set.verify_commit_light(

@@ -108,6 +108,25 @@ def _db(config: Config, name: str, in_memory: bool) -> DB:
     raise ValueError(f"unknown db_backend {backend!r}")
 
 
+def apply_crypto_config(crypto) -> None:
+    """[crypto] for a process that verifies (a node, `cmd light`): the
+    watchdog's and the ledger's knobs, and the backend's promise
+    (watchdog + ledger are jax-free; importing them here never
+    triggers backend bring-up)."""
+    from ..crypto.tpu import ledger as _ledger
+    from ..crypto.tpu import watchdog as _watchdog
+
+    _watchdog.configure(crypto.backend, crypto.watchdog_window_s)
+    if crypto.backend == "tpu":
+        # binding: a process promised a chip never serves from the
+        # host; "auto" and "cpu" take whatever backend JAX has
+        from ..crypto.tpu import backend as _tpu_backend
+
+        _tpu_backend.require_tpu()
+    if crypto.ledger_capacity != _ledger.capacity():
+        _ledger.set_capacity(crypto.ledger_capacity)
+
+
 class Node(Service):
     """reference: node/node.go Node."""
 
@@ -194,22 +213,7 @@ class Node(Service):
             _expanded.set_shard_crossover(
                 cfg.mesh.expanded_shard_crossover_keys or None)
             _resident.set_arena_shards(cfg.mesh.arena_shards)
-        # [crypto] watchdog/ledger knobs — same unconditional-when-
-        # loaded rule as [mesh] above (watchdog + ledger are jax-free;
-        # importing them here never triggers backend bring-up)
-        from ..crypto.tpu import ledger as _ledger
-        from ..crypto.tpu import watchdog as _watchdog
-
-        _watchdog.configure(cfg.crypto.backend,
-                            cfg.crypto.watchdog_window_s)
-        if cfg.crypto.backend == "tpu":
-            # binding: a node promised a chip never serves from the
-            # host; "auto" and "cpu" take whatever backend JAX has
-            from ..crypto.tpu import backend as _tpu_backend
-
-            _tpu_backend.require_tpu()
-        if cfg.crypto.ledger_capacity != _ledger.capacity():
-            _ledger.set_capacity(cfg.crypto.ledger_capacity)
+        apply_crypto_config(cfg.crypto)
         self.block_store = BlockStore(_db(cfg, "blockstore",
                                           self.in_memory))
         self.state_store = Store(_db(cfg, "state", self.in_memory))

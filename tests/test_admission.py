@@ -151,7 +151,7 @@ def test_garbage_flood_fully_shed_zero_abci_calls(monkeypatch):
     the test exercises the device code path without a compile)."""
     from tendermint_tpu.crypto.tpu import verify as tpu_verify
 
-    def fake_verify_batch(pubs, msgs, sigs):
+    def fake_verify_batch(pubs, msgs, sigs, shapes=None):
         return np.array(
             [Ed25519PubKey(p).verify_signature(m, s)
              for p, m, s in zip(pubs, msgs, sigs)], bool)
@@ -320,7 +320,7 @@ def test_collector_sentinel_mismatch_host_recheck(monkeypatch):
     from tendermint_tpu.crypto.tpu import verify as tpu_verify
 
     monkeypatch.setattr(tpu_verify, "verify_batch",
-                        lambda pubs, msgs, sigs: np.zeros(len(pubs),
+                        lambda pubs, msgs, sigs, shapes=None: np.zeros(len(pubs),
                                                           bool))
     cbatch.reset_breakers()
 
@@ -354,7 +354,7 @@ def test_collector_all_garbage_batch_trusted_when_sentinel_verifies(
     no per-signature host re-check and the breaker stays closed."""
     from tendermint_tpu.crypto.tpu import verify as tpu_verify
 
-    def fake_device(pubs, msgs, sigs):
+    def fake_device(pubs, msgs, sigs, shapes=None):
         out = np.zeros(len(pubs), bool)
         out[-1] = True  # the sentinel lane rides last and verifies
         return out

@@ -42,13 +42,16 @@ def _collector(run_batch, **kw):
     # weight 1 is the admission rule: min(len(pending), batch_max)
     ([1, 1, 1, 1, 1], 2, [[0, 1], [2, 3], [4]]),
     ([1, 1, 1], 8, [[0, 1, 2]]),
-    # weight len(plan) is the light rule: take while under batch_max …
-    ([2, 2, 2], 3, [[0, 1], [2]]),
-    # … so an item heavier than batch_max still goes, alone
+    # weight len(plan) is the light rule: a cut never outweighs
+    # batch_max (the plane's launches have a closed set of shapes) …
+    ([2, 2, 2], 3, [[0], [1], [2]]),
+    ([2, 1, 2, 1], 3, [[0, 1], [2, 3]]),
+    # … but an item heavier than batch_max still goes, alone
     ([5, 1, 1], 3, [[0], [1, 2]]),
+    ([1, 5, 1], 3, [[0], [1], [2]]),
     ([7], 3, [[0]]),
-], ids=["unit-fills", "unit-deadline", "weighted", "oversize-first",
-        "oversize-only"])
+], ids=["unit-fills", "unit-deadline", "weighted", "weighted-fills",
+        "oversize-first", "oversize-between", "oversize-only"])
 def test_cut_takes_while_under_batch_max(weights, batch_max, want):
     batches = []
 
@@ -195,7 +198,7 @@ def _triples(n, bad=()):
     return [pub] * n, msgs, sigs
 
 
-def _oracle(pubs, msgs, sigs):
+def _oracle(pubs, msgs, sigs, shapes=None):
     return np.array([Ed25519PubKey(p).verify_signature(m, s)
                      for p, m, s in zip(pubs, msgs, sigs)], bool)
 
@@ -227,7 +230,7 @@ def test_guarded_launch_ladder(monkeypatch, kernel, threshold,
 
     seen = []
 
-    def launch(pubs, msgs, sigs):
+    def launch(pubs, msgs, sigs, shapes=None):
         seen.append(len(pubs))
         return kernel(pubs, msgs, sigs)
 

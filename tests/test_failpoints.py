@@ -275,7 +275,7 @@ def test_production_batch_never_launches_while_open(monkeypatch):
 
     launches = []
 
-    def boom(pubs, msgs, sigs):
+    def boom(pubs, msgs, sigs, shapes=None):
         launches.append(len(pubs))
         raise RuntimeError("dead device")
 
@@ -318,7 +318,7 @@ def test_breaker_closes_on_successful_probe_and_readmits(monkeypatch):
     alive = {"up": False}
     launches = []
 
-    def flaky(pubs, msgs, sigs):
+    def flaky(pubs, msgs, sigs, shapes=None):
         launches.append(len(pubs))
         if not alive["up"]:
             raise RuntimeError("dead device")

@@ -221,17 +221,19 @@ def test_light_client_against_live_node():
 
 
 def test_backwards_verification():
-    """Requesting a height BELOW the latest trusted walks the hash
-    chain down from the nearest trusted anchor (reference
-    client.go:905 backwards, verifier.go:196 VerifyBackwards)."""
+    """Requesting a height BELOW THE FIRST trusted block walks the
+    hash chain down from it (reference client.go:905 backwards,
+    verifier.go:196 VerifyBackwards); a height between the first and
+    the last trusted block is verified by signature
+    (tests/test_light_between.py)."""
     import dataclasses
 
     from tendermint_tpu.light.verifier import verify_backwards
 
     chain = LightChain(8)
-    cl = _client(chain)
-    run(cl.verify_light_block_at_height(8))
-    assert cl.store.get(3) is None  # skipped straight to 8
+    cl = _client(chain, trust_height=8)
+    run(cl.initialize())
+    assert cl.store.heights() == [8]  # the root is the first trusted
     lb3 = run(cl.verify_light_block_at_height(3))
     assert lb3.height() == 3
     assert lb3.hash() == chain.blocks[3].hash()
@@ -259,8 +261,9 @@ def test_backwards_rejects_tampering_primary():
     """A primary serving a forged interim header during the walk-down
     fails verification instead of polluting the store."""
     chain = LightChain(8)
-    cl = _client(chain, primary=chain.provider(tamper_height=5))
-    run(cl.verify_light_block_at_height(8))
+    cl = _client(chain, trust_height=8,
+                 primary=chain.provider(tamper_height=5))
+    run(cl.initialize())
     with pytest.raises(LightClientError, match="backwards"):
         run(cl.verify_light_block_at_height(3))
     assert cl.store.get(5) is None and cl.store.get(3) is None
@@ -306,10 +309,8 @@ def test_dead_primary_promotes_witness():
 
 
 def test_store_latest_height_single_scan():
-    """LightStore.latest_height scans the prefix ONCE, then answers
-    O(1): saves update the cached maximum in place, deleting the
-    maximum (or a full prune) invalidates it, pruning to a keep-count
-    does not. The light client calls latest() on every verify request,
+    """LightStore scans the prefix ONCE, then answers from its index
+    of heights, which saves, deletes and prunes keep in step. The light client calls latest() on every verify request,
     so this scan was per-request cost."""
     chain = LightChain(8)
     inner = MemDB()
@@ -346,19 +347,20 @@ def test_store_latest_height_single_scan():
     store.delete(2)
     assert store.latest_height() == 7
     assert len(scans) == n_scans
-    # ...deleting the max invalidates it (one rescan, then O(1) again)
+    # ...and so does deleting the max: the index of heights is kept
+    # in step, the one scan stays the only one
     store.delete(7)
     assert store.latest_height() == 5
-    assert len(scans) == n_scans + 1
-    assert store.latest_height() == 5
-    assert len(scans) == n_scans + 1
-    # prune keeping the top heights preserves the maximum: no rescan
-    # from latest_height (prune/heights themselves scan, by design)
+    assert len(scans) == n_scans
+    # the other questions a request asks come from the same index
+    assert store.lowest_height() == 1
+    assert store.height_before(5) == 3 and store.height_before(1) == 0
+    assert store.light_block_before(4).height() == 3
+    assert store.light_block_before(1) is None
     store.prune(1)
     assert store.heights() == [5]
-    base = len(scans)
     assert store.latest_height() == 5
-    assert len(scans) == base
+    assert len(scans) == n_scans
     # full prune empties the store: the cache must not serve a ghost
     store.prune(0)
     assert store.latest_height() == 0
@@ -366,37 +368,46 @@ def test_store_latest_height_single_scan():
 
 
 def test_backwards_cache_and_trusted_anchor():
-    """The backwards-walk linkage cache serves repeat walks without
-    refetching, and anchor selection stays on TRUSTED blocks: a
-    cached interim with an older timestamp must not fail the
-    trusting-period check while a valid trusted anchor exists."""
+    """The backwards-walk linkage cache serves a walk that is made
+    again without refetching (a walk starts at the FIRST trusted
+    block, so only a walk that did not reach its end is made again),
+    and anchor selection stays on TRUSTED blocks: a cached interim
+    with an older timestamp must not fail the trusting-period check
+    while a valid trusted anchor exists."""
+    from tendermint_tpu.light.provider import ProviderError
+
     chain = LightChain(30)
     fetches = []
+    down = {15}   # heights the primary fails to hand over, once
 
     base = chain.provider()
 
     class Counting(Provider):
         async def light_block(self, height):
             fetches.append(height)
+            if height in down:
+                down.discard(height)
+                raise ProviderError(f"no answer for {height}")
             return await base.light_block(height)
 
-    cl = _client(chain, trust_height=1, primary=Counting())
-    run(cl.verify_light_block_at_height(30))  # trusted head at 30
-    run(cl.verify_light_block_at_height(10))  # walks 29..10
-    n_first = len(fetches)
-    assert n_first >= 19, f"first walk should fetch ~20 blocks, got {n_first}"
+    cl = _client(chain, trust_height=30, primary=Counting())
+    run(cl.initialize())                      # first trusted block: 30
+    with pytest.raises(ProviderError):
+        run(cl.verify_light_block_at_height(10))  # walks 29..16, dies
+    assert fetches[-1] == 15 and cl.store.heights() == [30]
     fetches.clear()
-    # second old-height walk in the cached range: zero new fetches
-    lb = run(cl.verify_light_block_at_height(20))
-    assert lb.height() == 20
-    assert fetches == [], f"cached walk refetched {fetches}"
+    # the same walk again: the linked range comes from the cache
+    lb = run(cl.verify_light_block_at_height(10))
+    assert lb.height() == 10
+    assert fetches == list(range(15, 9, -1)), \
+        f"cached part of the walk refetched: {fetches}"
     # anchor selection ignores cache entries: a cached interim with
     # an older header time sits closest above the target, the trust
     # period covers only the head — the walk must anchor on the
     # trusted head (and may still USE the cached link), not fail the
     # period check on the interim
-    cl2 = _client(chain, trust_height=1, primary=Counting())
-    run(cl2.verify_light_block_at_height(30))
+    cl2 = _client(chain, trust_height=30, primary=Counting())
+    run(cl2.initialize())
     cl2._interim_cache[29] = chain.blocks[29]
     # period covers h30 (time T0+30, now T0+100) but not h29
     cl2.trust_options.period_ns = 70 * 1_000_000_000 + 500_000_000
@@ -404,3 +415,40 @@ def test_backwards_cache_and_trusted_anchor():
     lb = run(cl2.verify_light_block_at_height(15))
     assert lb.height() == 15
     assert 29 not in fetches, "cached link for h29 was refetched"
+
+
+@pytest.mark.parametrize("target,base", [(10, 1), (25, 20), (21, 20)])
+def test_between_first_and_last_trusted_is_verified_by_signature(
+        target, base):
+    """reference client.go verifyLightBlock: a height between the first
+    and the last trusted block is verified FORWARDS, by signature, from
+    the closest trusted block below it — ONE fetch, no hash walk down
+    from the head."""
+    chain = LightChain(30)
+    fetches = []
+    inner = chain.provider()
+
+    class Counting(Provider):
+        async def light_block(self, height):
+            fetches.append(height)
+            return await inner.light_block(height)
+
+    cl = _client(chain, primary=Counting())
+    run(cl.verify_light_block_at_height(30))
+    if base != 1:
+        run(cl.verify_light_block_at_height(base))
+    assert cl.store.height_before(target) == base
+    assert cl.trusted_base(target).height() == base
+    assert cl.trusted_base(31).height() == 30      # above: the latest
+    fetches.clear()
+    lb = run(cl.verify_light_block_at_height(target))
+    assert lb.hash() == chain.blocks[target].hash()
+    assert fetches == [target]
+    assert cl.store.get(target) is not None
+    assert cl._interim_cache == {}                 # nobody walked
+    # a forged block there dies on its signatures, as above the head
+    cl2 = _client(chain, primary=chain.provider(tamper_height=target))
+    run(cl2.verify_light_block_at_height(30))
+    with pytest.raises((LightClientError, ValueError)):
+        run(cl2.verify_light_block_at_height(target))
+    assert cl2.store.get(target) is None

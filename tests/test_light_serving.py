@@ -205,8 +205,13 @@ def test_backwards_and_latest_through_plane():
         plane = _plane(chain)
         lb = await plane.get_verified(0)     # latest
         assert lb.height() == 12
-        lb3 = await plane.get_verified(3)    # hash-chain walk down
+        # between the first (1) and the last (12) trusted block: by
+        # signature from the closest one below, never a hash walk
+        before = _launches()
+        lb3 = await plane.get_verified(3)
         assert lb3.hash() == chain.blocks[3].hash()
+        assert _launches() > before and plane.hash_walks == 0
+        assert plane.steps == 2
         # latest again: served from the trusted store, no re-verify
         before = _launches()
         lb0 = await plane.get_verified(0)
@@ -308,7 +313,7 @@ def test_expired_store_never_served_trusted():
     async def go():
         plane = _plane(chain)
         await plane.get_verified(8)          # store: {1, 8}
-        await plane.get_verified(5)          # backwards walk: +{5}
+        await plane.get_verified(5)          # by signature from 1: +{5}
         # clock jump: 5 leaves its period, the head (8) stays inside
         t5 = chain.blocks[5].time()
         plane.client.now_fn = lambda: t5 + HOUR + 1
@@ -457,7 +462,7 @@ def test_device_sentinel_mismatch_reverifies_on_host(monkeypatch):
 
     monkeypatch.setattr(
         tpu_verify, "verify_batch",
-        lambda pubs, msgs, sigs: np.zeros(len(pubs), bool))
+        lambda pubs, msgs, sigs, shapes=None: np.zeros(len(pubs), bool))
     cbatch.reset_breakers()
     chain = LightChain(6)
 
@@ -485,7 +490,7 @@ def test_device_verdicts_trusted_when_sentinel_verifies(monkeypatch):
     from tendermint_tpu.crypto.ed25519 import Ed25519PubKey
     from tendermint_tpu.crypto.tpu import verify as tpu_verify
 
-    def oracle_device(pubs, msgs, sigs):
+    def oracle_device(pubs, msgs, sigs, shapes=None):
         return np.array(
             [Ed25519PubKey(p).verify_signature(m, s)
              for p, m, s in zip(pubs, msgs, sigs)], bool)
@@ -729,3 +734,179 @@ def test_e2e_manifest_light_proxy_op():
                 {"node": 0, "op": "light_proxy", "at_height": 2},
             ],
         })
+
+
+# --- the plane as `cmd light` runs it -----------------------------------
+
+
+def test_a_cut_leaves_the_sentinels_lane_free():
+    """A device launch holds at most the widest stated lane count, the
+    sentinel's lane counted in: cuts of at most batch_max - 1 signature
+    lanes, and a plan wider than that alone, in pieces."""
+    seen = []
+
+    class Spy(LightVerifyCollector):
+        def _verify_triples(self, triples):
+            seen.append(len(triples))
+            return np.ones(len(triples), bool)
+
+    chain = LightChain(6)
+
+    def plan(h):
+        lb = chain.blocks[h]
+        sh = lb.signed_header
+        return lb.validator_set.plan_commit_light(
+            CHAIN_ID, sh.commit.block_id, sh.header.height, sh.commit)
+
+    async def go():
+        coll = Spy(batch_max=8, flush_ms=5.0, pending_max=64)
+        assert coll.shapes.lanes == 8 and coll.batch_max == 7
+        await asyncio.gather(*(coll.check(plan(h)) for h in range(1, 6)))
+        coll.close()
+
+    run(go())
+    assert seen == [6, 6, 3]       # 3-lane plans: two a cut, never 9
+
+
+@pytest.mark.parametrize("lanes,launches", [
+    (3, [8]), (7, [8]), (8, [8, 8]), (20, [8, 8, 8])])
+def test_wide_batches_split_onto_the_stated_shapes(monkeypatch, lanes,
+                                                   launches):
+    """Lanes past the shape go as further launches of the same shape,
+    a sentinel each."""
+    from tendermint_tpu.crypto.tpu import verify as tpu_verify
+
+    got = []
+
+    def fake(pubs, msgs, sigs, shapes=None):
+        got.append(shapes.fit(len(pubs)))
+        return np.ones(len(pubs), bool)
+
+    monkeypatch.setattr(tpu_verify, "verify_batch", fake)
+    cbatch.reset_breakers()
+    chain = LightChain(2)
+    pk = chain.blocks[1].validator_set.validators[0].pub_key
+    coll = LightVerifyCollector(batch_max=8, device_threshold=1)
+    try:
+        out = coll._verify_triples([(pk, b"m", bytes(64))] * lanes)
+    finally:
+        coll.close()
+        cbatch.reset_breakers()
+    assert out.all() and [x for fit in got for x in fit] == launches
+
+
+def test_resolved_since_counts_every_request_once():
+    chain = LightChain(8)
+
+    async def go():
+        plane = _plane(chain)
+        await asyncio.gather(plane.get_verified(5), plane.get_verified(5),
+                             plane.get_verified(6))
+        assert plane.resolved_since() == {
+            "hits": 0, "coalesced": 1, "misses": 2}
+        await plane.get_verified(5)                  # the LRU
+        plane.cache.clear()
+        await plane.get_verified(6)                  # the trusted store
+        assert plane.resolved_since() == {
+            "hits": 2, "coalesced": 0, "misses": 0}
+        assert plane.resolved_since() == {
+            "hits": 0, "coalesced": 0, "misses": 0}
+        st = plane.status_check()
+        assert st["steps"] == 2 and st["hash_walks"] == 0
+        plane.close()
+
+    run(go())
+
+
+def test_follow_loop_outlives_a_saturated_plane(capsys):
+    """`cmd light`'s follow loop (cmd.follow_light) polls
+    plane.get_verified(0) beside the RPC callers. A flood of distinct
+    heights saturates the collector, and the loop's poll is shed like
+    any caller's: that tick is skipped and said so, the daemon lives,
+    and the next tick verifies the newest header. A block the client
+    refuses ends it, as an error of Client.update() did."""
+    from tendermint_tpu import cmd
+
+    chain = LightChain(16)
+
+    async def go():
+        plane = _plane(chain, cfg=LightConfig(flush_ms=1.0,
+                                              pending_max=4))
+        await plane.initialize()
+        failpoints.arm("light.verify", "delay", delay_ms=300)
+        try:
+            flood = [asyncio.ensure_future(plane.get_verified(h))
+                     for h in range(10, 15)]
+            for _ in range(400):
+                if plane.collector.saturated():
+                    break
+                await asyncio.sleep(0.005)
+            assert plane.collector.saturated()
+            shed_before = plane.sheds["queue_full"]
+            # one tick against the saturated plane: shed, not raised
+            await cmd.follow_light(plane, 1, 0.01, once=True)
+            assert plane.sheds["queue_full"] == shed_before + 1
+            loop = asyncio.ensure_future(
+                cmd.follow_light(plane, 1, 0.01))
+            await asyncio.gather(*flood, return_exceptions=True)
+            for _ in range(400):
+                if plane.client.store.latest_height() == 16:
+                    break
+                await asyncio.sleep(0.01)
+            assert plane.client.store.latest_height() == 16
+            assert not loop.done()
+            loop.cancel()
+        finally:
+            failpoints.reset()
+        # a refused block is not backpressure: it ends the loop
+        plane.client.primary = chain.provider(tamper_height=16)
+        plane.cache.clear()
+        plane.client.store.delete(16)
+        with pytest.raises(ValueError, match="different block"):
+            await cmd.follow_light(plane, 1, 0.01, once=True)
+        plane.close()
+
+    run(go())
+    out = capsys.readouterr().out
+    assert "follow tick skipped" in out
+    assert "verified height 16" in out
+
+
+def test_start_light_pool_is_the_commands_stack(monkeypatch, tmp_path):
+    """`cmd light --laddr` serves through cmd.start_light_pool: [crypto]
+    applied, [light] workers over ONE plane, the shape loaded, the
+    root pinned through the plane, then the ports; the store on the
+    configured durable db."""
+    from tendermint_tpu import cmd
+    from tendermint_tpu.config import Config
+    from tendermint_tpu.libs.db import MemDB as Mem, SqliteDB
+
+    loaded = []
+    monkeypatch.setattr(cbatch, "load_ed25519_programs",
+                        lambda shapes: loaded.append(shapes.lanes) or 1)
+    cfg = Config()
+    assert isinstance(cmd.light_store_db(cfg, ""), Mem)
+    db = cmd.light_store_db(cfg, str(tmp_path / "light.sqlite"))
+    assert isinstance(db, SqliteDB)
+    chain = LightChain(6)
+
+    async def go():
+        client = Client(
+            CHAIN_ID, TrustOptions(period_ns=HOUR, height=1,
+                                   hash=chain.blocks[1].hash()),
+            chain.provider(), [], LightStore(db), now_fn=lambda: NOW)
+        pool = await cmd.start_light_pool(cfg, client, "127.0.0.1", 0)
+        try:
+            assert loaded == [1024]
+            assert len(pool.ports) == cfg.light.workers == 2
+            assert all(p.plane is pool.plane for p in pool.proxies)
+            assert client.store.heights() == [1]
+            pool.plane.collector.device_threshold = 10**9
+            res = await HTTPClient("127.0.0.1", pool.ports[1]).call(
+                "commit", height=4)
+            assert res["signed_header"]["header"]["height"] == "4"
+        finally:
+            pool.close()
+
+    run(go())
+    db.close()

@@ -590,3 +590,76 @@ def test_pex_strikes_decay_but_survive_accepts(monkeypatch):
         assert len(rx._flood_strikes.get(skewed.id, [])) <= 2
 
     asyncio.run(go())
+
+
+def test_leaf_fold_ns_and_lookback_widen_a_keyed_run():
+    """A keyed run whose units are whole requests (the light proxy's):
+    `fold_ns` is the pause AND the overlap a run survives, `lookback`
+    how far back its entry is looked for."""
+    import time
+
+    t = tracing.Tracer(capacity=1024)
+    now = time.perf_counter_ns()
+    kind = tracing.LIGHT_REQUEST
+
+    def unit(start, **kw):
+        t.leaf(kind, start, fold_key=kind, **kw)
+
+    # a unit that began a second before the run's end: the default
+    # refuses the overlap, LIGHT_FOLD_NS takes it
+    unit(now - 1_000)
+    unit(now - 1_000_000_000)
+    assert len(t) == 2
+    t.clear()
+    wide = dict(fold_ns=tracing.LIGHT_FOLD_NS,
+                lookback=tracing.LIGHT_FOLD_LOOKBACK)
+    unit(now - 1_000, **wide)
+    unit(now - 1_000_000_000, hits=1, **wide)
+    for _ in range(tracing.LEAF_KEY_LOOKBACK + 8):   # a few launches
+        with t.span(tracing.CRYPTO_PACK):
+            pass
+    unit(time.perf_counter_ns() - 500, hits=1, **wide)
+    (entry,) = [r for r in t.snapshot() if r[0] == kind]
+    assert entry[6]["n"] == 3 and entry[6]["hits"] == 2
+    assert entry[5] >= 1_000_000_000     # the extent its units spread over
+    # past the wider lookback the run ends all the same
+    for _ in range(tracing.LIGHT_FOLD_LOOKBACK):
+        with t.span(tracing.CRYPTO_PACK):
+            pass
+    unit(time.perf_counter_ns() - 500, **wide)
+    assert len([r for r in t.snapshot() if r[0] == kind]) == 2
+
+
+def test_light_leaf_folds_by_kind():
+    import time
+
+    tracing.TRACER.clear()
+    for kind in (tracing.LIGHT_FETCH, tracing.LIGHT_PLAN) * 3:
+        tracing.light_leaf(kind, time.perf_counter_ns() - 100, lanes=1)
+    got = {r[0]: r[6] for r in tracing.TRACER.snapshot()}
+    assert got[tracing.LIGHT_FETCH]["n"] == 3
+    assert got[tracing.LIGHT_PLAN]["lanes"] == 3
+    tracing.TRACER.clear()
+
+
+
+def test_quiet_records_nothing_beneath_it():
+    """Inside Tracer.quiet() no span and no leaf reaches the ring, on
+    this task alone, and what was current comes back after it."""
+    import time
+
+    t = tracing.Tracer(capacity=64)
+    with t.span(tracing.CONSENSUS_HEIGHT) as outer:
+        with t.quiet():
+            with t.span(tracing.VERIFY_COLLECT) as inner:
+                inner.set_attr("lanes", 3)
+                with t.span(tracing.VERIFY_SIGN_BATCH):
+                    pass
+            t.leaf(tracing.DB_WRITE, time.perf_counter_ns() - 10)
+            assert t.begin(tracing.CRYPTO_PACK) is tracing.NOOP_SPAN
+            (t.current() or tracing.NOOP_SPAN).set_attr("backend", "x")
+        assert t.current() is outer
+        with t.span(tracing.VERIFY_COLLECT):
+            pass
+    assert [r[0] for r in t.snapshot()] == [
+        tracing.VERIFY_COLLECT, tracing.CONSENSUS_HEIGHT]

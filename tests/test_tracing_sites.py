@@ -1011,7 +1011,8 @@ def test_light_spans_cut_reasons_and_lineage(monkeypatch):
     assert [len(p) for p in plans] == [3, 3, 3]
 
     async def go():
-        col = LightVerifyCollector(batch_max=6, flush_ms=30.0,
+        # (a cut leaves the sentinel's lane free: batch_max - 1)
+        col = LightVerifyCollector(batch_max=7, flush_ms=30.0,
                                    device_threshold=1)
         try:
             with TRACER.span(tracing.P2P_RECV_MSG):
@@ -1042,6 +1043,134 @@ def test_light_spans_cut_reasons_and_lineage(monkeypatch):
     for v in verifies:
         assert tracing.LIGHT_FLUSH in ancestors(recs, v)
         assert v[3] != flushes[0][3]           # it ran in a worker thread
+
+
+LIGHT_KINDS = {
+    # kind: the sums a unit of the proxy's path leaves beside n, busy_ns
+    tracing.LIGHT_REQUEST: ("hits", "coalesced", "misses", "failed"),
+    tracing.LIGHT_FETCH: ("witness",),
+    tracing.LIGHT_PLAN: ("lanes", "trusting"),
+    tracing.LIGHT_STEP: ("adjacent", "pivots", "gap"),
+    tracing.LIGHT_STORE_SAVE: (),
+    tracing.LIGHT_DETECT: (),
+}
+
+
+def _light_requests(tmp_path=None):
+    """A proxy on its plane, two witnesses, asked over TCP for the
+    latest header, for a height between the trusted ones, for that
+    height again and for one that does not exist: the ring's entries of
+    the light kinds, and the plane."""
+    from tendermint_tpu.config import LightConfig
+    from tendermint_tpu.light import ServingPool
+    from tendermint_tpu.rpc.jsonrpc import HTTPClient, RPCError
+
+    from test_light import LightChain, _client
+
+    chain = LightChain(12)
+    box = {}
+
+    async def go():
+        client = _client(chain, witnesses=[chain.provider(),
+                                           chain.provider()])
+        await client.initialize()
+        pool = ServingPool(client, workers=1,
+                           config=LightConfig(flush_ms=1.0))
+        pool.plane.collector.device_threshold = 10**9
+        TRACER.clear()
+        # (listen, not start: the shapes' load is the next test's)
+        (port,) = await pool.listen("127.0.0.1")
+        rpc = HTTPClient("127.0.0.1", port)
+        try:
+            await rpc.call("commit")               # latest: 1 -> 12
+            await rpc.call("commit", height=5)     # between: 1 -> 5
+            await rpc.call("header", height=5)     # the cache's
+            with pytest.raises(RPCError):
+                await rpc.call("commit", height=99)
+        finally:
+            box["plane"] = pool.plane
+            pool.close()
+
+    run(go())
+    return [r for r in TRACER.snapshot() if r[0] in LIGHT_KINDS], \
+        box["plane"]
+
+
+@pytest.mark.parametrize("kind", sorted(LIGHT_KINDS))
+def test_light_request_path_spans_at_their_sites(kind):
+    """Every per-request site of the light proxy's path leaves ONE
+    folded entry a run (n, busy_ns and its sums), however many requests
+    ran: the ring holds 16,384 and a proxy answers hundreds a second."""
+    recs, plane = _light_requests()
+    mine = [r for r in recs if r[0] == kind]
+    assert len(mine) == 1, [r[0] for r in recs]
+    attrs = mine[0][6]
+    want = {
+        # four routes entered; one failed; 5 came from the LRU
+        tracing.LIGHT_REQUEST: dict(n=4, hits=1, coalesced=0, misses=3,
+                                    failed=1),
+        # primary: 12, 5, 99 (not found); witnesses: two a verified block
+        tracing.LIGHT_FETCH: dict(n=3 + 2 * 2, witness=4),
+        # 1 -> 12 and 1 -> 5: a trusting and an own plan each
+        tracing.LIGHT_PLAN: dict(n=4, trusting=2),
+        tracing.LIGHT_STEP: dict(n=2, adjacent=0, pivots=0,
+                                 gap=11 + 4),
+        tracing.LIGHT_STORE_SAVE: dict(n=2),
+        tracing.LIGHT_DETECT: dict(n=2),
+    }[kind]
+    for key, value in want.items():
+        assert attrs.get(key, 1 if key == "n" else 0) == value, attrs
+    assert set(LIGHT_KINDS[kind]) <= set(attrs) | {"n", "busy_ns"} \
+        or attrs.get("n", 1) == 1
+    if kind == tracing.LIGHT_PLAN:
+        assert attrs["lanes"] >= 4 * 2      # >1/3 and >2/3 of 4 keys
+    assert plane.steps == 2 and plane.hash_walks == 0
+    assert mine[0][2] == 0                  # a root: no request's child
+
+
+def test_light_load_programs_span_and_the_ports_after_it(monkeypatch):
+    """ServingPool.start loads the plane's launch shapes (span
+    light.load_programs {programs, seconds, lanes}) BEFORE a port is
+    open; a plane whose shapes were loaded already leaves no span."""
+    from tendermint_tpu.config import LightConfig
+    from tendermint_tpu.crypto import batch as cbatch
+    from tendermint_tpu.light import ServingPool
+
+    from test_light import LightChain, _client
+
+    order = []
+
+    def fake_load(shapes):
+        order.append(("load", shapes.lanes, shapes.blocks))
+        return 2 if len(order) == 1 else 0
+
+    monkeypatch.setattr(cbatch, "load_ed25519_programs", fake_load)
+    chain = LightChain(3)
+
+    async def go():
+        for _ in range(2):
+            pool = ServingPool(_client(chain), workers=2,
+                               config=LightConfig(batch_max=512))
+            real = pool.listen
+
+            async def listen(host, ports, real=real):
+                order.append(("listen", len(ports)))
+                return await real(host, ports)
+
+            pool.listen = listen
+            try:
+                ports = await pool.start("127.0.0.1")
+                assert len(ports) == 2 and all(ports)
+            finally:
+                pool.close()
+
+    TRACER.clear()
+    run(go())
+    assert order == [("load", 512, 2), ("listen", 2)] * 2
+    (span,) = [r for r in TRACER.snapshot()
+               if r[0] == tracing.LIGHT_LOAD_PROGRAMS]
+    assert span[6]["programs"] == 2 and span[6]["lanes"] == 512
+    assert span[6]["seconds"] >= 0
 
 
 # ------------------------------------------------------------ device names
