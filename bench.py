@@ -182,13 +182,13 @@ def main():
                      + "; ".join(faults))
         _emit(line)
 
-    def pipelined(launch, pidx, packed):
+    def pipelined(launch, pidx, packed, in_order):
         """Device-only ms/launch, excluding the per-call host round
         trip and input transfer: inputs device_put once, then the
         two-burst slope from tools/bench_util isolates execution."""
         pidx = jax.device_put(pidx)
         packed = {kk: jax.device_put(v) for kk, v in packed.items()}
-        return pipelined_exec_s(lambda: launch(pidx, packed))
+        return pipelined_exec_s(lambda: launch(pidx, packed, in_order))
 
     # PRODUCT HOT PATH: ValidatorSet.verify_commit* routes big commits
     # through per-validator comb tables cached on device across heights
@@ -207,8 +207,10 @@ def main():
     m0 = metrics_before()
     p50_1k = _measure(
         lambda: exp1k.verify(idx1k, msgs[:n1k], sigs[:n1k]), 7, warmed=True)
-    pidx1k, packed1k, _ = exp1k._prepare(idx1k, msgs[:n1k], sigs[:n1k])
-    dev1k, single1k, _tot = pipelined(exp1k._launch, pidx1k, packed1k)
+    pidx1k, packed1k, _wf, slots = exp1k._prepare(
+        idx1k, msgs[:n1k], sigs[:n1k])
+    dev1k, single1k, _tot = pipelined(exp1k._launch, pidx1k, packed1k,
+                                      slots is not None)
     emit({
         **common,
         "value": None,
@@ -250,14 +252,17 @@ def main():
     # (numpy), device = kernel launch to synced verdict on the packed
     # arrays. They do not sum exactly to p50 (transfer overlap), but
     # bound where the time goes.
-    pidx, packed, _wf = exp._prepare(idx, msgs, sigs)
+    pidx, packed, _wf, slots = exp._prepare(idx, msgs, sigs)
+    in_order = slots is not None
     host_ms = _measure(lambda: exp._prepare(idx, msgs, sigs), 5,
                        warmed=True) * 1e3
     dev_ms = _measure(
-        lambda: exp._launch(pidx, packed).block_until_ready(), 5) * 1e3
+        lambda: exp._launch(pidx, packed, in_order).block_until_ready(),
+        5) * 1e3
     line["host_pack_p50_ms"] = round(host_ms, 3)
     line["device_p50_ms"] = round(dev_ms, 3)
-    dev_pipe, dev_single, _tot = pipelined(exp._launch, pidx, packed)
+    dev_pipe, dev_single, _tot = pipelined(exp._launch, pidx, packed,
+                                           in_order)
     line["device_exec_ms_per_launch"] = (
         round(dev_pipe * 1e3, 3) if dev_pipe else None)
     line["single_launch_synced_ms"] = round(dev_single * 1e3, 3)

@@ -32,8 +32,19 @@ as (8, 128) over the trailing two dims, so a stored (..., 4, 22) table
 pads 22 -> 128 and wastes 5.8x HBM (a 10k-val set OOMed at 23 GB).
 Tables are therefore stored as (V*69*9, 128) rows — one point entry
 per row, 88 payload ints + 40 pad — and the verify kernel fetches all
-69 selected entries per lane in ONE flat row-gather before the window
-loop (69 small in-loop gathers from a multi-GB buffer scalarize).
+69 selected entries per lane before the window loop (69 small in-loop
+gathers from a multi-GB buffer scalarize), by one of two reads. The
+general one is ONE flat row-gather of 69 rows a lane: any lanes, any
+keys, and the chip pays it per row (~10 ns a 512-byte row, ~5 % of
+its bandwidth). A launch whose lanes are the set IN ORDER — key
+indices strictly ascending, and the keys from the first one's block
+of _BLOCK_KEYS to the last one no more than the launch has lanes: a
+Commit verified against its own set, a light check's prefix — puts
+key k's lane in slot k of the slab instead, reads the slab's rows as
+they lie (all nine entries of every window, 9x the bytes and no
+per-row cost) and selects by digit (_rows_in_order, a Pallas kernel).
+The rule is read off the indices at pack time (_in_order_base); both
+reads hand the window loop the same entries, bit for bit.
 Memory: V * 69 * 9 * 512 B ≈ 318 KB/key — 3.3 GB for 10,240 keys.
 """
 
@@ -212,13 +223,131 @@ WINDOWS_PER_ITER = int(__import__("os").environ.get(
     "TM_TPU_WINDOWS_PER_ITER", "3"))
 
 
+# Keys a grid step of _rows_in_order reads: an in-order launch's slab
+# starts on a block of them (a block's table rows, _BLOCK_KEYS * 621,
+# are whole (8, 128) tiles), and _SLAB_LANES lanes of selected entries
+# are turned limb-major at a time, so its lanes come in those.
+# Measured on the v5e at 10,240 lanes over 10,000 keys (PERF.md §6,
+# PR 43): 32 keys a step 5.71 ms, 16 keys 6.04, 8 keys 6.18, against
+# 10.60 for the gather and its transpose; 3.18 GB at the ~590 GB/s a
+# plain pass over HBM reaches is 5.4.
+_BLOCK_KEYS = 32
+_SLAB_LANES = 128
+# two blocks of table rows in flight (2 x 10.2 MB), the selected
+# entries of _SLAB_LANES lanes (4.5 MB), two output blocks
+_ROWS_VMEM_BYTES = 48 << 20
+
+
+def _interpret_pallas() -> bool:
+    """Off the TPU (tests, CPU nodes) the Pallas kernel is
+    interpreted: the same body as plain XLA operations."""
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
+def _rows_in_order(atab, first, dmag, payload: int):
+    """The table read of an in-order launch: lane i's key is
+    first + i (first on a block of _BLOCK_KEYS), so the entries wanted
+    lie in storage order. atab (V*69*9, 128) table rows, first (1,)
+    int32, dmag (69, N) digit magnitudes -> (69, payload, N): for
+    every window w and lane, the first `payload` ints of row
+    (key*69 + w)*9 + dmag[w, lane], limb-major: what the flat
+    row-gather, its transpose and its slice hand the window loop for
+    those keys. Each grid step takes the rows of _BLOCK_KEYS keys as
+    they lie in HBM (one contiguous DMA, double-buffered by the
+    pipeline) and, eight keys and one window at a time, loads the nine
+    candidate entries as nine sublane-strided vectors and keeps the
+    one the digit names; once _SLAB_LANES lanes are selected they are
+    transposed, a window at a time, into the output block. Steps past
+    the table's last block (V is not a multiple of the lanes) read
+    that block again: their lanes have no key and no verdict anyone
+    reads. The digits come in as the program holds them, (windows,
+    lanes), and are turned key-major in the kernel: handed in
+    transposed, the operand's row-major layout is carried back by
+    XLA's layout assignment through every field array of the program
+    (lanes no longer minor: `decompress` 4.1 -> 15.7 ms, `msm` 19.0 ->
+    33.4 on the v5e, PERF.md §6, PR 43)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = dmag.shape[1]
+    key_rows = _WINDOWS * _ENTRIES
+    kb, slab = _BLOCK_KEYS, _SLAB_LANES
+    per = slab // kb                # grid steps to a slab of lanes
+    assert n % slab == 0 and (kb * key_rows) % 8 == 0
+    last = (atab.shape[0] // key_rows - 1) // kb
+
+    def kernel(first_ref, d_ref, tab_ref, out_ref, picked, by_key):
+        step = pl.program_id(0) % per
+
+        @pl.when(step == 0)
+        def _():
+            by_key[...] = d_ref[...].T              # (lanes, windows)
+
+        def eight_keys(g, carry):
+            r0 = g * (8 * key_rows)
+            at = pl.multiple_of(step * kb + g * 8, 8)
+            d = by_key[pl.ds(at, 8), :]             # (8 keys, windows)
+            for w in range(_WINDOWS):
+                dw = d[:, w:w + 1]
+                got = tab_ref[pl.ds(r0 + w * _ENTRIES, 8,
+                                    stride=key_rows), :]
+                for j in range(1, _ENTRIES):
+                    rows = tab_ref[pl.ds(r0 + w * _ENTRIES + j, 8,
+                                         stride=key_rows), :]
+                    got = jnp.where(dw == j, rows, got)
+                picked[w, pl.ds(at, 8), :] = got
+            return carry
+
+        jax.lax.fori_loop(0, kb // 8, eight_keys, 0)
+
+        @pl.when(step == per - 1)
+        def _():
+            def limb_major(w, carry):
+                out_ref[w] = picked[w].T[:payload, :]
+                return carry
+
+            jax.lax.fori_loop(0, _WINDOWS, limb_major, 0)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((_WINDOWS, payload, n), atab.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n // kb,),
+            in_specs=[
+                pl.BlockSpec((slab, slab),
+                             lambda i, first: (0, i // per)),
+                pl.BlockSpec(
+                    (kb * key_rows, _ROW),
+                    lambda i, first: (
+                        jnp.minimum(first[0] // kb + i, last), 0)),
+            ],
+            out_specs=pl.BlockSpec((_WINDOWS, payload, slab),
+                                   lambda i, first: (0, 0, i // per)),
+            scratch_shapes=[
+                pltpu.VMEM((_WINDOWS, slab, _ROW), atab.dtype),
+                pltpu.VMEM((slab, slab), dmag.dtype)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_ROWS_VMEM_BYTES),
+        interpret=_interpret_pallas(),
+        name="comb_rows_in_order",
+    )(first, jnp.pad(dmag, ((0, slab - _WINDOWS), (0, 0))), atab)
+
+
 @functools.cache
 def _xcore(wpi: int = WINDOWS_PER_ITER):
     """The shared verify body: everything after the padded message
     exists on device. Both front-ends trace through this — bytes
     (`_xkernel`: msg is the (N, W) buffer the host packed) and
     structured template+patch (`_skernel`: msg is the words
-    assemble_core forms); sha512.challenge_words takes either."""
+    assemble_core forms); sha512.challenge_words takes either.
+    `in_order` (static): the launch's lanes are consecutive keys from
+    idx[0] (ExpandedKeys._in_order_base), and the table entries are
+    read in storage order (_rows_in_order), not gathered."""
     import jax
     import jax.numpy as jnp
 
@@ -230,7 +359,8 @@ def _xcore(wpi: int = WINDOWS_PER_ITER):
     assert _WINDOWS % wpi == 0, "windows-per-iter must divide 69"
     L = fe.NLIMB  # payload layout: 4 coords of L limbs per table row
 
-    def core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab):
+    def core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab,
+             in_order=False):
         # The phase scopes follow the order the operations are traced
         # in (gather and msm open twice): moving one would change the
         # program and with it every cached executable.
@@ -275,23 +405,29 @@ def _xcore(wpi: int = WINDOWS_PER_ITER):
             neg_r = ed.neg(R)
 
         with jax.named_scope(tv.PHASE_GATHER):
-            # Gather every window's selected entry in ONE flat
-            # row-gather.
+            # Every window's selected entry: ONE flat row-gather,
+            # or the rows as they lie where the lanes are consecutive
+            # keys.
             dsign = digk < 0
             dmag = jnp.abs(digk)  # (69, N) in 0..8
-            flat = (
-                idx[None, :] * (_WINDOWS * _ENTRIES)
-                + jnp.arange(_WINDOWS, dtype=jnp.int32)[:, None] * _ENTRIES
-                + dmag
-            )  # (69, N)
-            sel = jnp.take(atab, flat.reshape(-1), axis=0)  # (69*N, 128)
-            # ONE transpose to the kernel's limb-major layout; slicing
-            # any pad ints fuses into it. Doing this per window instead
-            # (69 small transposes out of a lane-major buffer) costs
-            # ~60 ms of device time at 16k lanes — measured, not
-            # hypothetical.
-            sel = jnp.transpose(sel.reshape(_WINDOWS, n, _ROW), (0, 2, 1))
-            sel = sel[:, : 4 * L, :]  # (69, 4L, N)
+            if in_order:
+                sel = _rows_in_order(atab, idx[:1], dmag, 4 * L)
+            else:
+                flat = (
+                    idx[None, :] * (_WINDOWS * _ENTRIES)
+                    + jnp.arange(_WINDOWS, dtype=jnp.int32)[:, None]
+                    * _ENTRIES
+                    + dmag
+                )  # (69, N)
+                sel = jnp.take(atab, flat.reshape(-1), axis=0)  # (69*N, 128)
+                # ONE transpose to the kernel's limb-major layout;
+                # slicing any pad ints fuses into it. Doing this per
+                # window instead (69 small transposes out of a
+                # lane-major buffer) costs ~60 ms of device time at
+                # 16k lanes — measured, not hypothetical.
+                sel = jnp.transpose(
+                    sel.reshape(_WINDOWS, n, _ROW), (0, 2, 1))
+                sel = sel[:, : 4 * L, :]  # (69, 4L, N)
 
         def one_window(w, acc_a, acc_b):
             e = jax.lax.dynamic_index_in_dim(sel, w, 0, keepdims=False)
@@ -335,9 +471,11 @@ def _xkernel(wpi: int = WINDOWS_PER_ITER):
 
     core = _xcore(wpi)
 
-    @jax.jit
-    def kernel(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab):
-        return core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab)
+    @functools.partial(jax.jit, static_argnames=("in_order",))
+    def kernel(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab, btab,
+               *, in_order=False):
+        return core(idx, akeys, sb, msg, nblocks, s_ok, key_ok, atab,
+                    btab, in_order)
 
     return kernel
 
@@ -482,15 +620,15 @@ def _skernel(wpi: int = WINDOWS_PER_ITER):
     core = _xcore(wpi)
     assemble = assemble_core()
 
-    @functools.partial(jax.jit, static_argnames=("width",))
+    @functools.partial(jax.jit, static_argnames=("width", "in_order"))
     def skernel(idx, akeys, sb, s_ok, key_ok, atab, btab,
                 pre, pre_len, suf, suf_len, patch, split, patch_len,
-                group, *, width):
+                group, *, width, in_order=False):
         with fe.as_calls(idx.shape[0] <= _CALLS_MAX_LANES):
             msg, nblocks = assemble(pre, pre_len, suf, suf_len, patch,
                                     split, patch_len, group, width)
             return core(idx, akeys, sb, msg, nblocks, s_ok, key_ok,
-                        atab, btab)
+                        atab, btab, in_order)
 
     return skernel
 
@@ -552,6 +690,11 @@ def _aval(a):
     placed = getattr(a, "committed", False)
     return jax.ShapeDtypeStruct(a.shape, a.dtype,
                                 sharding=a.sharding if placed else None)
+
+
+# what an in-order program's shape carries in tv._COMPILED_SHAPES
+# beside the gathering program's of the same lanes and width
+_ROWS_KEY = {False: (), True: ("in_order",)}
 
 
 def _count_compile(kernel: str, shape: tuple) -> None:
@@ -904,13 +1047,60 @@ class ExpandedKeys:
         # pre-padding here would home every pad lane (idx 0) on device
         # 0 and inflate the common per-device bucket for all shards.
         bucket = n if self.sharded else self._bucket(n)
+        base = self._in_order_base(idx, bucket)
+        if base is not None:
+            sig_raw, well_formed = self._sig_rows(sigs, 0)
+            idx, packed, slots = self._in_slots(
+                idx, base, bucket, tv.pack_sig_msg(sig_raw, msgs))
+            return idx, packed, well_formed, slots
         pad = bucket - n
         sig_raw, well_formed = self._sig_rows(sigs, pad)
         if pad:
             idx = np.concatenate([idx, np.zeros(pad, np.int32)])
             msgs = list(msgs) + [b""] * pad
         packed = tv.pack_sig_msg(sig_raw, msgs)
-        return idx, packed, well_formed
+        return idx, packed, well_formed, None
+
+    def _in_order_base(self, idx: np.ndarray, bucket: int) -> int | None:
+        """The rule that picks the table read, off the launch's key
+        indices alone: the first key of the slab an IN-ORDER launch
+        reads (_rows_in_order), None for a launch that gathers. In
+        order: indices strictly ascending (each key at most once, in
+        the set's order) and the keys from the first one's block to
+        the last one no more than the launch's lanes, so that a lane
+        per key of the slab adds no lane of device work. A Commit
+        verified against its own set is (absent votes leave gaps), a
+        light check's prefix is; votes in arrival order, a window of
+        commits (keys repeat) and a sparse subset (span >> lanes) are
+        not. Launches whose lanes go to several devices (key-range-
+        sharded tables: _route; a mesh at _SHARD_MIN lanes:
+        _shard_args) keep the gather, which is local to each; so do
+        lanes that are not whole slabs (verify_structured's `lanes`)
+        and a set smaller than one block."""
+        if self.sharded or bucket % _SLAB_LANES or (
+                self.mesh is not None and bucket >= tv._SHARD_MIN) \
+                or len(self.pubkeys) < _BLOCK_KEYS:
+            return None
+        base = int(idx[0]) // _BLOCK_KEYS * _BLOCK_KEYS
+        if int(idx[-1]) - base >= bucket or not (idx[1:] > idx[:-1]).all():
+            return None
+        return base
+
+    def _in_slots(self, idx, base: int, bucket: int, per_lane: dict):
+        """An in-order launch's lanes: key k's lane in slot k - base
+        of `bucket`, every per-lane array scattered into zeros (a slot
+        without a signature carries s_ok False, as a pad lane's
+        verdict is never read). Returns (the program's idx: the slab's
+        keys, clipped to the set's last; the arrays; the slots the
+        caller's lanes took, where their verdicts are read)."""
+        slots = idx - base
+        keys = np.minimum(base + np.arange(bucket, dtype=np.int32),
+                          len(self.pubkeys) - 1)
+        out = {}
+        for name, a in per_lane.items():
+            out[name] = np.zeros((bucket,) + a.shape[1:], a.dtype)
+            out[name][slots] = a
+        return keys, out, slots
 
     def _shard_args(self, idx, fields, repl_keys=()):
         """Shared mesh dispatch for both launch forms (replicated
@@ -1005,8 +1195,9 @@ class ExpandedKeys:
         btab = jax.device_put(tv.b_comb_tables(), repl_s)
         return lidx, routed, btab, repl_s, slot
 
-    def _launch(self, idx, packed):
-        """Device side of verify: one kernel launch over packed lanes."""
+    def _launch(self, idx, packed, in_order=False):
+        """Device side of verify: one kernel launch over packed lanes
+        (`in_order`: idx is _in_slots')."""
         if self.sharded:
             lidx, routed, btab, _repl_s, slot = self._route(idx, packed)
             _count_compile(
@@ -1025,13 +1216,15 @@ class ExpandedKeys:
         # count at the POST-padding shape: mesh_lane_pad may merge two
         # requested buckets into one compiled shape
         _count_compile("expanded",
-                       (idx.shape[0], packed["msg"].shape[1]))
+                       (idx.shape[0], packed["msg"].shape[1])
+                       + _ROWS_KEY[in_order])
         return _xkernel(WINDOWS_PER_ITER)(
             idx=idx,
             akeys=self.akeys,
             key_ok=self.key_ok,
             atab=self.tables,
             btab=btab,
+            in_order=in_order,
             **packed,
         )
 
@@ -1047,8 +1240,9 @@ class ExpandedKeys:
         self._maybe_reshard()
 
         def prepare():
-            idx, packed, well_formed = self._prepare(indices, msgs, sigs)
-            return (idx, packed), well_formed
+            idx, packed, well_formed, slots = self._prepare(
+                indices, msgs, sigs)
+            return (idx, packed), slots, well_formed
 
         return self._traced_verify(n, "expanded", prepare, self._launch)
 
@@ -1058,9 +1252,11 @@ class ExpandedKeys:
         enqueue) / device_exec (wait-until-ready) / readback (D2H
         copy) children — the stage vocabulary BENCH's stage_breakdown
         and /debug/trace report. `prepare` returns (launch_args,
-        well_formed); `launch(*launch_args)` returns the device
-        verdict array. One launch-ledger record per call, its stages
-        timed around the same blocks the spans bracket."""
+        slots, well_formed), slots None for a launch that gathers
+        (its verdicts are the first n lanes);
+        `launch(*launch_args, in_order)` returns the device verdict
+        array. One launch-ledger record per call, its stages timed
+        around the same blocks the spans bracket."""
         from ...libs.metrics import tpu_metrics
 
         if not self.sharded:
@@ -1073,11 +1269,12 @@ class ExpandedKeys:
                 t.span(tracing.CRYPTO_VERIFY, lanes=n, backend=backend):
             rec.lanes = n
             with rec.stage("pack"), t.span(tracing.CRYPTO_PACK, lanes=n):
-                launch_args, well_formed = prepare()
+                launch_args, slots, well_formed = prepare()
+            rec.rows = "gathered" if slots is None else "in_order"
             rec.bytes_h2d = _ledger.nbytes_of(launch_args)
             with rec.stage("dispatch"), \
                     t.span(tracing.CRYPTO_DISPATCH, lanes=n):
-                out = launch(*launch_args)
+                out = launch(*launch_args, slots is not None)
             if hasattr(out, "block_until_ready"):
                 with rec.stage("exec"), \
                         t.span(tracing.CRYPTO_DEVICE_EXEC, lanes=n):
@@ -1092,7 +1289,8 @@ class ExpandedKeys:
                 rec.n_devices = self.n_shards
                 rec.active_devices = [
                     str(d) for d in self.mesh.devices.flat]
-            res = full[:n] & well_formed
+            res = (full[:n] if slots is None else full[slots]) \
+                & well_formed
             rec.verdicts(res)
             return res
 
@@ -1143,31 +1341,36 @@ class ExpandedKeys:
             if n > lanes or width != self._S_WIDTHS[0]:
                 raise ValueError("batch does not fit the live program")
             bucket = lanes
-        pad = bucket - n
-        sig_raw, well_formed = self._sig_rows(sigs, pad)
 
         def padded(a, rows):
             return np.pad(a, ((0, rows),) + ((0, 0),) * (a.ndim - 1))
 
-        if pad:
-            idx = np.concatenate([idx, np.zeros(pad, np.int32)])
-        fields = dict(
-            sb=sig_raw,
-            s_ok=tv.s_range_ok(sig_raw),
+        templates = dict(
             pre=np.pad(sbatch.pre, ((0, kp - k), (0, 128 - pw))),
             pre_len=padded(sbatch.pre_len, kp - k),
             suf=np.pad(sbatch.suf, ((0, kp - k), (0, 64 - sw))),
-            suf_len=padded(sbatch.suf_len, kp - k),
-            patch=padded(sbatch.patch, pad),
-            split=padded(sbatch.split, pad),
-            patch_len=padded(sbatch.patch_len, pad),
-            group=padded(sbatch.group, pad),
-        )
-        return idx, fields, well_formed, width
+            suf_len=padded(sbatch.suf_len, kp - k))
+        per_lane = dict(patch=sbatch.patch, split=sbatch.split,
+                        patch_len=sbatch.patch_len, group=sbatch.group)
+        base = self._in_order_base(idx, bucket)
+        if base is not None:
+            sig_raw, well_formed = self._sig_rows(sigs, 0)
+            idx, fields, slots = self._in_slots(
+                idx, base, bucket,
+                dict(sb=sig_raw, s_ok=tv.s_range_ok(sig_raw), **per_lane))
+            return idx, {**fields, **templates}, well_formed, width, slots
+        pad = bucket - n
+        sig_raw, well_formed = self._sig_rows(sigs, pad)
+        if pad:
+            idx = np.concatenate([idx, np.zeros(pad, np.int32)])
+        fields = dict(
+            sb=sig_raw, s_ok=tv.s_range_ok(sig_raw), **templates,
+            **{name: padded(a, pad) for name, a in per_lane.items()})
+        return idx, fields, well_formed, width, None
 
     _S_REPL = ("pre", "pre_len", "suf", "suf_len")
 
-    def _launch_structured(self, idx, fields, width):
+    def _launch_structured(self, idx, fields, width, in_order=False):
         if self.sharded:
             import jax
 
@@ -1192,18 +1395,21 @@ class ExpandedKeys:
             return _RoutedVerdicts(out, slot)
         idx, fields, btab = self._shard_args(
             idx, fields, repl_keys=self._S_REPL)
-        _count_compile("structured", (idx.shape[0], width))
+        _count_compile("structured",
+                       (idx.shape[0], width) + _ROWS_KEY[in_order])
         with _phase_names_in_key():
             return _skernel(WINDOWS_PER_ITER)(
                 idx=idx, akeys=self.akeys, key_ok=self.key_ok,
-                atab=self.tables, btab=btab, width=width, **fields)
+                atab=self.tables, btab=btab, width=width,
+                in_order=in_order, **fields)
 
     def _structured_avals(self, bucket: int) -> dict:
         """What _launch_structured hands the program for `bucket`
         lanes (the shape after _shard_args' padding), as shapes, dtypes
         and placements alone: _prepare_structured's layout (templates
         padded to _S_GROUPS x 128 / 64 B, types/sign_batch.py's
-        per-lane fields) without a batch to prepare."""
+        per-lane fields) without a batch to prepare. The same for
+        either table read."""
         import jax
 
         from ...types.sign_batch import PATCH_W
@@ -1250,9 +1456,9 @@ class ExpandedKeys:
         self._maybe_reshard()
 
         def prepare():
-            idx, fields, well_formed, width = self._prepare_structured(
-                indices, sbatch, sigs, lanes)
-            return (idx, fields, width), well_formed
+            idx, fields, well_formed, width, slots = \
+                self._prepare_structured(indices, sbatch, sigs, lanes)
+            return (idx, fields, width), slots, well_formed
 
         return self._traced_verify(n, "structured", prepare,
                                    self._launch_structured)
@@ -1260,17 +1466,19 @@ class ExpandedKeys:
 
     def load_structured(self, lanes: int) -> int:
         """Compile (or load from the compile cache) and run once the
-        structured program at `lanes` lanes and the narrow width, over
-        these tables and lanes that verify nothing: what
-        verify_structured(lanes=...) launches from then on. Returns
-        the programs loaded: 1, or 0 where it was loaded already."""
+        structured programs at `lanes` lanes and the narrow width,
+        over these tables and lanes that verify nothing: what
+        verify_structured(lanes=...) launches from then on, the
+        gathering program and, where a launch of so many lanes can be
+        in order (_in_order_base), the one that reads the rows as
+        they lie. Returns the programs loaded, 0 where all were
+        loaded already."""
         from ...types.sign_batch import PATCH_W
 
         width = self._S_WIDTHS[0]
         if self.sharded:
             return 0
         _LIVE_LANES.add(lanes)
-        known = ("structured", lanes, width) in tv._COMPILED_SHAPES
         kp = self._S_GROUPS
         zeros = np.zeros
         fields = dict(
@@ -1280,9 +1488,16 @@ class ExpandedKeys:
             patch=zeros((lanes, PATCH_W), np.uint8),
             split=zeros(lanes, np.int32), patch_len=zeros(lanes, np.int32),
             group=zeros(lanes, np.int32))
-        out = self._launch_structured(zeros(lanes, np.int32), fields, width)
-        out.block_until_ready()
-        return 0 if known else 1
+        idx = zeros(lanes, np.int32)
+        both = self._in_order_base(idx[:1], lanes) is not None
+        loaded = 0
+        for in_order in (False, True)[:1 + both]:
+            known = ("structured", lanes, width) + _ROWS_KEY[in_order] \
+                in tv._COMPILED_SHAPES
+            self._launch_structured(idx, fields, width,
+                                    in_order).block_until_ready()
+            loaded += not known
+        return loaded
 
 
 # -- process-wide LRU of expanded sets (one active + one in transition) --
@@ -1410,10 +1625,11 @@ def structured_phases() -> dict[str, str]:
     if keys is None or shape is None or keys.sharded:
         raise ValueError("no structured launch on one chip's tables "
                          "yet: nothing to map")
-    bucket, width = shape
+    bucket, width, *rows = shape
     with _phase_names_in_key():
         compiled = _skernel(WINDOWS_PER_ITER).lower(
-            width=width, **keys._structured_avals(bucket)).compile()
+            width=width, in_order=bool(rows),
+            **keys._structured_avals(bucket)).compile()
     return tv.phase_of_instructions(compiled.as_text())
 
 

@@ -186,7 +186,8 @@ class LaunchRecord:
 
     __slots__ = ("kernel", "workload", "wall", "mono", "_t0",
                  "lanes", "capacity", "bytes_h2d", "bytes_d2h",
-                 "compile_hit", "device", "n_devices", "shard_lanes",
+                 "compile_hit", "rows", "device", "n_devices",
+                 "shard_lanes",
                  "active_devices", "verdict", "ok_lanes", "stages_ms",
                  "error", "_done", "_restamp")
 
@@ -201,6 +202,9 @@ class LaunchRecord:
         self.bytes_h2d = 0
         self.bytes_d2h = 0
         self.compile_hit: bool | None = None
+        # the comb-table read of a launch on expanded tables
+        # (crypto/tpu/expanded.py): gathered | in_order; None elsewhere
+        self.rows: str | None = None
         self.device = ""
         self.n_devices = 1
         self.shard_lanes: list[int] | None = None
@@ -290,6 +294,7 @@ class LaunchRecord:
             "bytes_h2d": int(self.bytes_h2d),
             "bytes_d2h": int(self.bytes_d2h),
             "compile_cache": cc,
+            "rows": self.rows,
             "stages_ms": dict(self.stages_ms),
             "shard_lanes": (list(self.shard_lanes)
                             if self.shard_lanes is not None else None),
@@ -436,9 +441,13 @@ def rollup(records: list[dict] | None = None,
     for r in records:
         w = workloads.setdefault(r["workload"], {
             "launches": 0, "lanes": 0, "bytes_h2d": 0, "bytes_d2h": 0,
-            "backends": {}, "verdicts": {}, "_exec": []})
+            "backends": {}, "verdicts": {}, "rows_lanes": {},
+            "_exec": []})
         w["launches"] += 1
         w["lanes"] += r.get("lanes", 0)
+        if r.get("rows"):
+            w["rows_lanes"][r["rows"]] = \
+                w["rows_lanes"].get(r["rows"], 0) + r.get("lanes", 0)
         w["bytes_h2d"] += r.get("bytes_h2d", 0)
         w["bytes_d2h"] += r.get("bytes_d2h", 0)
         w["backends"][r["backend"]] = \

@@ -1,7 +1,7 @@
 """The main path's kernels, compiled for the chip at their real shapes.
 
 The TPU's compiler is installed here and compiles for a chip that is
-described, not attached (`jax.experimental.topologies`). These four
+described, not attached (`jax.experimental.topologies`). These five
 compiles catch, at no chip time, what CPU tests cannot: a program the
 v5e's compiler refuses, or one that no longer fits a 16 GB chip. A
 compile that passes is not a chip run — `chip_smoke.py` is.
@@ -13,7 +13,7 @@ has started in whichever xdist worker was given the file — never at
 import, in a `skipif`, in `parametrize` or in conftest.py, where every
 worker would race for the library and collect different tests.
 Everything built from the topology (sharding, shapes) is built in
-fixtures or tests too, all four tests live in this one file, and the
+fixtures or tests too, all five tests live in this one file, and the
 persistent compile cache is off around them (an entry written for a
 described chip cannot be read back without one, and warns).
 """
@@ -106,7 +106,7 @@ def _keys_stub(n: int):
     keys = object.__new__(ex.ExpandedKeys)
     keys.pubkeys = tuple(hashlib.sha256(b"k%d" % i).digest()
                          for i in range(n))
-    keys.sharded = False
+    keys.sharded, keys.mesh = False, None
     return keys
 
 
@@ -117,7 +117,7 @@ def test_xkernel_compiles_for_v5e(one_chip, no_compile_cache):
 
     n = 128
     # ~190-byte sign bytes: 3 SHA-512 blocks, bucketed to 4
-    idx, packed, _ = _keys_stub(n)._prepare(
+    idx, packed, *_ = _keys_stub(n)._prepare(
         list(range(n)), [b"m" * 190] * n, [bytes(64)] * n)
     assert packed["msg"].shape[1] == 4 * 128 - 64
     args = {k: _spec(v, one_chip, LANES) for k, v in packed.items()}
@@ -129,10 +129,17 @@ def test_xkernel_compiles_for_v5e(one_chip, no_compile_cache):
     assert m.argument_size_in_bytes > KEYS * ex._KEY_BYTES
 
 
-def test_skernel_compiles_for_v5e(one_chip, no_compile_cache):
-    """expanded._skernel (sign bytes assembled on device), the shape
-    ValidatorSet.verify_commit launches for a 10,000-validator commit."""
+@pytest.mark.parametrize("in_order", [False, True])
+def test_skernel_compiles_for_v5e(one_chip, no_compile_cache, in_order,
+                                  monkeypatch):
+    """expanded._skernel (sign bytes assembled on device), the shapes
+    ValidatorSet.verify_commit launches for a 10,000-validator commit:
+    the lanes in the set's order (the table rows read as they lie, by
+    the Pallas kernel as the chip's compiler takes it, not
+    interpreted) and any other lanes (the row gather)."""
     from tendermint_tpu.crypto.tpu import expanded as ex
+
+    monkeypatch.setattr(ex, "_interpret_pallas", lambda: False)
     from tendermint_tpu.types.block import (
         BlockID, BlockIDFlag, Commit, CommitSig, PartSetHeader)
     from tendermint_tpu.types.sign_batch import CommitSignBatch
@@ -146,16 +153,31 @@ def test_skernel_compiles_for_v5e(one_chip, no_compile_cache):
                               1_753_928_000_000_000_000 + i, bytes(64))
                     for i in range(n)])
     lanes = list(range(n))
-    idx, fields, _, width = _keys_stub(n)._prepare_structured(
+    idx, fields, _, width, slots = _keys_stub(n)._prepare_structured(
         lanes, CommitSignBatch("chip-smoke", commit, lanes),
         [bytes(64)] * n)
+    assert slots is not None        # 128 lanes in order
     args = {k: _spec(v, one_chip,
                      None if k in ex.ExpandedKeys._S_REPL else LANES)
             for k, v in fields.items()}
     compiled = ex._skernel().lower(
-        idx=_spec(idx, one_chip, LANES), width=width, **args,
-        **_table_specs(one_chip)).compile()
-    _fits(compiled, "_skernel")
+        idx=_spec(idx, one_chip, LANES), width=width, in_order=in_order,
+        **args, **_table_specs(one_chip)).compile()
+    m = _fits(compiled, "_skernel")
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == in_order
+    if in_order:
+        # no (69 x lanes, 128) buffer of gathered rows is left behind,
+        # and a trace's reader finds the kernel under its phase
+        assert m.temp_size_in_bytes < 0.7e9
+        assert ex.tv.PHASE_GATHER in {
+            phase for name, phase in
+            ex.tv.phase_of_instructions(text).items()
+            if name.startswith("comb_rows_in_order")}
+    # lanes stay the minor dimension of the program's arrays: a kernel
+    # operand handed in transposed turned 31,656 of them lanes-major
+    # through layout assignment, and every phase 2-4x slower (PR 43)
+    assert text.count(f"{LANES}]{{0,1:") < 100
 
 
 def test_table_builder_compiles_for_v5e(one_chip, no_compile_cache):

@@ -196,9 +196,9 @@ def test_expanded_traced_verify_records():
     ek.sharded = False
 
     def prepare():
-        return (np.zeros((4, 2), np.uint8),), np.ones(2, bool)
+        return (np.zeros((4, 2), np.uint8),), None, np.ones(2, bool)
 
-    def launch(arg):
+    def launch(arg, in_order):
         return np.ones(4, bool)
 
     with ledger.workload("light"):
@@ -208,7 +208,7 @@ def test_expanded_traced_verify_records():
     assert r["kernel"] == "expanded" and r["workload"] == "light"
     assert r["lanes"] == 2 and r["capacity"] == 4
     assert r["bytes_h2d"] == 8 and r["bytes_d2h"] == 4
-    assert r["verdict"] == "ok"
+    assert r["verdict"] == "ok" and r["rows"] == "gathered"
     for stage in ("pack", "dispatch", "readback"):
         assert stage in r["stages_ms"]
 

@@ -136,7 +136,8 @@ def test_structured_avals_are_what_a_launch_hands_the_program(
     e.akeys = np.zeros((len(pubs), 32), np.uint8)
     e.key_ok = np.ones(len(pubs), bool)
     e.tables = np.zeros((len(pubs), 4), np.int32)
-    idx, fields, _wf, width = e._prepare_structured(lanes, sb, sigs)
+    idx, fields, _wf, width, _slots = e._prepare_structured(
+        lanes, sb, sigs)
     idx, fields, btab = e._shard_args(idx, fields, repl_keys=e._S_REPL)
     args = dict(idx=idx, akeys=e.akeys, key_ok=e.key_ok, atab=e.tables,
                 btab=btab, **fields)
@@ -298,6 +299,208 @@ def test_vote_batch_structured_verdicts(monkeypatch):
     assert list(verdicts) == expect and not all_ok
 
 
+# ------------------------------- the two table reads, side by side
+
+
+_RUN_KEYS = 150     # not a multiple of a bucket, nor of _BLOCK_KEYS
+
+
+@pytest.fixture(scope="module")
+def run_keys():
+    """150 keys' tables, key 77 one that does not decompress (key_ok
+    False), and the seeds of the others."""
+    seeds = [hashlib.sha256(b"run%d" % i).digest()
+             for i in range(_RUN_KEYS)]
+    pubs = [ref.public_key_from_seed(s) for s in seeds]
+    bad = next(b for b in (bytes([v]) + bytes(31) for v in range(2, 99))
+               if ref.decompress(b) is None)
+    pubs[77] = bad
+    return ex.ExpandedKeys(pubs), seeds
+
+
+def _run_commit(seeds, keys, spoil=None):
+    """A Commit whose slot i is key keys[i]'s vote (the lanes of a
+    launch in the set's order), signed; `spoil` {position: how}."""
+    bid = BlockID(hash=bytes(range(32)),
+                  part_set_header=PartSetHeader(2, bytes(32)))
+    css = [CommitSig(BlockIDFlag.NIL if k % 7 == 3 else BlockIDFlag.COMMIT,
+                     bytes([k % 256] * 20), 10**18 + 977 * k, b"")
+           for k in keys]
+    commit = Commit(height=977, round=1, block_id=bid, signatures=css)
+    for i, k in enumerate(keys):
+        sig = ref.sign(seeds[k], commit.vote_sign_bytes(CHAIN, i))
+        how = (spoil or {}).get(i)
+        if how == "forged":
+            sig = sig[:7] + bytes([sig[7] ^ 1]) + sig[8:]
+        elif how == "malformed":
+            sig = sig[:63]
+        css[i].signature = sig
+    sb = CommitSignBatch(CHAIN, commit, list(range(len(keys))))
+    return sb, [c.signature for c in css]
+
+
+def _absent(rng_seed: int, share: float):
+    rng = np.random.default_rng(rng_seed)
+    gone = rng.choice(_RUN_KEYS, int(round(share * _RUN_KEYS)),
+                      replace=False)
+    return [k for k in range(_RUN_KEYS) if k not in set(gone.tolist())]
+
+
+# (the keys of the launch in order, {position: how spoiled})
+_RUNS = {
+    "whole_set": (list(range(_RUN_KEYS)), {}),
+    "absent_3pc": (_absent(43, 0.03), {}),
+    "prefix": (list(range(101)), {}),
+    "base_above_0": (list(range(40, 140)), {}),
+    "ends_at_last_key": (list(range(50, _RUN_KEYS)), {}),
+    "gaps_forged_malformed_bad_key": (
+        [k for k in range(36, 150) if k % 9], {5: "forged",
+                                               11: "malformed"}),
+}
+
+
+@pytest.mark.parametrize("case, form", [
+    (c, "structured") for c in sorted(_RUNS)] + [
+    # the bytes path shares the slots and the core: one bucket's cases
+    ("ends_at_last_key", "bytes"),
+    ("gaps_forged_malformed_bad_key", "bytes")])
+def test_in_order_read_gives_the_gathers_verdicts(run_keys, case, form):
+    """The program that reads the rows as they lie and the one that
+    gathers them, on the SAME launch (an in-order launch's arrays are
+    a legal gather launch too): the whole verdict vectors equal, lane
+    for lane, empty and clipped slots included, and what the caller
+    reads is the oracle's."""
+    e, seeds = run_keys
+    keys, spoil = _RUNS[case]
+    sb, sigs = _run_commit(seeds, keys, spoil)
+    if form == "structured":
+        idx, fields, wf, width, slots = e._prepare_structured(
+            keys, sb, sigs)
+        both = [np.asarray(e._launch_structured(idx, fields, width, io))
+                for io in (True, False)]
+        got = e.verify_structured(keys, sb, sigs)
+    else:
+        idx, packed, wf, slots = e._prepare(keys, sb.materialize(), sigs)
+        both = [np.asarray(e._launch(idx, packed, io))
+                for io in (True, False)]
+        got = e.verify(keys, sb.materialize(), sigs)
+    assert slots is not None and len(both[0]) == ex.ExpandedKeys._bucket(
+        len(keys))
+    base = keys[0] // ex._BLOCK_KEYS * ex._BLOCK_KEYS
+    assert list(slots) == [k - base for k in keys]
+    assert list(idx) == [min(base + i, _RUN_KEYS - 1)
+                         for i in range(len(idx))]
+    assert both[0].tolist() == both[1].tolist()
+    expect = [k != 77 and spoil.get(i) is None
+              for i, k in enumerate(keys)]
+    assert list(both[0][slots] & wf) == expect == list(got)
+    # no slot without a signature yields a verdict
+    empty = np.ones(len(both[0]), bool)
+    empty[slots] = False
+    assert not both[0][empty].any()
+    if spoil:
+        refused = [i for i, ok in enumerate(got) if not ok]
+        assert refused == sorted(
+            list(spoil) + [keys.index(77)]) and 77 in keys
+
+
+def _stub(n_keys: int, mesh=None):
+    e = _tableless_keys(n_keys)
+    e.mesh = mesh
+    return e
+
+
+@pytest.mark.parametrize("idx, lanes, base", [
+    (np.arange(300), None, 0),                      # the set, whole
+    (np.arange(40, 300), None, 32),                 # from its block
+    (np.array([3, 4, 9, 100, 127]), None, 0),       # gaps, one bucket
+    (np.arange(37, 160), None, 32),                 # 37..159 from 32
+    (np.arange(37, 161), None, None),               # one key too far
+    (np.arange(5, 45), 128, 0),                     # the live shape
+    (np.arange(60, 170), 128, None),                # 32..169 > 128 lanes
+    (np.array([0, 1, 2, 2, 3]), None, None),        # a repeat
+    (np.array([4, 3, 2, 1]), None, None),           # descending
+    (np.random.default_rng(7).permutation(200), None, None),
+    (np.arange(0, 3000, 25), None, None),           # sparse: span >> n
+    (np.arange(90), 100, None),                     # lanes off a slab
+])
+def test_rule_that_picks_the_table_read(idx, lanes, base):
+    idx = idx.astype(np.int32)
+    bucket = lanes or ex.ExpandedKeys._bucket(len(idx))
+    assert _stub(3000)._in_order_base(idx, bucket) == base
+
+
+def test_lanes_spread_over_devices_keep_the_gather():
+    idx = np.arange(2048, dtype=np.int32)
+    assert _stub(3000)._in_order_base(idx, 2048) == 0
+    assert _stub(3000, ex.tv._mesh())._in_order_base(idx, 2048) is None
+    assert _stub(3000, ex.tv._mesh())._in_order_base(idx[:1024],
+                                                    1024) == 0
+    sharded = _stub(3000)
+    sharded.sharded = True
+    assert sharded._in_order_base(idx, 2048) is None
+
+
+def test_ledger_record_says_which_read_ran(run_keys):
+    from tendermint_tpu.crypto.tpu import ledger
+
+    e, seeds = run_keys
+    keys = _RUNS["ends_at_last_key"][0]
+    sb, sigs = _run_commit(seeds, keys)
+    assert list(e.verify_structured(keys, sb, sigs)) == [
+        k != 77 for k in keys]
+    r = ledger.snapshot()[-1]
+    assert (r["kernel"], r["rows"], r["lanes"], r["capacity"]) == (
+        "structured", "in_order", 100, 128)
+    back = keys[::-1]
+    sb, sigs = _run_commit(seeds, back)
+    assert list(e.verify_structured(back, sb, sigs)) == [
+        k != 77 for k in back]
+    r = ledger.snapshot()[-1]
+    assert (r["rows"], r["lanes"], r["capacity"]) == ("gathered", 100, 128)
+    roll = ledger.rollup(ledger.snapshot()[-2:])["workloads"]
+    assert [w["rows_lanes"] for w in roll.values()] == [
+        {"in_order": 100, "gathered": 100}]
+
+
+def test_phases_of_an_in_order_launch(run_keys, monkeypatch):
+    """structured_phases() after an in-order launch maps THAT
+    program, from the launch's own executable: nothing compiles."""
+    from jax import monitoring
+
+    e, seeds = run_keys
+    keys = _RUNS["prefix"][0]
+    sb, sigs = _run_commit(seeds, keys)
+    monkeypatch.setattr(ex, "_CACHE", type(ex._CACHE)({b"k": e}))
+    monkeypatch.setattr(ex.tv, "_COMPILED_SHAPES", {})
+    assert e.verify_structured(keys, sb, sigs).sum() == len(keys) - 1
+    assert next(reversed(ex.tv._COMPILED_SHAPES)) == (
+        "structured", 128, 192, "in_order")
+    compiles = []
+
+    def on(event, secs, **kw):
+        if event.endswith("backend_compile_duration"):
+            compiles.append(secs)
+
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        phase_of = ex.structured_phases()
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    assert compiles == []
+    assert ex.tv.PHASE_GATHER in set(phase_of.values())
+
+
+def test_load_structured_loads_both_reads(run_keys, monkeypatch):
+    e, _ = run_keys
+    monkeypatch.setattr(ex, "_LIVE_LANES", set())
+    monkeypatch.setattr(ex.tv, "_COMPILED_SHAPES", {})
+    assert e.load_structured(128) == 2
+    assert set(ex.tv._COMPILED_SHAPES) == {
+        ("structured", 128, 192), ("structured", 128, 192, "in_order")}
+    assert e.load_structured(128) == 0
+
+
 # ---------------------------------------------- the assembly alone
 
 
@@ -400,7 +603,8 @@ def test_assembled_bytes_equal_host_assemble(case):
     build, splits, groups, width, how = _ASSEMBLE_CASES[case]
     sb = build()
     n = len(sb)
-    _idx, f, _wf, got_width = _tableless_keys(1)._prepare_structured(
+    _idx, f, _wf, got_width, _slots = _tableless_keys(
+        1)._prepare_structured(
         [0] * n, sb, [bytes(64)] * n)
     assert set(sb.split.tolist()) == splits
     assert sb.pre.shape[0] == groups and got_width == width

@@ -331,9 +331,9 @@ def test_structured_kernel_takes_calls_up_to_its_lane_limit(
                     for i in range(n)])
     keys = object.__new__(ex.ExpandedKeys)
     keys.pubkeys = tuple(bytes([i]) * 32 for i in range(n_keys))
-    keys.sharded = False
+    keys.sharded, keys.mesh = False, None
     lanes = [i % n_keys for i in range(n)]
-    idx, fields, _, width = keys._prepare_structured(
+    idx, fields, _, width, _slots = keys._prepare_structured(
         lanes, CommitSignBatch("form", commit, list(range(n))),
         [bytes(64)] * n)
 

@@ -134,7 +134,7 @@ def kernel_rollup(records: list[dict]) -> dict:
 def render_workloads(workloads: dict) -> str:
     header = (f"  {'workload':<12} {'launches':>8} {'lanes':>9} "
               f"{'h2d':>10} {'d2h':>10} {'exec p50':>9} "
-              f"{'exec p99':>9}  backends / verdicts")
+              f"{'exec p99':>9}  backends / verdicts / table-read lanes")
     lines = [header]
     for name, w in sorted(workloads.items(),
                           key=lambda kv: -kv[1].get("launches", 0)):
@@ -145,7 +145,8 @@ def render_workloads(workloads: dict) -> str:
             f"{_fmt_bytes(w.get('bytes_d2h')):>10} "
             f"{w.get('exec_ms_p50', 0):>9} {w.get('exec_ms_p99', 0):>9}"
             f"  {_fmt_mix(w.get('backends'))} / "
-            f"{_fmt_mix(w.get('verdicts'))}")
+            f"{_fmt_mix(w.get('verdicts'))} / "
+            f"{_fmt_mix(w.get('rows_lanes'))}")
     return "\n".join(lines)
 
 
@@ -166,6 +167,7 @@ def summarize(sections: list[tuple[str, dict, list[dict]]],
     rounds without reparsing tables."""
     backends: dict[str, int] = {}
     verdicts: dict[str, int] = {}
+    rows_lanes: dict[str, int] = {}
     total = {"launches": 0, "lanes": 0, "bytes_h2d": 0, "bytes_d2h": 0}
     by_workload: dict[str, int] = {}
     for _label, workloads, _recs in sections:
@@ -178,8 +180,10 @@ def summarize(sections: list[tuple[str, dict, list[dict]]],
                 backends[b] = backends.get(b, 0) + n
             for v, n in (w.get("verdicts") or {}).items():
                 verdicts[v] = verdicts.get(v, 0) + n
+            for v, n in (w.get("rows_lanes") or {}).items():
+                rows_lanes[v] = rows_lanes.get(v, 0) + n
     out = dict(total, workloads=by_workload, backends=backends,
-               verdicts=verdicts)
+               verdicts=verdicts, rows_lanes=rows_lanes)
     if watchdog:
         out["effective_backend"] = watchdog.get("effective_backend")
     if hbm:

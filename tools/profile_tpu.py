@@ -79,12 +79,13 @@ def main():
     rec["warm_verify_p50_ms"] = round(1e3 * sorted(warms)[1], 2)
 
     t = time.perf_counter()
-    pidx, packed, _ = exp._prepare(idx, msgs, sigs)
+    pidx, packed, _wf, slots = exp._prepare(idx, msgs, sigs)
+    in_order = slots is not None
     rec["host_prepare_ms"] = round(1e3 * (time.perf_counter() - t), 2)
     log(f"host prepare {rec['host_prepare_ms']:.1f}ms")
     for i in range(3):
         t = time.perf_counter()
-        o = exp._launch(pidx, packed)
+        o = exp._launch(pidx, packed, in_order)
         o.block_until_ready()
         log(f"device launch #{i} {1e3 * (time.perf_counter() - t):.1f}ms")
 
@@ -96,7 +97,7 @@ def main():
     dpidx = jax.device_put(pidx)
     dpacked = {k: jax.device_put(v) for k, v in packed.items()}
     per, single, totals = pipelined_exec_s(
-        lambda: exp._launch(dpidx, dpacked))
+        lambda: exp._launch(dpidx, dpacked, in_order))
     for k, tt in totals.items():
         log(f"pipelined x{k} (device-resident inputs): total "
             f"{1e3 * tt:.1f}ms")
@@ -109,7 +110,8 @@ def main():
     # host->device transfer (the production cold-call shape).
     for k in (1, 4):
         t = time.perf_counter()
-        outs = [exp._launch(pidx, packed) for _ in range(k)]
+        outs = [exp._launch(pidx, packed, in_order)
+                for _ in range(k)]
         outs[-1].block_until_ready()
         dt = 1e3 * (time.perf_counter() - t)
         log(f"pipelined x{k} (host inputs): total {dt:.1f}ms "
